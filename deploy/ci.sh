@@ -87,16 +87,15 @@ timeout -k 10 "$CHAOS_TIMEOUT" env JAX_PLATFORMS=cpu \
 echo "== perf-smoke: warm pipeline must hit the feature-plane cache =="
 # Runs the builder pipeline twice on one small dataset (bench.py
 # warm_pipeline) and asserts the warm run actually reused cached
-# state: cache hits > 0 and warm pipeline_seconds <= cold. The XLA
-# compilation cache gets a FRESH directory — deserializing persisted
-# CPU executables is unreliable on this jaxlib (see tests/conftest.py).
+# state: cache hits > 0 and warm pipeline_seconds <= cold. jax's
+# persistent compilation cache stays OFF here: on the CPU backend the
+# one cache rule (services/context.py) turns it on only when
+# JAX_COMPILATION_CACHE_DIR is set (hazard note: tests/conftest.py).
 PERF_TIMEOUT="${LO_CI_PERF_TIMEOUT:-600}"
-PERF_CACHE="$(mktemp -d)"
 PERF_OUT="$(mktemp)"
 SLICE_OUT="$(mktemp)"
-trap 'rm -rf "$PERF_CACHE" "$PERF_OUT" "$SLICE_OUT"' EXIT
+trap 'rm -rf "$PERF_OUT" "$SLICE_OUT"' EXIT
 timeout -k 10 "$PERF_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     LO_BENCH_WARM_ROWS=20000 \
     python bench.py --phase warm_pipeline | tee "$PERF_OUT"
@@ -129,7 +128,6 @@ echo "== slice-smoke: concurrent half-mesh jobs must beat serialization =="
 SLICE_TIMEOUT="${LO_CI_SLICE_TIMEOUT:-600}"
 timeout -k 10 "$SLICE_TIMEOUT" env JAX_PLATFORMS=cpu \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase concurrent_jobs | tee "$SLICE_OUT"
 python - "$SLICE_OUT" <<'EOF'
@@ -164,9 +162,8 @@ echo "== ckpt-stall: async checkpointing must hide the commit =="
 CKPT_TIMEOUT="${LO_CI_CKPT_TIMEOUT:-300}"
 CKPT_OUT="$(mktemp)"
 MIG_OUT="$(mktemp)"
-trap 'rm -rf "$PERF_CACHE" "$PERF_OUT" "$SLICE_OUT" "$CKPT_OUT" "$MIG_OUT"' EXIT
+trap 'rm -rf "$PERF_OUT" "$SLICE_OUT" "$CKPT_OUT" "$MIG_OUT"' EXIT
 timeout -k 10 "$CKPT_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase ckpt_stall | tee "$CKPT_OUT"
 python - "$CKPT_OUT" <<'EOF'
@@ -202,7 +199,6 @@ echo "== migration-smoke: live migration must not perturb the math =="
 MIG_TIMEOUT="${LO_CI_MIG_TIMEOUT:-600}"
 timeout -k 10 "$MIG_TIMEOUT" env JAX_PLATFORMS=cpu \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase migration_smoke | tee "$MIG_OUT"
 python - "$MIG_OUT" <<'EOF'
@@ -243,10 +239,9 @@ echo "== elastic-smoke: autoscaler must relieve pressure, roll back safely =="
 #    the run stays bit-identical to an untouched rigid twin
 ELASTIC_TIMEOUT="${LO_CI_ELASTIC_TIMEOUT:-600}"
 ELASTIC_OUT="$(mktemp)"
-trap 'rm -rf "$PERF_CACHE" "$PERF_OUT" "$SLICE_OUT" "$CKPT_OUT" "$MIG_OUT" "$ELASTIC_OUT"' EXIT
+trap 'rm -rf "$PERF_OUT" "$SLICE_OUT" "$CKPT_OUT" "$MIG_OUT" "$ELASTIC_OUT"' EXIT
 timeout -k 10 "$ELASTIC_TIMEOUT" env JAX_PLATFORMS=cpu \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase elastic_smoke | tee "$ELASTIC_OUT"
 python - "$ELASTIC_OUT" <<'EOF'
@@ -304,9 +299,8 @@ MONITOR_OUT="$(mktemp)"
 INCIDENT_OUT="$(mktemp)"
 ROOFLINE_OUT="$(mktemp)"
 XRAY_OUT="$(mktemp)"
-trap 'rm -rf "$PERF_CACHE" "$PERF_OUT" "$SLICE_OUT" "$CKPT_OUT" "$MIG_OUT" "$ELASTIC_OUT" "$CHAOS_OUT" "$OVERHEAD_OUT" "$OBS_OUT" "$SERVE_OUT" "$PAGED_OUT" "$QUANT_OUT" "$DISAGG_OUT" "$SWEEP_OUT" "$MONITOR_OUT" "$ROOFLINE_OUT" "$XRAY_OUT"' EXIT
+trap 'rm -rf "$PERF_OUT" "$SLICE_OUT" "$CKPT_OUT" "$MIG_OUT" "$ELASTIC_OUT" "$CHAOS_OUT" "$OVERHEAD_OUT" "$OBS_OUT" "$SERVE_OUT" "$PAGED_OUT" "$QUANT_OUT" "$DISAGG_OUT" "$SWEEP_OUT" "$MONITOR_OUT" "$ROOFLINE_OUT" "$XRAY_OUT"' EXIT
 timeout -k 10 "$SENTINEL_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase sentinel_chaos | tee "$CHAOS_OUT"
 python - "$CHAOS_OUT" <<'EOF'
@@ -335,7 +329,6 @@ echo "== sentinel-overhead: armed sentinel must cost < 3% =="
 # sentinel_overhead); the armed health word + drop guard must stay
 # under a 3% steady-state slowdown.
 timeout -k 10 "$SENTINEL_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase sentinel_overhead | tee "$OVERHEAD_OUT"
 python - "$OVERHEAD_OUT" <<'EOF'
@@ -366,7 +359,6 @@ echo "== obs-smoke: traced job must tell its whole story for < 3% =="
 # LO_TRACE=0 must stay under the same < 3% gate as the sentinel.
 OBS_TIMEOUT="${LO_CI_OBS_TIMEOUT:-600}"
 timeout -k 10 "$OBS_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase obs_overhead | tee "$OBS_OUT"
 python - "$OBS_OUT" <<'EOF'
@@ -409,7 +401,6 @@ echo "== serving-smoke: resident plane must beat the batch path =="
 #    linearly with batch. Override with LO_SMOKE_SERVE_DECODE_FLOOR.
 SERVE_TIMEOUT="${LO_CI_SERVE_TIMEOUT:-900}"
 timeout -k 10 "$SERVE_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     LO_BENCH_TLM_D=128 LO_BENCH_TLM_LAYERS=2 LO_BENCH_TLM_SEQ=128 \
     LO_BENCH_SERVE_TOKENS=32 LO_BENCH_SERVE_PROMPT=16 \
@@ -459,7 +450,6 @@ echo "== paged-smoke: paged KV must beat slot KV at equal HBM =="
 #    its per-tenant servingP99 objective must not fire.
 PAGED_TIMEOUT="${LO_CI_PAGED_TIMEOUT:-900}"
 timeout -k 10 "$PAGED_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     LO_BENCH_TLM_D=128 LO_BENCH_TLM_LAYERS=2 LO_BENCH_TLM_SEQ=128 \
     LO_BENCH_PAGED_SLO_MS=30000 \
@@ -510,7 +500,6 @@ echo "== quant-smoke: int8 KV must beat bf16 at equal HBM, gated on quality =="
 #    corrupted stream.
 QUANT_TIMEOUT="${LO_CI_QUANT_TIMEOUT:-900}"
 timeout -k 10 "$QUANT_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     LO_BENCH_TLM_D=128 LO_BENCH_TLM_LAYERS=2 LO_BENCH_TLM_SEQ=128 \
     python bench.py --phase quant_serving | tee "$QUANT_OUT"
@@ -573,7 +562,6 @@ DISAGG_TIMEOUT="${LO_CI_DISAGG_TIMEOUT:-900}"
 # arm's compute and invert the contrast (split mechanics are covered
 # by tests/test_serving.py under the forced-8-device conftest)
 timeout -k 10 "$DISAGG_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     LO_BENCH_TLM_D=128 LO_BENCH_TLM_LAYERS=2 LO_BENCH_TLM_SEQ=128 \
     python bench.py --phase disagg_serving | tee "$DISAGG_OUT"
@@ -641,7 +629,6 @@ echo "== sweep-smoke: fused sweep must beat serial trials =="
 #    LO_SMOKE_SWEEP_FLOOR.
 SWEEP_TIMEOUT="${LO_CI_SWEEP_TIMEOUT:-900}"
 timeout -k 10 "$SWEEP_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase sweep_fusion | tee "$SWEEP_OUT"
 python - "$SWEEP_OUT" <<'EOF'
@@ -687,7 +674,6 @@ echo "== monitor-smoke: SLO watchdog must page, resolve, and cost < 1% =="
 #    steady-state vs the monitor stopped
 MONITOR_TIMEOUT="${LO_CI_MONITOR_TIMEOUT:-600}"
 timeout -k 10 "$MONITOR_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase monitor_smoke | tee "$MONITOR_OUT"
 python - "$MONITOR_OUT" <<'EOF'
@@ -733,7 +719,6 @@ echo "== incident-smoke: a page must auto-capture a bundle, cost < 3% =="
 #  - an armed-but-idle recorder costs < 3% steady-state vs off
 INCIDENT_TIMEOUT="${LO_CI_INCIDENT_TIMEOUT:-600}"
 timeout -k 10 "$INCIDENT_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase incident_smoke | tee "$INCIDENT_OUT"
 python - "$INCIDENT_OUT" <<'EOF'
@@ -788,7 +773,6 @@ echo "== roofline-smoke: perf reports must land and cost < 3% =="
 #  - LO_PERF=1 vs LO_PERF=0 steady-state fit cost stays < 3%
 ROOFLINE_TIMEOUT="${LO_CI_ROOFLINE_TIMEOUT:-600}"
 timeout -k 10 "$ROOFLINE_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase perf_report | tee "$ROOFLINE_OUT"
 python - "$ROOFLINE_OUT" <<'EOF'
@@ -835,7 +819,6 @@ echo "== xray-smoke: HBM ledger must attribute + cost < 3% =="
 #  - LO_XRAY=1 vs LO_XRAY=0 steady-state fit cost stays < 3%
 XRAY_TIMEOUT="${LO_CI_XRAY_TIMEOUT:-600}"
 timeout -k 10 "$XRAY_TIMEOUT" env JAX_PLATFORMS=cpu \
-    JAX_COMPILATION_CACHE_DIR="$PERF_CACHE" \
     LO_COMPUTE_DTYPE=float32 \
     python bench.py --phase xray_overhead | tee "$XRAY_OUT"
 python - "$XRAY_OUT" <<'EOF'

@@ -12,7 +12,7 @@ stack:
   artifacts) replacing MongoDB-as-everything (reference
   docker-compose.yml:42-90).
 - A JAX runtime: device-mesh manager, jit/pjit training engines,
-  double-buffered host->HBM input feed, Orbax checkpointing.
+  double-buffered host->HBM input feed, verified step checkpointing.
 - A parallelism library: DP/FSDP/TP/PP/SP(ring attention)/Ulysses/EP
   over `jax.sharding.Mesh` — all absent in the reference (SURVEY §2.4).
 """

@@ -8,7 +8,7 @@ shared Docker volumes path-routed by artifact type
 - save/load any Python object by (name, type) — ``dill`` fallback;
 - a *native* protocol for framework objects: anything exposing
   ``__lo_save__(dir)`` / classmethod ``__lo_load__(dir)`` (our JAX
-  model handles use Orbax/msgpack inside, not pickles);
+  model handles use msgpack inside, not pickles);
 - raw-bytes artifacts (e.g. the Explore service's plot PNGs,
   database_executor_image/utils.py:295-320);
 - type-routed directory layout so every service reads every other

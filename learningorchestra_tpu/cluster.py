@@ -13,7 +13,7 @@ is all-or-nothing — when one member dies, jax's coordination service
 fatally exits the survivors anyway (and a half-replaced pod could
 never rejoin a live jit). So on any member's non-zero exit the
 supervisor tears the whole pod down and re-forms it; checkpointed
-trains resume from their latest orbax step and the boot requeue
+trains resume from their latest checkpoint step and the boot requeue
 replays unfinished jobs (docs/DEPLOY.md "Failure semantics"). Clean
 exits (code 0, e.g. after SIGTERM drain) do not restart — the Swarm
 ``on-failure`` contract.

@@ -289,11 +289,6 @@ class Config:
     # -1 = auto (a quarter of one device's memory), 0 disables.
     arena_bytes: int = dataclasses.field(
         default_factory=lambda: int(os.environ.get("LO_ARENA_BYTES", "-1")))
-    # Persistent XLA compilation cache directory; empty = off. Opt-in:
-    # deserializing XLA:CPU executables is unstable on some jaxlib
-    # builds (tests/conftest.py), so this never defaults on.
-    xla_cache_dir: str = dataclasses.field(
-        default_factory=lambda: os.environ.get("LO_XLA_CACHE_DIR", ""))
     fault_inject: str = dataclasses.field(
         default_factory=lambda: os.environ.get("LO_FAULT_INJECT", ""))
 

@@ -89,8 +89,8 @@ def _block(p: Dict[str, jnp.ndarray], x: jnp.ndarray,
 
     q, k, v = heads(q), heads(k), heads(v)
     scale = 1.0 / math.sqrt(d // n_heads)
-    # same measured crossover as the LM families (BENCHMARKS.md):
-    # flash from seq 1024 on TPU, dense oracle below
+    # same crossover as the LM families (not measured on today's
+    # code): flash from seq 1024 on TPU, dense oracle below
     use_flash = (attention == "flash" or
                  (attention == "auto" and s >= 1024 and
                   jax.default_backend() == "tpu"))

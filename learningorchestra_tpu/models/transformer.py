@@ -149,7 +149,7 @@ class _Attention(nn.Module):
     n_kv_heads: int = 0      # 0 -> n_heads (standard MHA)
     # one (d, 3*proj) matmul instead of three (d, proj) ones: at small
     # d_model the MXU is under-tiled in the output dim, so widening N
-    # 3x raises utilization (the BENCHMARKS.md d=512 roofline gap).
+    # 3x raises utilization (not measured on today's code).
     # MHA only — under GQA the q/k/v widths differ and column-sharding
     # the concatenation would split across block boundaries.
     fused_qkv: bool = False
@@ -609,8 +609,8 @@ class FusedHeadOut(NamedTuple):
     """Training output of a ``fused_head_chunk`` TransformerLM: the
     final hidden states plus the lm_head kernel, so the loss can run
     the vocab projection + cross-entropy in token chunks and the
-    (tokens, vocab) logits tensor never materializes in HBM (the
-    d_model=512/vocab-32k roofline gap named in BENCHMARKS.md)."""
+    (tokens, vocab) logits tensor never materializes in HBM (its
+    effect on the step is not measured on today's code)."""
     hidden: Any     # (b, s, d) final-norm output
     kernel: Any     # (d, vocab) lm_head weight
     aux: Any        # MoE load-balance scalar
@@ -762,9 +762,9 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
     chunks of the final hidden states through the lm_head matmul, so
     peak logits memory is (chunk, vocab) instead of (b*s, vocab) and
     the full logits tensor never round-trips HBM between forward and
-    loss (BENCHMARKS.md names this epilogue as the d=512 roofline
-    gap: one (8192, 512) x (512, 32000) matmul per step feeding an
-    elementwise log-softmax over 262M f32 logits). The backward
+    loss (at d=512/vocab 32k the unfused epilogue is one (8192, 512)
+    x (512, 32000) matmul per step feeding an elementwise log-softmax
+    over 262M f32 logits; not measured on today's code). The backward
     recomputes each chunk's logits via jax.checkpoint. Accuracy is
     computed inside the same scan and emitted as a loss metric, so
     the engine does not re-run the projection for it."""
@@ -1081,10 +1081,10 @@ class TextClassifier:
     def _resolved_attention(self, seq_len: Optional[int] = None) -> str:
         if self.attention != "auto":
             return self.attention
-        # same measured crossover as the LM (BENCHMARKS.md flash
-        # table), resolved from the ACTUAL batch width when known — a
-        # max_len=2048 classifier fed 128-token batches should take
-        # the dot path, not flash below the measured crossover
+        # same crossover as the LM, resolved from the ACTUAL batch
+        # width when known — a max_len=2048 classifier fed 128-token
+        # batches should take the dot path, not flash below the
+        # crossover
         if jax.default_backend() == "tpu":
             return "flash" if (seq_len or self.max_len) >= 1024 else "dot"
         return "dot"
@@ -1398,7 +1398,7 @@ class LanguageModel:
             raise ValueError(
                 f"sliding_window must be >= 0, got {sliding_window}")
         # LO_TLM_REMAT env overrides; default "none" (measure before
-        # paying recompute FLOPs — see BENCHMARKS.md queued table)
+        # paying recompute FLOPs — not measured on today's code)
         self.remat = remat
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
@@ -1455,14 +1455,10 @@ class LanguageModel:
     def _resolved_attention(self, seq_len: Optional[int] = None) -> str:
         if self.attention != "auto":
             return self.attention
-        # On-chip micro-bench (BENCHMARKS.md "Flash kernel", re-run
-        # 2026-07-31 at the committed 512^2 auto tiles): the Pallas
-        # flash kernel now beats XLA's fused dot at EVERY measured
-        # length — 1024: 8.8 vs 9.7 ms causal (2.2x at full), 2048:
-        # 12.1 vs 15.5 ms, 4096: 19.6 vs 36.0 ms — and is the only
-        # path that compiles at 8k+ (dot materializes the (bh, s, s)
-        # scores). Cross over at 1024 on the ACTUAL sequence length
-        # when known; below 1024 is unmeasured, keep the dot oracle.
+        # flash from seq 1024 on the chip, resolved on the ACTUAL
+        # sequence length when known; the dot path materializes the
+        # (bh, s, s) scores, so long contexts need the kernel. The
+        # crossover itself is not measured on today's code.
         if jax.default_backend() == "tpu":
             return "flash" if (seq_len or self.max_len) >= 1024 else "dot"
         return "dot"
@@ -1470,8 +1466,8 @@ class LanguageModel:
     def _head_chunk(self) -> int:
         """Fused-head chunk size (0 = full logits). Auto rule: fuse
         when the vocab is large enough that the (tokens, vocab) f32
-        logits tensor dominates the step's HBM traffic (the measured
-        d=512 roofline gap, BENCHMARKS.md). Under sequence-parallel
+        logits tensor dominates the step's HBM traffic (not measured
+        on today's code). Under sequence-parallel
         attention the loss runs its shard_map twin
         (:func:`_fused_head_loss_sharded`), keeping the sequence dim
         sharded. ``LO_LM_HEAD_CHUNK`` overrides (0 disables, N sets
@@ -2032,8 +2028,7 @@ class LanguageModel:
         def decode(params, cache, buf, key):
             # the WHOLE decode loop runs as one device program
             # (lax.fori_loop carrying buf+cache) — one host round trip
-            # for the entire continuation instead of one per token,
-            # which dominates generate() latency on relayed backends
+            # for the entire continuation instead of one per token
             def body(pos, carry):
                 buf, cache = carry
                 tok = jax.lax.dynamic_slice(buf, (0, pos - 1), (b, 1))

@@ -5,10 +5,10 @@ program ACHIEVED against it:
 
 - a platform registry of per-chip dense bf16 peak FLOP/s and peak HBM
   bandwidth (public spec-sheet numbers, substring-matched against
-  jax's ``device_kind``; ``None`` off-TPU where a roofline is not
-  meaningful, overridable via ``LO_PEAK_TFLOPS_PER_CHIP`` /
-  ``LO_PEAK_HBM_GBPS`` for chips the table predates — or to pin a
-  roofline on the CPU backend in tests);
+  jax's ``device_kind``; ``None`` on the CPU backend where a roofline
+  is not meaningful, an error for an accelerator no row matches;
+  ``LO_PEAK_TFLOPS_PER_CHIP`` / ``LO_PEAK_HBM_GBPS`` override — e.g.
+  to pin a roofline on the CPU backend in tests);
 - :func:`roofline` — achieved TFLOP/s/chip, achieved GB/s/chip,
   arithmetic intensity and a compute-/bandwidth-bound classification
   against the ridge point, from the per-step flops and
@@ -20,9 +20,11 @@ program ACHIEVED against it:
   ``lo_hbm_bw_util_frac`` gauges on ``/metrics``.
 
 ``LO_PERF=0`` disables the extended block and the registry (the
-legacy ``tflopsPerSecPerChip``/``mfu`` history fields stay); like the
-rest of this package, nothing here may ever fail or stall the job it
-observes.
+legacy ``tflopsPerSecPerChip``/``mfu`` history fields stay). Like the
+rest of this package nothing here stalls the job it observes, with
+ONE deliberate failure: an accelerator whose ``device_kind`` matches
+no table row raises :class:`UnknownDeviceKind` rather than letting MFU
+silently vanish from every report.
 """
 
 from __future__ import annotations
@@ -80,72 +82,71 @@ def _device() -> Any:
     return jax.devices()[0]
 
 
-def _match(table, kind: str) -> Optional[float]:
+class UnknownDeviceKind(RuntimeError):
+    """An accelerator whose ``device_kind`` matches no row of the peak
+    tables: a roofline against a guessed peak is worse than none."""
+
+
+def _match(table, kind: str):
+    """The first ``(row key, peak)`` whose key is in ``kind``."""
     for key, value in table:
-        if key in kind:
-            return value
-    return None
+        if key in kind.lower():
+            return key, value
+    raise UnknownDeviceKind(
+        f"no peak for device_kind {kind!r} in observability/perf.py "
+        f"(rows: {[k for k, _ in table]}); add the chip's public "
+        f"spec-sheet row")
+
+
+def _peak(table, env_var: str, unit: float) -> Optional[float]:
+    """``env_var`` override, else the table row for this accelerator's
+    ``device_kind``; None on the CPU backend (no roofline there), an
+    :class:`UnknownDeviceKind` error for an accelerator no row
+    matches."""
+    env = os.environ.get(env_var)
+    if env:
+        try:
+            return float(env) * unit
+        except ValueError:
+            pass
+    dev = _device()
+    if dev.platform == "cpu":
+        return None
+    return _match(table, dev.device_kind)[1]
 
 
 def peak_flops_per_chip() -> Optional[float]:
-    """Dense bf16 peak of the current accelerator, None off-TPU (MFU
-    is only meaningful against a hardware roofline).
-    ``LO_PEAK_TFLOPS_PER_CHIP`` overrides the table — for chips it
-    predates, or to pin a roofline on the CPU backend."""
-    env = os.environ.get("LO_PEAK_TFLOPS_PER_CHIP")
-    if env:
-        try:
-            return float(env) * 1e12
-        except ValueError:
-            pass
-    try:
-        dev = _device()
-    except Exception:  # noqa: BLE001 — no backend, no roofline
-        return None
-    if dev.platform != "tpu":
-        return None
-    return _match(PEAK_FLOPS_BF16,
-                  getattr(dev, "device_kind", "").lower())
+    """Dense bf16 peak of the current accelerator (None on the CPU
+    backend: MFU is only meaningful against a hardware roofline).
+    ``LO_PEAK_TFLOPS_PER_CHIP`` overrides the table."""
+    return _peak(PEAK_FLOPS_BF16, "LO_PEAK_TFLOPS_PER_CHIP", 1e12)
 
 
 def peak_hbm_bytes_per_chip() -> Optional[float]:
     """Peak HBM bandwidth (bytes/s) of the current accelerator, None
-    off-TPU. ``LO_PEAK_HBM_GBPS`` overrides the table."""
-    env = os.environ.get("LO_PEAK_HBM_GBPS")
-    if env:
-        try:
-            return float(env) * 1e9
-        except ValueError:
-            pass
-    try:
-        dev = _device()
-    except Exception:  # noqa: BLE001
-        return None
-    if dev.platform != "tpu":
-        return None
-    return _match(PEAK_HBM_BYTES,
-                  getattr(dev, "device_kind", "").lower())
+    on the CPU backend. ``LO_PEAK_HBM_GBPS`` overrides the table."""
+    return _peak(PEAK_HBM_BYTES, "LO_PEAK_HBM_GBPS", 1e9)
 
 
 def platform_summary() -> Dict[str, Any]:
-    """The roofline this process measures against: platform, chip
-    kind, peaks and the ridge point (flops/byte above which a program
-    is compute-bound)."""
-    try:
-        dev = _device()
-        platform = dev.platform
-        kind = getattr(dev, "device_kind", "")
-    except Exception:  # noqa: BLE001
-        platform, kind = "unknown", ""
+    """The roofline this process measures against: platform, the
+    ``device_kind`` the chip reports, the peaks of the table row it
+    matched and the ridge point (flops/byte above which a program is
+    compute-bound)."""
+    dev = _device()
     peak_f = peak_flops_per_chip()
     peak_b = peak_hbm_bytes_per_chip()
     out: Dict[str, Any] = {
-        "platform": platform,
-        "deviceKind": kind,
+        "platform": dev.platform,
+        "deviceKind": dev.device_kind,
         "peakTflopsPerChip": (round(peak_f / 1e12, 2)
                               if peak_f else None),
         "peakHbmGbPerSec": (round(peak_b / 1e9, 1) if peak_b else None),
     }
+    if os.environ.get("LO_PEAK_TFLOPS_PER_CHIP"):
+        out["peakRow"] = "LO_PEAK_TFLOPS_PER_CHIP"
+    elif dev.platform != "cpu":
+        out["peakRow"] = _match(PEAK_FLOPS_BF16, dev.device_kind)[0]
     if peak_f and peak_b:
         out["ridgeFlopsPerByte"] = round(peak_f / peak_b, 2)
     return out
@@ -161,8 +162,9 @@ def roofline(flops_per_step: float, bytes_per_step: float, steps: int,
     ``bytes accessed``) and :func:`enabled`, adds achieved
     ``gbPerSecPerChip``, ``arithmeticIntensity`` (flops/byte),
     ``hbmBwUtil`` and the ``boundBy`` classification against the
-    ridge point. Off-TPU with no override every peak-relative field is
-    simply absent — never a division by a made-up number."""
+    ridge point. On the CPU backend with no override every
+    peak-relative field is simply absent — never a division by a
+    made-up number."""
     out: Dict[str, Any] = {}
     if not flops_per_step or steps <= 0 or dt <= 0 or n_chips <= 0:
         return out

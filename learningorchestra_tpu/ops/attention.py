@@ -40,10 +40,6 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -52,7 +48,10 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # interpret ONLY on the CPU backend (tests, rehearsals); every
+    # other backend compiles the kernel and a compile failure
+    # propagates — nothing catches it and gives way to dot/dense
+    return jax.default_backend() == "cpu"
 
 
 # Default tile edge for block_q/block_k when the caller doesn't pick
@@ -325,7 +324,7 @@ def _fwd_pallas(q, k, v, *, scale: float, causal: bool,
         # bh and the Q-tile axis own disjoint outputs/accumulator
         # streaks -> Mosaic may split them across megacore; the KV
         # stream axis accumulates and must stay sequential
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -533,7 +532,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         out_shape=jax.ShapeDtypeStruct((bh, group * sq_p, d_p),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse_l, delta_l)
@@ -558,7 +557,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
                    jax.ShapeDtypeStruct((bh, sk_p, d_p), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32),
                         pltpu.VMEM((block_k, d_p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse_l, delta_l)

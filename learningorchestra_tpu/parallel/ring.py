@@ -242,12 +242,13 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     over ``sp``, batch over the data axes.
 
     ``block_impl``: ``"dense"`` (XLA einsum tiles), ``"flash"``
-    (Pallas kernel per hop), or ``"auto"`` (flash on TPU, dense
-    elsewhere — interpret-mode pallas is for tests, not speed)."""
+    (Pallas kernel per hop), or ``"auto"`` (dense on the CPU backend
+    only — interpret-mode pallas is for tests, not speed; every other
+    backend compiles the kernel, and a compile failure propagates)."""
     if mesh_lib.SP not in mesh.axis_names:
         raise ValueError("mesh has no 'sp' axis")
     if block_impl == "auto":
-        block_impl = "flash" if jax.default_backend() == "tpu" else "dense"
+        block_impl = "dense" if jax.default_backend() == "cpu" else "flash"
     data = mesh_lib.data_axes(mesh)
     spec = P(data if data else None, mesh_lib.SP, None, None)
     inner = (ring_flash_attention if block_impl == "flash"

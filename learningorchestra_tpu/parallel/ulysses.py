@@ -46,7 +46,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"divisible by sp={n} (repeat K/V to full heads "
             f"otherwise)")
     if attn_fn is None:
-        if jax.default_backend() == "tpu":
+        if _flash_local():
             # local attention over the gathered sequence runs the
             # fused flash kernel — O(block) memory for the full-seq
             # score rows instead of a dense (s, s) tile per head;
@@ -78,6 +78,14 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return gather_heads(out)
 
 
+def _flash_local() -> bool:
+    """The local full-sequence attention runs the Pallas flash kernel
+    on every backend but the CPU (same rule as the kernel's own
+    interpret switch, ops/attention.py): the kernel compiles or the
+    failure propagates — nothing gives way to the dense path."""
+    return jax.default_backend() != "cpu"
+
+
 def ulysses_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                               mesh: Mesh, causal: bool = False,
                               window: int = 0,
@@ -86,8 +94,11 @@ def ulysses_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError("mesh has no 'sp' axis")
     data = mesh_lib.data_axes(mesh)
     spec = P(data if data else None, mesh_lib.SP, None, None)
+    # pallas_call emits ShapeDtypeStructs with no varying-mesh-axes
+    # info, which the vma checker rejects (same as ring's flash hops)
+    extra = {"check_vma": False} if _flash_local() else {}
     fn = mesh_lib.shard_map(
         functools.partial(ulysses_attention, axis_name=mesh_lib.SP,
                           causal=causal, scale=scale, window=window),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **extra)
     return fn(q, k, v)

@@ -5,6 +5,6 @@ substrate (in-process TF/sklearn ``fit`` calls, binary_execution.py:
 - ``mesh``       — device-mesh manager and axis conventions
 - ``data``       — host->device double-buffered input feed
 - ``engine``     — jit/pjit train/eval/predict loops
-- ``checkpoint`` — Orbax step checkpointing + pytree artifact IO
+- ``checkpoint`` — verified msgpack step checkpointing + pytree artifact IO
 - ``distributed``— multi-host initialization (jax.distributed)
 """

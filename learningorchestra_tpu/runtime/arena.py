@@ -57,17 +57,21 @@ def _ledger(op: str, key: Any, nbytes: int = 0,
 
 
 def _auto_budget() -> int:
-    """A quarter of one device's reported memory; 1 GiB fallback
-    (XLA:CPU and some PJRT plugins report no ``bytes_limit``)."""
-    try:
-        import jax
+    """A quarter of one device's reported memory. XLA:CPU reports no
+    ``bytes_limit`` and gets a 1 GiB stand-in; an accelerator that
+    reports none is an error — sizing an HBM tier from a guess would
+    either waste the device or overcommit it."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit // 4
-    except Exception:  # noqa: BLE001 — budget sizing must never raise
-        pass
+    dev = jax.local_devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit > 0:
+        return limit // 4
+    if dev.platform != "cpu":
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            f"bytes_limit in memory_stats(); set LO_ARENA_BYTES to "
+            f"size the HBM arena explicitly")
     return 1 << 30
 
 

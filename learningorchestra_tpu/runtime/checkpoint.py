@@ -3,19 +3,16 @@
 The reference has NO mid-training checkpointing — persistence is the
 final artifact only, and a failed job is simply re-run from its stored
 parent (SURVEY §5: binary_executor utils.py:195-208, server.py:74-118).
-Here training jobs checkpoint per-epoch/step via Orbax on TPU and can
-resume, and pytree artifacts are serialized with msgpack
-(flax.serialization) instead of pickles.
+Here training jobs checkpoint per-epoch/step and can resume, and
+pytree artifacts are serialized with msgpack (flax.serialization)
+instead of pickles.
 
-Off-TPU the step checkpoints use the same msgpack serialization
-instead of Orbax: on this jaxlib, tensorstore reads (Orbax restore)
-and XLA:CPU executables deserialized from jax's persistent
-compilation cache corrupt the glibc heap when they share a process
-("corrupted double-linked list" / SIGSEGV in the next jitted step),
-and once the cache is warm no amount of disabling-at-restore helps —
-the poisoned executable has already run during fit. Keeping
-tensorstore out of CPU processes entirely removes the conflict while
-the compilation cache stays on.
+ONE layout on every backend: a directory per step holding msgpack
+payload file(s) plus a manifest, committed through
+:meth:`Checkpointer._commit_host`. The chip takes exactly the path the
+CPU tests cover (atomic commit, manifest verification, quarantine,
+async tier, shards); no tensorstore-backed reader shares a process
+with jax's compilation cache.
 
 Integrity (docs/RELIABILITY.md): each msgpack step dir carries a
 ``manifest.json`` (per-file byte size + sha256, step, wall time) and
@@ -27,7 +24,6 @@ init). ``restore()`` re-hashes the payload against the manifest;
 a torn or bit-flipped step dir is moved to ``<dir>/.quarantine/``
 (bounded to the ``LO_CKPT_QUARANTINE_KEEP`` newest entries) and
 restore transparently falls back to the newest VERIFIED step.
-Orbax (TPU) keeps its own atomic-commit + metadata machinery.
 
 Layout (``shards > 1``): the state dict is partitioned into N
 byte-balanced sub-files (``shard-00000-of-00002.msgpack``, …) under
@@ -117,10 +113,6 @@ class CheckpointCorrupted(IOError):
     layer classifies it transient."""
 
 
-def _use_orbax() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _fsync_file(f) -> None:
     f.flush()
     os.fsync(f.fileno())
@@ -177,20 +169,9 @@ def _place_like(restored: Any, target: Any) -> Any:
     return jax.tree_util.tree_map(_place, restored, target)
 
 
-class _NullAsyncManager:
-    """Orbax-shaped facade for the msgpack backend: saves are
-    synchronous, so finishing/closing are no-ops."""
-
-    def wait_until_finished(self) -> None:
-        pass
-
-    def close(self) -> None:
-        pass
-
-
 class Checkpointer:
-    """save(step, pytree) / latest_step() / restore — Orbax on TPU,
-    msgpack files off-TPU (same directory-per-step layout)."""
+    """save(step, pytree) / latest_step() / restore over the verified
+    msgpack directory-per-step layout (module docstring)."""
 
     def __init__(self, directory: str, max_to_keep: int = 3,
                  shards: int = 1):
@@ -200,22 +181,12 @@ class Checkpointer:
         # sub-files per step commit (multi-host: one per mesh-slice
         # shard, i.e. shards=jax.process_count()); 1 = legacy layout
         self._shards = max(1, int(shards))
-        if _use_orbax():
-            import orbax.checkpoint as ocp
-
-            self._mgr = ocp.CheckpointManager(
-                self._dir,
-                options=ocp.CheckpointManagerOptions(
-                    max_to_keep=max_to_keep, create=True),
-            )
-        else:
-            self._mgr = _NullAsyncManager()
-            # a kill mid-save leaves a <step>.tmp dir that was never
-            # committed — it holds no verified state, sweep it
-            for name in os.listdir(self._dir):
-                if name.endswith(".tmp"):
-                    shutil.rmtree(os.path.join(self._dir, name),
-                                  ignore_errors=True)
+        # a kill mid-save leaves a <step>.tmp dir that was never
+        # committed — it holds no verified state, sweep it
+        for name in os.listdir(self._dir):
+            if name.endswith(".tmp"):
+                shutil.rmtree(os.path.join(self._dir, name),
+                              ignore_errors=True)
 
     # -- msgpack layout helpers ----------------------------------------
     def _step_dirs(self) -> List[int]:
@@ -401,11 +372,6 @@ class Checkpointer:
             pass
 
     def _save_impl(self, step: int, tree: Any) -> None:
-        if _use_orbax():
-            import orbax.checkpoint as ocp
-
-            self._mgr.save(step, args=ocp.args.StandardSave(tree))
-            return
         host = jax.tree_util.tree_map(np.asarray, tree)
         self._commit_host(step, host)
 
@@ -475,8 +441,6 @@ class Checkpointer:
         """Newest step passing cheap (size) verification. Steps failing
         it are skipped — not quarantined; only restore(), which does the
         full re-hash, moves dirs aside."""
-        if _use_orbax():
-            return self._mgr.latest_step()
         for step in reversed(self._step_dirs()):
             try:
                 self._verify_sizes(step)
@@ -486,15 +450,6 @@ class Checkpointer:
         return None
 
     def restore(self, target: Any, step: Optional[int] = None) -> Any:
-        if _use_orbax():
-            if step is None:
-                step = self._mgr.latest_step()
-            if step is None:
-                return None
-            import orbax.checkpoint as ocp
-
-            return self._mgr.restore(
-                step, args=ocp.args.StandardRestore(target))
         if step is not None:
             try:
                 raw = self._read_verified_tree(step)
@@ -539,9 +494,6 @@ class Checkpointer:
             step = self.latest_step()
         if step is None:
             return None
-        if _use_orbax():
-            meta = self._mgr.item_metadata(step)
-            return getattr(meta, "tree", meta)
         # raw nested state dict; numpy leaves expose .shape/.dtype
         return self._read_verified_tree(step)
 
@@ -553,12 +505,6 @@ class Checkpointer:
         Reads are VERIFIED like ``restore()``: a corrupt step is
         quarantined; with ``step=None`` the read falls back to the
         next-newest verified step, an explicit step raises."""
-        if _use_orbax():
-            if step is None:
-                step = self.latest_step()
-            if step is None:
-                return None
-            return self._restore_partial_orbax(target_subtree, step)
         while True:
             explicit = step is not None
             if not explicit:
@@ -582,31 +528,6 @@ class Checkpointer:
                 return None
             out[key] = serialization.from_state_dict(sub_target, raw[key])
         return out
-
-    def _restore_partial_orbax(self, target_subtree: Any,
-                               step: int) -> Any:
-        """Uses a fresh read-only manager: the instance manager's
-        handler registry is pinned to StandardRestore by the failed
-        full restore that precedes a migration."""
-        import orbax.checkpoint as ocp
-
-        mgr = ocp.CheckpointManager(self._dir)
-        try:
-            # newer orbax spells partial restore `partial_restore=True`;
-            # 0.7.x uses the empty-transforms idiom (keys absent from
-            # ``item`` are skipped, present ones restore 1:1 — which
-            # requires explicit per-leaf restore_args)
-            try:
-                return mgr.restore(step, args=ocp.args.PyTreeRestore(
-                    item=target_subtree, partial_restore=True))
-            except TypeError:
-                restore_args = jax.tree_util.tree_map(
-                    lambda _: ocp.RestoreArgs(), target_subtree)
-                return mgr.restore(step, args=ocp.args.PyTreeRestore(
-                    item=target_subtree, restore_args=restore_args,
-                    transforms={}))
-        finally:
-            mgr.close()
 
     # -- sidecar progress metadata ------------------------------------
     # Epoch progress can't be reconstructed from the restored step when
@@ -637,16 +558,13 @@ class Checkpointer:
         return meta if isinstance(meta, dict) else None
 
     def wait_until_finished(self, reraise: bool = True) -> None:
-        """Barrier for in-flight commits. The synchronous backend has
-        none (msgpack saves return committed; Orbax's manager drains
-        itself) — this exists so callers can treat sync and async
-        checkpointers uniformly (runtime/async_ckpt.py)."""
+        """Barrier for in-flight commits: none here (``save`` returns
+        committed). Exists so callers treat this and the async manager
+        (runtime/async_ckpt.py) uniformly."""
         del reraise
-        self._mgr.wait_until_finished()
 
     def close(self) -> None:
-        self._mgr.wait_until_finished()
-        self._mgr.close()
+        pass
 
 
 # ----------------------------------------------------------------------

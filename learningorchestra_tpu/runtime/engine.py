@@ -209,9 +209,7 @@ class Engine:
             shardings = rules_lib.param_shardings(
                 params, self._mesh, self._param_rules, fsdp=self._fsdp)
             params = jax.device_put(params, shardings)
-            # jit propagates the param shardings into matching
-            # optimizer-state leaves (adam mu/nu mirror params)
-            opt_state = jax.jit(self._optimizer.init)(params)
+            opt_state = self._init_opt_state_on_mesh(params)
             rep = mesh_lib.replicated(self._mesh)
             return TrainState(
                 step=jax.device_put(jnp.zeros((), jnp.int32), rep),
@@ -223,6 +221,28 @@ class Engine:
         if self._mesh is not None:
             state = jax.device_put(state, mesh_lib.replicated(self._mesh))
         return state
+
+    def _init_opt_state_on_mesh(self, params):
+        """Optimizer state for rules-sharded ``params``: jit propagates
+        the param shardings into matching leaves (adam mu/nu mirror
+        params); leaves that depend on no input (the step ``count``)
+        come back on the default device alone, so they are replicated
+        over the mesh here. Left there, a checkpoint restore — which
+        commits every leaf to its target's sharding — would pin them
+        to one device and the next step would refuse the mixed
+        placement."""
+        opt_state = jax.jit(self._optimizer.init)(params)
+        mesh_devices = set(self._mesh.devices.flat)
+        rep = mesh_lib.replicated(self._mesh)
+
+        def on_mesh(x):
+            # a tracer (eval_shape of init_state) has no placement
+            if isinstance(x, jax.core.Tracer) or \
+                    x.sharding.device_set == mesh_devices:
+                return x
+            return jax.device_put(x, rep)
+
+        return jax.tree_util.tree_map(on_mesh, opt_state)
 
     def _cast(self, tree):
         dtype = self._compute_dtype
@@ -775,11 +795,11 @@ class Engine:
                          epoch: int) -> None:
         step = int(state.step)
         checkpointer.save(step, state)
-        # the orbax save above is async: the sidecar records which step
-        # it describes, and resume ignores it unless that exact step is
-        # what actually restored (a crash mid-save leaves an older
-        # committed step + a newer sidecar — trusting it would skip
-        # never-trained epochs)
+        # the save above may be async (runtime/async_ckpt.py): the
+        # sidecar records which step it describes, and resume ignores
+        # it unless that exact step is what actually restored (a crash
+        # mid-save leaves an older committed step + a newer sidecar —
+        # trusting it would skip never-trained epochs)
         if hasattr(checkpointer, "save_meta"):
             checkpointer.save_meta({"step": step, "epochs_done": epoch + 1})
 
@@ -801,11 +821,9 @@ class Engine:
         except (ValueError, KeyError, TypeError) as exc:
             # The targeted restore failed. Decide what that MEANS from
             # the checkpoint's own metadata (structure only, no array
-            # reads) rather than the exception text — orbax raises
-            # ValueError both for layout drift and for I/O corruption
-            # (tensorstore NOT_FOUND), and silently training from
-            # scratch on a corrupted read could overwrite the last
-            # good checkpoint at the next save.
+            # reads) rather than the exception text: silently training
+            # from scratch on a corrupted read could overwrite the
+            # last good checkpoint at the next save.
             import warnings
 
             migrated, reason = self._restore_params_only(state,
@@ -887,7 +905,7 @@ class Engine:
                 jnp.asarray(new, cur.dtype), cur.sharding),
             state.params, raw["params"])
         if self._mesh is not None and self._param_rules is not None:
-            opt_state = jax.jit(self._optimizer.init)(params)
+            opt_state = self._init_opt_state_on_mesh(params)
         else:
             opt_state = self._optimizer.init(params)
         step = state.step
@@ -921,7 +939,7 @@ class Engine:
                 host_state.params, mesh, self._param_rules,
                 fsdp=self._fsdp)
             params = jax.device_put(host_state.params, shardings)
-            ref_opt = jax.jit(self._optimizer.init)(params)
+            ref_opt = self._init_opt_state_on_mesh(params)
             opt_state = jax.tree_util.tree_map(
                 lambda h, r: jax.device_put(
                     jnp.asarray(h, r.dtype), r.sharding),

@@ -44,39 +44,11 @@ DCN, DP, FSDP, TP, PP, SP, EP = \
     "dcn", "dp", "fsdp", "tp", "pp", "sp", "ep"
 KNOWN_AXES = (DCN, DP, FSDP, TP, PP, SP, EP)
 
-# jax >= 0.5 exposes shard_map at top level and spells the
-# replication-check toggle ``check_vma``; 0.4.x has it under
-# jax.experimental as ``check_rep``. Alias here so callers stay
-# version-agnostic (always pass ``check_vma``). On 0.4.x the vma type
-# system backing the check does not exist (no ``lax.pcast`` to mark
-# scan carries varying), so the static check is disabled outright —
-# it never affects computed values.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, **kwargs):
-        kwargs.pop("check_vma", None)
-        kwargs["check_rep"] = False
-        return _shard_map_04x(f, **kwargs)
-
-# vma ("varying mesh axes") helpers, identity/empty on 0.4.x where
-# values inside shard_map carry no per-axis varying type
-_pcast = getattr(jax.lax, "pcast", None)
-
-
-def pcast(x, axis_name, to="varying"):
-    if _pcast is None:
-        return x
-    return _pcast(x, axis_name, to=to)
-
-
-def typeof(x):
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(x)
-    return jax.core.get_aval(x)
+# plain aliases of the installed JAX's own names, kept so callers need
+# not change (always pass ``check_vma``)
+shard_map = jax.shard_map
+pcast = jax.lax.pcast
+typeof = jax.typeof
 
 
 def parse_mesh_spec(spec: str) -> Dict[str, int]:
@@ -106,19 +78,14 @@ def build_mesh(spec: str = "auto",
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     # AxisType.Auto = classic GSPMD propagation: we annotate inputs /
-    # outputs, XLA infers internals and inserts collectives. (Newer
-    # JAX defaults to Explicit, which demands out_shardings on every
+    # outputs, XLA infers internals and inserts collectives. (JAX
+    # defaults to Explicit, which demands out_shardings on every
     # ambiguous gather/scatter — wrong trade-off for a framework that
-    # runs arbitrary user models.) JAX 0.4.x has no AxisType at all —
-    # every mesh is GSPMD-auto there, so omitting the argument keeps
-    # identical semantics.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-
+    # runs arbitrary user models.)
     def make(shapes, names, devs):
-        if axis_type is None:
-            return jax.make_mesh(shapes, names, devices=devs)
-        return jax.make_mesh(shapes, names,
-                             (axis_type.Auto,) * len(names), devices=devs)
+        return jax.make_mesh(
+            shapes, names, (jax.sharding.AxisType.Auto,) * len(names),
+            devices=devs)
 
     if spec == "auto":
         return make((n,), (DP,), devices)

@@ -5,7 +5,7 @@ a long job cannot monopolize the cluster
 (reference spark_image/fairscheduler.xml:1-8, builder_image
 server.py:57-63). The TPU analogue: the mesh is an exclusive lease
 (services/scheduler.FairLease), and long engine fits offer to YIELD
-the lease at epoch boundaries — per-epoch orbax checkpoints make the
+the lease at epoch boundaries — per-epoch checkpoints make the
 hand-off durable, and since all jobs share one process the model
 state stays live in memory across the yield.
 

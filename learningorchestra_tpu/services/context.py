@@ -11,6 +11,7 @@ tests run fully in-process with a tmp-dir store.
 
 from __future__ import annotations
 
+import os
 from typing import Optional
 
 from learningorchestra_tpu.config import Config, get_config
@@ -66,7 +67,7 @@ class ServiceContext:
         # JobManager's slice allocator via ServingLease handles
         from learningorchestra_tpu.services.serving import ServingManager
         self.serving = ServingManager(self)
-        _wire_xla_cache(self.config)
+        wire_compile_cache()
         # callbacks fired by the pod guard when a degraded pod's
         # heartbeats resume (the Api registers worker-lost requeue)
         self.on_pod_healthy: list = []
@@ -140,23 +141,34 @@ class ServiceContext:
         self.catalog.close()
 
 
-def _wire_xla_cache(config: Config) -> None:
-    """Point jax's persistent compilation cache at LO_XLA_CACHE_DIR so
-    repeat jobs skip recompiles across process restarts. Strictly
-    opt-in (empty = off): deserializing XLA:CPU executables from disk
-    is unstable on some jaxlib builds (tests/conftest.py)."""
-    if not config.xla_cache_dir:
-        return
-    import os
+def compile_cache_path() -> str:
+    """The fixed default location of jax's persistent compilation
+    cache: ``<checkout>/.jax_cache``. Fixed because the directory is
+    part of the cache key — a path that moves never hits."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_cache")
 
-    try:
-        import jax
 
-        os.makedirs(config.xla_cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir",
-                          config.xla_cache_dir)
-    except Exception as exc:  # noqa: BLE001 — cache is best-effort
-        print(f"xla cache: disabled ({exc!r})", flush=True)
+def wire_compile_cache() -> Optional[str]:
+    """THE compile-cache rule, for every entry point (``lo-server``,
+    ``chip_smoke.py``, ``bench.py`` phase children): when
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and code
+    sets nothing; otherwise an accelerator backend caches under
+    :func:`compile_cache_path` and the CPU backend caches nothing
+    (tests/conftest.py keeps its own opt-in). Call before the first
+    compile. Returns the directory in use, None when the cache is off."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    if jax.default_backend() == "cpu":
+        return None
+    path = compile_cache_path()
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _start_monitor(ctx: "ServiceContext"):

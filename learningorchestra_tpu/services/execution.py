@@ -313,7 +313,7 @@ class ExecutionService:
                 result = getattr(instance, method)(**treated)
             finally:
                 if ckpt is not None:
-                    ckpt.close()  # flush async orbax writes
+                    ckpt.close()  # flush async checkpoint writes
             if type_string.startswith(_INSTANCE_RESULT_PREFIXES):
                 result = instance  # the fitted object is the artifact
             with obs_trace.span("artifactSave"):
@@ -470,7 +470,7 @@ def checkpoint_dir_for(ctx, name: str) -> str:
 def _prepare_checkpointer(ctx, name: str, type_string: str,
                           treated: Dict[str, Any]):
     """``"checkpoint": true`` in fit methodParameters enables per-epoch
-    orbax checkpointing under the execution's name; a PATCH re-run of
+    step checkpointing under the execution's name; a PATCH re-run of
     the same execution then resumes from the latest step (the engine
     restores before training — beyond the reference, whose failed jobs
     restart from scratch, README.md:194-198).
@@ -535,7 +535,7 @@ def replay_method_call(name: str, type_string: str, parent_name: str,
     """Worker-side twin of the coordinator's pipeline: load the same
     artifact from the shared store, resolve the same parameters, call
     the same method — so every host participates in the global-mesh
-    jit (including orbax checkpoint saves, which are collective).
+    jit (including checkpoint saves).
     Catalog/artifact WRITES stay with the coordinator; the worker's
     copy of the result is discarded."""
     global _worker_ctx

@@ -549,7 +549,7 @@ class JobManager:
                                 # lease released by the CM. A
                                 # checkpointed fit stays resumable — a
                                 # PATCH re-run picks up at the latest
-                                # orbax step.
+                                # checkpoint step.
                                 record_cancel(exc, attempt_no, timing(
                                     {"queueWaitSeconds": round(
                                         queue_wait, 6)}))

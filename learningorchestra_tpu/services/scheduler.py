@@ -35,7 +35,7 @@ tune/evaluate behind it.
   epoch loops call it between epochs. If ANOTHER pool is waiting, the
   holder releases, the waiter runs, and the holder re-queues through
   the same fair policy, re-acquiring its EXACT device block (its
-  arrays still live there). Per-epoch orbax checkpoints plus
+  arrays still live there). Per-epoch checkpoints plus
   in-process state make the hand-off safe and nearly free.
 - **Weights** — ``LO_POOL_WEIGHTS="train=2,tune=1"`` biases the
   fair-share ratio (fairscheduler.xml ``weight`` parity); unlisted

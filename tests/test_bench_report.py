@@ -1,15 +1,17 @@
-"""bench.py reporting contract: the rendered BENCHMARKS.md table and
-the final compact summary line the driver parses (BENCH_r03 recorded
-``parsed: null`` because tail-capture truncated the one giant report
-line — the compact trailer is the fix)."""
+"""bench.py reporting contract: the table ``--write-md`` renders and
+the final compact summary line (a tail capture can truncate the one
+giant report line — the compact trailer always survives)."""
 
 import importlib.util
 import json
+import os
 import subprocess
 import sys
 
-spec = importlib.util.spec_from_file_location("lo_bench",
-                                              "/root/repo/bench.py")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "bench.py")
+
+spec = importlib.util.spec_from_file_location("lo_bench", BENCH)
 bench = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(bench)
 
@@ -19,7 +21,7 @@ def _report():
         "metric": "mnist_cnn_train_samples_per_sec_per_chip",
         "value": 1234.5, "unit": "samples/s", "vs_baseline": 10.0,
         "extra": {
-            "tpu_reachable": True,
+            "accelerator": "tpu | TPU v5 lite | 1",
             "reference_proxy_torch_cpu_samples_per_sec": 123.4,
             "models": {
                 "mnist_cnn": {"platform": "tpu",
@@ -60,31 +62,30 @@ def test_write_md_renders_time_to_accuracy_and_full_data_gb(tmp_path):
     assert counts == {9}, rows[:8]
 
 
-def test_compact_summary_is_last_line_and_parses():
-    """Run bench.py main with every phase stubbed out via a tiny
-    PHASES monkeypatch — asserting the LAST stdout line is a compact
+def test_compact_summary_is_last_line_and_parses(tmp_path):
+    """Run bench.py main with the probe stubbed HEALTHY and every
+    phase stubbed out — asserting the LAST stdout line is a compact
     parseable summary regardless of report size."""
-    code = r"""
+    code = f"""
 import importlib.util, json, sys
-sys.path.insert(0, "/root/repo")  # bench imports __graft_entry__
-spec = importlib.util.spec_from_file_location("lo_bench",
-                                              "/root/repo/bench.py")
+sys.path.insert(0, {REPO!r})  # bench imports __graft_entry__
+spec = importlib.util.spec_from_file_location("lo_bench", {BENCH!r})
 bench = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(bench)
-bench._tpu_healthy = lambda: False
-bench._run_phase = lambda phase, env=None: {"stub": phase,
-                                            "x": "y" * 2000}
-bench._prior_tpu_numbers = lambda: {"note": "stub"}
+bench._accelerator_probe = lambda: (True, "tpu | TPU v5 lite | 1")
+bench._run_phase = lambda phase, env=None: {{"stub": phase,
+                                            "x": "y" * 2000}}
 sys.exit(bench.main([]))
 """
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, timeout=120,
-                         cwd="/tmp")
+                         cwd=str(tmp_path))
     assert out.returncode == 0, out.stderr[-2000:]
     lines = [ln for ln in out.stdout.strip().splitlines() if ln]
     compact = json.loads(lines[-1])
     assert compact["metric"]
-    assert "tpu_reachable" in compact
+    assert compact["accelerator"] == "tpu | TPU v5 lite | 1"
+    assert compact["failed_phases"] == []
     assert compact["unit"] == "samples/s"
     # the full report is the line before, and is larger
     assert len(lines) >= 2 and len(lines[-2]) > len(lines[-1])

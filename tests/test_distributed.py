@@ -410,7 +410,7 @@ def test_worker_sigkill_reports_failure(tmp_path):
     """SIGKILL one of two pod processes mid-train: the coordinator's
     pod guard marks the in-flight mesh job failed with a typed
     WorkerLost execution document within the heartbeat bound, /health
-    reports degraded, and new mesh jobs are refused (VERDICT round-3
+    reports degraded, and new mesh jobs are refused (round-3 review
     missing #4 — Swarm re-placement parity, reference
     README.md:200-202)."""
     import os

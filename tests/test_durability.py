@@ -5,7 +5,7 @@ The reference loses in-flight jobs on failure — a client polling
 (README.md:194-198). SURVEY §7 step 8 sets the rebuild's bar at
 requeue-or-fail: on boot, executions/functions whose full request
 lives in metadata are re-run (checkpointed trains RESUME from their
-latest orbax step); everything else gets a typed failure execution
+latest checkpoint step); everything else gets a typed failure execution
 document so pollers see a terminal state.
 """
 
@@ -58,7 +58,7 @@ time.sleep(600)
 
 def test_kill_and_restart_resumes_checkpointed_train(tmp_path):
     """SIGKILL a server mid-train; a fresh boot on the same home must
-    requeue the stranded train, resume it from the latest orbax step,
+    requeue the stranded train, resume it from the latest checkpoint step,
     and finish within the original 300-epoch budget."""
     home = str(tmp_path / "lo_home")
     child_py = tmp_path / "child.py"
@@ -187,7 +187,7 @@ def test_job_manager_prunes_completed_futures(tmp_config):
 
 
 def test_pod_reform_requeues_checkpointed_train(tmp_config):
-    """Elastic pod recovery (VERDICT r4 item 6): a train refused while
+    """Elastic pod recovery: a train refused while
     the pod is degraded (WorkerLost) requeues AUTOMATICALLY when the
     guard sees heartbeats resume — the checkpointed run finishes, from
     its saved step, with NO server restart."""

@@ -124,6 +124,22 @@ def test_ulysses_with_flash_blocks_matches_full():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_ulysses_sharded_entry_takes_flash_like_an_accelerator(
+        monkeypatch):
+    """The path every backend but the CPU takes THROUGH the sharded
+    entry point: flash kernel (interpreted here) inside its shard_map.
+    The chip-only branch used to keep the vma check on, which a
+    pallas_call's outputs cannot pass — it failed at trace time on the
+    first accelerator run."""
+    monkeypatch.setattr(ulysses, "_flash_local", lambda: True)
+    mesh = _mesh("dp=2,sp=4")
+    q, k, v = _qkv(s=32, seed=5)
+    want = ring.full_attention_reference(q, k, v, causal=True)
+    got = ulysses.ulysses_attention_sharded(q, k, v, mesh, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ----------------------------------------------------------------------
 # pipeline
 # ----------------------------------------------------------------------

@@ -100,13 +100,51 @@ def test_bad_override_falls_through(monkeypatch):
 def test_table_matching_is_substring_ordered():
     # v5e chips report device_kind "TPU v5 lite"; the generic "v5"
     # entry (v5p peak) must NOT shadow it
+    assert obs_perf._match(obs_perf.PEAK_FLOPS_BF16, "TPU v5 lite") == \
+        ("v5 lite", pytest.approx(197e12))
     assert obs_perf._match(
-        obs_perf.PEAK_FLOPS_BF16, "tpu v5 lite") == pytest.approx(197e12)
+        obs_perf.PEAK_FLOPS_BF16, "tpu v5p")[1] == pytest.approx(459e12)
     assert obs_perf._match(
-        obs_perf.PEAK_FLOPS_BF16, "tpu v5p") == pytest.approx(459e12)
-    assert obs_perf._match(
-        obs_perf.PEAK_HBM_BYTES, "tpu v4") == pytest.approx(1228e9)
-    assert obs_perf._match(obs_perf.PEAK_FLOPS_BF16, "h100") is None
+        obs_perf.PEAK_HBM_BYTES, "tpu v4")[1] == pytest.approx(1228e9)
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def test_v5e_device_kind_reads_its_table_row(monkeypatch):
+    """The kind a v5e chip reports resolves to the published v5e
+    peaks, and the summary names the kind and the row it matched."""
+    monkeypatch.setattr(obs_perf, "_device",
+                        lambda: _FakeDevice("tpu", "TPU v5 lite"))
+    assert obs_perf.peak_flops_per_chip() == pytest.approx(197e12)
+    assert obs_perf.peak_hbm_bytes_per_chip() == pytest.approx(819e9)
+    summary = obs_perf.platform_summary()
+    assert summary["deviceKind"] == "TPU v5 lite"
+    assert summary["peakRow"] == "v5 lite"
+    assert summary["peakTflopsPerChip"] == pytest.approx(197.0)
+    assert summary["peakHbmGbPerSec"] == pytest.approx(819.0)
+
+
+@pytest.mark.parametrize("platform,kind", [("tpu", "TPU v9 mega"),
+                                           ("gpu", "NVIDIA H100")])
+def test_unknown_accelerator_device_kind_raises(monkeypatch, platform,
+                                                kind):
+    """An accelerator no table row matches is an error naming the
+    kind — never a silent None that drops MFU from every report."""
+    monkeypatch.setattr(obs_perf, "_device",
+                        lambda: _FakeDevice(platform, kind))
+    with pytest.raises(obs_perf.UnknownDeviceKind, match=kind):
+        obs_perf.peak_flops_per_chip()
+    with pytest.raises(obs_perf.UnknownDeviceKind, match=kind):
+        obs_perf.peak_hbm_bytes_per_chip()
+    with pytest.raises(obs_perf.UnknownDeviceKind):
+        obs_perf.platform_summary()
+    # the explicit override still pins a roofline for such a chip
+    monkeypatch.setenv("LO_PEAK_TFLOPS_PER_CHIP", "100")
+    assert obs_perf.peak_flops_per_chip() == pytest.approx(100e12)
 
 
 # ------------------------------------------------- roofline math

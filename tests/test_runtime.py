@@ -173,7 +173,7 @@ def test_checkpointer_roundtrip(tmp_config, tmp_path):
     ck = Checkpointer(str(tmp_path / "ckpt"))
     ck.save(1, tree)
     ck.save(2, jax.tree_util.tree_map(lambda v: v * 2, tree))
-    ck._mgr.wait_until_finished()
+    ck.wait_until_finished()
     assert ck.latest_step() == 2
     restored = ck.restore(tree)
     assert np.allclose(restored["a"], np.arange(4.0) * 2)

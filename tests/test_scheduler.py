@@ -255,7 +255,7 @@ def test_mesh_yield_config_disables_preemption(tmp_config):
 def test_job_manager_fair_pools(tmp_config):
     """End-to-end through JobManager: a long train job yields between
     epochs and a tune job submitted later finishes FIRST instead of
-    waiting for the whole train (VERDICT round-4 item 3)."""
+    waiting for the whole train."""
     from learningorchestra_tpu.catalog import Catalog
     from learningorchestra_tpu.services.jobs import JobManager
 

@@ -485,7 +485,7 @@ def test_generate_through_predict_verb(server):
 
 
 def test_train_checkpoint_and_patch_resume(server):
-    """checkpoint: true saves per-epoch orbax steps under the execution
+    """checkpoint: true saves per-epoch step dirs under the execution
     name; PATCH re-runs the same execution and resumes from them."""
     import os
 

@@ -1,0 +1,100 @@
+"""The main path's Pallas kernels, asked of the TPU's own compiler.
+
+No chip is attached here: the installed TPU compiler compiles for a
+DESCRIBED ``v5e:2x2`` topology, which refuses what interpret mode on
+the CPU cannot see (tile alignment, VMEM budget, Mosaic lowering).
+Nothing runs, so these say nothing about results or times — they guard
+"the kernel still compiles for the chip" at ~2 s each and no chip time.
+
+The topology is described inside a module-scoped fixture and never at
+import: only one process may load the TPU library, and under xdist
+every worker imports every test file. Keep these cases in ONE file and
+compile in the test's own process.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from learningorchestra_tpu.ops import attention as attn
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache
+    # but can never be read back without a chip: keep the cache off
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, sharding, *shapes, dtype=jnp.bfloat16) -> str:
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _loss(fn):
+    def loss(q, k, v):
+        return fn(q, k, v).astype(jnp.float32).sum()
+    return loss
+
+
+SMOKE = (16, 1024, 16, 64)  # chip_smoke.py's own training shape
+
+# (id, q shape, kv shape, flash kwargs, differentiate)
+CASES = [
+    ("smoke_fwd", SMOKE, SMOKE, {"causal": True}, False),
+    ("smoke_fwd_bwd", SMOKE, SMOKE, {"causal": True}, True),
+    ("gqa_16_4_d128", (2, 2048, 16, 128), (2, 2048, 4, 128),
+     {"causal": True}, True),
+    ("window_256", (4, 1024, 8, 64), (4, 1024, 8, 64),
+     {"causal": True, "window": 256}, True),
+    ("unaligned_seq_1100", (2, 1100, 8, 64), (2, 1100, 8, 64),
+     {"causal": True}, True),
+]
+
+
+@pytest.mark.parametrize("qs,kvs,kwargs,grad",
+                         [c[1:] for c in CASES],
+                         ids=[c[0] for c in CASES])
+def test_flash_attention_compiles_for_v5e(one_chip, qs, kvs, kwargs,
+                                          grad):
+    fn = functools.partial(attn.flash_attention, interpret=False,
+                           **kwargs)
+    if grad:
+        fn = jax.value_and_grad(_loss(fn), argnums=(0, 1, 2))
+    text = _compiled_text(fn, one_chip, qs, kvs, kvs)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_ring_hop_kernel_compiles_for_v5e(one_chip, grad):
+    """``flash_attention_with_lse`` at a non-zero ``kv_offset`` — the
+    per-hop block of ring attention (parallel/ring.py), with the
+    gradient flowing through BOTH outputs as the lse merge needs."""
+    def hop(q, k, v):
+        o, lse = attn.flash_attention_with_lse(
+            q, k, v, causal=True, kv_offset=-1024, interpret=False)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    fn = jax.value_and_grad(hop, argnums=(0, 1, 2)) if grad else hop
+    shape = (2, 1024, 16, 64)
+    text = _compiled_text(fn, one_chip, shape, shape, shape)
+    assert "tpu_custom_call" in text
