@@ -27,6 +27,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import dill
 
+from learningorchestra_tpu.observability import trace as obs_trace
+
 
 class ArtifactNotFound(Exception):
     pass
@@ -49,6 +51,11 @@ def _validate_type(type_string: str) -> str:
     if len(parts) != 2 or not all(_NAME_RE.match(p) for p in parts):
         raise ValueError(f"invalid artifact type: {type_string!r}")
     return type_string
+
+
+def _dir_bytes(d: str) -> int:
+    return sum(os.path.getsize(os.path.join(root, f))
+               for root, _, files in os.walk(d) for f in files)
 
 
 class ArtifactStore:
@@ -142,19 +149,21 @@ class ArtifactStore:
             raise ArtifactNotFound(f"{type_string}/{name}")
         with open(meta_path) as f:
             meta = json.load(f)
-        if meta["kind"] == "native":
-            module = importlib.import_module(meta["module"])
-            cls = module
-            for part in meta["class"].split("."):
-                cls = getattr(cls, part)
-            return cls.__lo_load__(os.path.join(d, "native"))
-        elif meta["kind"] == "dill":
-            with open(os.path.join(d, "object.dill"), "rb") as f:
-                return dill.load(f)
-        elif meta["kind"] == "bytes":
-            with open(os.path.join(d, meta["filename"]), "rb") as f:
-                return f.read()
-        raise ValueError(f"unknown artifact kind {meta['kind']!r}")
+        with obs_trace.span("artifactLoad", artifact=name,
+                            bytes=_dir_bytes(d)):
+            if meta["kind"] == "native":
+                module = importlib.import_module(meta["module"])
+                cls = module
+                for part in meta["class"].split("."):
+                    cls = getattr(cls, part)
+                return cls.__lo_load__(os.path.join(d, "native"))
+            elif meta["kind"] == "dill":
+                with open(os.path.join(d, "object.dill"), "rb") as f:
+                    return dill.load(f)
+            elif meta["kind"] == "bytes":
+                with open(os.path.join(d, meta["filename"]), "rb") as f:
+                    return f.read()
+            raise ValueError(f"unknown artifact kind {meta['kind']!r}")
 
     # ------------------------------------------------------------------
     def save_bytes(self, data: bytes, name: str, type_string: str,

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from learningorchestra_tpu.observability import trace as obs_trace
 from learningorchestra_tpu.ops import attention as attn_ops
 from learningorchestra_tpu.parallel import moe as moe_lib
 from learningorchestra_tpu.parallel import ring as ring_lib
@@ -693,7 +694,9 @@ class TransformerLM(nn.Module):
         mesh = self.mesh or mesh_lib.current_mesh()
         fuse = self.fused_proj
 
-        x = nn.Embed(self.vocab_size, self.d_model, name="embed")(tokens)
+        with jax.named_scope("embed"):
+            x = nn.Embed(self.vocab_size, self.d_model,
+                         name="embed")(tokens)
         if decode_pos is None:
             x = sharding_lib.constrain(
                 x, mesh, mesh_lib.data_axes(mesh) or None,
@@ -756,6 +759,7 @@ def _token_targets(batch, weights):
     return tgt, tok_mask
 
 
+@jax.named_scope("head_loss")
 def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
                      aux_coef: float):
     """Chunked vocab-projection + softmax cross-entropy: scans token
@@ -807,6 +811,7 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
     return loss, {"accuracy": (ok_sum, total)}
 
 
+@jax.named_scope("head_loss")
 def _fused_head_loss_sharded(out: FusedHeadOut, batch, weights,
                              chunk: int, aux_coef: float, mesh):
     """Sequence-parallel twin of :func:`_fused_head_loss`: under
@@ -1252,10 +1257,13 @@ class TextClassifier:
         model.history = config["history"]
         if config["built"]:
             sample = np.zeros((1, 8), np.int32)
-            model._build_params(sample)
-            restored = ckpt.load_pytree(
-                os.path.join(path, "weights.msgpack"),
-                {"params": model.params})
+            weights = os.path.join(path, "weights.msgpack")
+            with obs_trace.span("paramInit"):
+                model._build_params(sample)
+            with obs_trace.span("weightsRead",
+                                bytes=os.path.getsize(weights)):
+                restored = ckpt.load_pytree(weights,
+                                            {"params": model.params})
             model.params = restored["params"]
         return model
 
@@ -2574,9 +2582,12 @@ class LanguageModel:
         model.history = config["history"]
         if config["built"]:
             sample = np.zeros((1, 8), np.int32)
-            model._build_params(sample)
-            restored = ckpt.load_pytree(
-                os.path.join(path, "weights.msgpack"),
-                {"params": model.params})
+            weights = os.path.join(path, "weights.msgpack")
+            with obs_trace.span("paramInit"):
+                model._build_params(sample)
+            with obs_trace.span("weightsRead",
+                                bytes=os.path.getsize(weights)):
+                restored = ckpt.load_pytree(weights,
+                                            {"params": model.params})
             model.params = restored["params"]
         return model

@@ -13,7 +13,8 @@ Registered series (docs/OBSERVABILITY.md):
 - ``lo_lease_wait_seconds`` — slice-lease queue wait per grant;
 - ``lo_serving_request_seconds`` — serving request latency
   (submit → respond);
-- ``lo_compile_seconds`` — engine compile/lowering wall clock;
+- ``lo_compile_seconds`` — one observation per engine ``compile``
+  span (a step call that built an executable);
 - ``lo_checkpoint_commit_seconds`` — checkpoint commit wall clock.
 """
 

@@ -17,6 +17,20 @@ serving batcher finishing a request admitted on an HTTP thread) uses
 the explicit ``trace=`` / ``parent=`` arguments, or :func:`add` to
 record an already-measured interval retroactively.
 
+One clock with the profiler: while a :func:`span` is open it also
+holds a ``jax.profiler.TraceAnnotation`` of the same name, so in a
+profiler session (``benchmark/run.py --trace 1``, ``POST /profile``,
+an incident capture) every live span lies in the host plane of the
+same ``.xplane.pb`` as the device's ops. :func:`add` records an
+interval that is already over and so never reaches the profiler.
+
+Compile activity: the first live span registers ``jax.monitoring``
+listeners (once a process) that add what jax traced, lowered, built
+or loaded from its compilation cache to the calling thread's open
+span (``traceSeconds``, ``lowerSeconds``, ``backendCompileSeconds``,
+``cacheLoadSeconds``, ``cacheHits``, ``cacheMisses``, ``builds``):
+the span a build happened in names the dispatch that caused it.
+
 Thread-safe; bounded (``LO_TRACE_RING`` spans per trace, at most
 ``_MAX_TRACES`` traces, LRU-evicted); and when ``LO_TRACE=0`` every
 call degrades to a shared no-op object — no allocation, no lock.
@@ -31,6 +45,24 @@ from typing import Any, Dict, List, Optional, Tuple
 from learningorchestra_tpu.runtime import locks
 
 _MAX_TRACES = 256
+# span attrs handed to the profiler as the annotation's arguments
+_ANNOTATION_ARGS = ("epoch", "executable")
+# jax.monitoring durations -> the attr of the open span they add to.
+# backend_compile wraps jax's compile-or-load-from-cache, so it fires
+# once per executable built (``builds``) and contains the cache load.
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "traceSeconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerSeconds",
+    "/jax/core/compile/backend_compile_duration": "backendCompileSeconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cacheLoadSeconds",
+}
+_COMPILE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cacheHits",
+    "/jax/compilation_cache/cache_misses": "cacheMisses",
+}
+# every attr the listeners may add to a span
+COMPILE_ATTRS = ("builds", *_COMPILE_DURATIONS.values(),
+                 *_COMPILE_EVENTS.values())
 
 _lock = locks.make_lock("trace.registry")
 _traces: "collections.OrderedDict[str, _Trace]" = collections.OrderedDict()
@@ -55,7 +87,7 @@ class Span:
     marking ``cacheHit`` on an open compile span)."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start",
-                 "end", "attrs", "thread")
+                 "end", "attrs", "thread", "annotation", "intervals")
 
     def __init__(self, trace_id: str, span_id: int,
                  parent_id: Optional[int], name: str,
@@ -69,6 +101,11 @@ class Span:
         self.end: Optional[float] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.thread = thread
+        # the profiler annotation held while a live span is open
+        self.annotation: Any = None
+        # compile attr -> [seconds, [(start, seconds)]]: the total and
+        # the outermost intervals it is the sum of
+        self.intervals: Optional[Dict[str, List[Any]]] = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -120,13 +157,30 @@ class _Trace:
                   attrs)
         self.spans[sid] = sp
         while len(self.spans) > self.ring:
-            # oldest finished span first; never drop an open span
-            victim = next((k for k, s in self.spans.items()
-                           if s.end is not None), None)
-            if victim is None:
-                victim = next(iter(self.spans))
-            del self.spans[victim]
+            self._evict()
         return sp
+
+    def _evict(self) -> None:
+        """Make room. A long fit leaves some six spans an epoch, so
+        its oldest finished ``epoch`` goes first, children and all:
+        what is outside an epoch (``submit``, ``dataLoad``, every
+        ``compile``) outlives any number of epochs. Else the oldest
+        finished span; never an open one."""
+        root = next((s for s in self.spans.values()
+                     if s.name == "epoch" and s.end is not None), None)
+        if root is not None:
+            doomed = {root.span_id}
+            for sid, s in self.spans.items():  # parents come first
+                if s.parent_id in doomed:
+                    doomed.add(sid)
+            for sid in doomed:
+                del self.spans[sid]
+            return
+        victim = next((k for k, s in self.spans.items()
+                       if s.end is not None), None)
+        if victim is None:
+            victim = next(iter(self.spans))
+        del self.spans[victim]
 
 
 def _get_trace(trace_id: str, create: bool) -> Optional[_Trace]:
@@ -206,6 +260,8 @@ class _SpanCtx:
         if etype is not None:
             self.sp.attrs.setdefault("error", etype.__name__)
         self.sp.end = time.monotonic()
+        self.sp.intervals = None
+        _close_annotation(self.sp)
         if self._pushed:
             st = _stack()
             if st and st[-1] is self.sp:
@@ -244,16 +300,84 @@ def span(name: str, trace: Optional[str] = None,
         tr = _get_trace(trace, create=True)
         sp = tr.new_span(name, parent, now, attrs or None, tname)
     _stack().append(sp)
+    _open_annotation(sp)
     return _SpanCtx(sp, pushed=True)
+
+
+_annotation_cls: Any = None  # jax's TraceAnnotation; False without jax
+
+
+def _open_annotation(sp: Span) -> None:
+    """Hold a profiler annotation of the span's name while it is open
+    (a ``TraceMe`` flag check when no profiler session runs), and on
+    the first live span register the compile listeners. ``jax`` is
+    imported here so that this module imports without it."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            import jax
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_duration)
+            jax.monitoring.register_event_listener(_on_compile_event)
+            _annotation_cls = jax.profiler.TraceAnnotation
+        except ImportError:
+            _annotation_cls = False
+    if _annotation_cls:
+        sp.annotation = _annotation_cls(sp.name)
+        sp.annotation.__enter__()
+
+
+def _close_annotation(sp: Span) -> None:
+    ann, sp.annotation = sp.annotation, None
+    if ann is not None:
+        args = {k: sp.attrs[k] for k in _ANNOTATION_ARGS
+                if k in sp.attrs}
+        if args:
+            ann.set_metadata(**args)
+        ann.__exit__(None, None, None)
+
+
+def _on_compile_duration(event: str, duration: float, **_: Any) -> None:
+    attr = _COMPILE_DURATIONS.get(event)
+    st = getattr(_tls, "stack", None)
+    if attr is None or not st:
+        return
+    sp = st[-1]
+    # a jit traced inside another's trace reports its seconds first
+    # and the caller's hold them again: keep the outermost intervals,
+    # with a running total (a step's trace reports thousands)
+    end = time.monotonic()
+    start = end - duration
+    if sp.intervals is None:
+        sp.intervals = {}
+    entry = sp.intervals.setdefault(attr, [0.0, []])
+    kept = entry[1]
+    while kept and kept[-1][0] >= start:
+        entry[0] -= kept.pop()[1]
+    kept.append((start, duration))
+    entry[0] += duration
+    annotate(**{attr: round(entry[0], 6)})
+    if attr == "backendCompileSeconds":
+        annotate(builds=len(kept))
+
+
+def _on_compile_event(event: str, **_: Any) -> None:
+    attr = _COMPILE_EVENTS.get(event)
+    st = getattr(_tls, "stack", None)
+    if attr is not None and st:
+        annotate(**{attr: st[-1].attrs.get(attr, 0) + 1})
 
 
 def add(name: str, trace: str, start: float, end: float,
         parent: Optional[int] = None, **attrs: Any) -> Optional[int]:
     """Record an already-measured interval (monotonic seconds) — the
     retro path for code that batches work across threads (serving)
-    and only knows the boundaries after the fact. Returns the new
-    span's id (for parenting follow-up spans), or None when
-    disabled."""
+    and only knows the boundaries after the fact. Such a span is over
+    before it is recorded, so it holds no profiler annotation and
+    takes no compile attrs: it does not reach a profiler capture.
+    Returns the new span's id (for parenting follow-up spans), or None
+    when disabled."""
     if not _enabled():
         return None
     tname = threading.current_thread().name
@@ -333,11 +457,6 @@ def durations_by_name(trace_id: str) -> Dict[str, float]:
 def known_traces() -> List[str]:
     with _lock:
         return list(_traces.keys())
-
-
-def discard(trace_id: str) -> None:
-    with _lock:
-        _traces.pop(trace_id, None)
 
 
 def reset() -> None:

@@ -20,6 +20,14 @@ log-sum-exp; the (seq × seq) matrix is never materialised):
 runs skip fully-masked tiles in all three kernels (grid-level
 ``pl.when``), halving causal FLOPs.
 
+Names on the device: the three kernels are ``flash_fwd``,
+``flash_bwd_dq`` and ``flash_bwd_dkv`` (``name=``; the TPU compiler
+takes it into the instruction name, ``%flash_fwd.1 = … custom-call``),
+which is how a profiler trace and ``benchmark/layer_metrics`` tell them
+apart. The decode paths below are plain XLA under the scopes
+``decode_attn``, ``paged_decode_attn`` and
+``quantized_paged_decode_attn`` (``op_name`` metadata).
+
 The reference framework has no attention op at all (SURVEY §5
 "long-context" row — sequence models run inside user TF code through
 the generic executor, binary_execution.py:177-189); flash attention is
@@ -327,6 +335,7 @@ def _fwd_pallas(q, k, v, *, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o[..., :d], lse[..., 0]
 
@@ -535,6 +544,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse_l, delta_l)
 
     # second kernel: K/V resident, Q streams — grid dims (b, j, i)
@@ -560,6 +570,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse_l, delta_l)
     return (dq[..., :d], dk[:, :sk, :d], dv[:, :sk, :d])
 
@@ -800,6 +811,7 @@ def reference_attention(q, k, v, causal: bool = False,
 # q is one row, the op is bandwidth-bound on the KV cache read.
 
 
+@jax.named_scope("decode_attn")
 def decode_attention(q: jax.Array, k_cache: jax.Array,
                      v_cache: jax.Array, col: jax.Array, *,
                      pad_offset: Optional[jax.Array] = None,
@@ -844,6 +856,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array,
     return o.reshape(b, s, h, d).astype(q.dtype)
 
 
+@jax.named_scope("paged_decode_attn")
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array,
                            block_tables: jax.Array,
@@ -1175,6 +1188,7 @@ def quantized_paged_verify_attention(q: jax.Array, k_pool: jax.Array,
     return jnp.concatenate(outs, axis=1)
 
 
+@jax.named_scope("quantized_paged_decode_attn")
 def quantized_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                                      k_scales: jax.Array,
                                      v_pool: jax.Array,

@@ -17,6 +17,7 @@ This is what replaces the reference's hot loop — ``getattr(instance,
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import threading
 import time
@@ -200,27 +201,33 @@ class Engine:
         self._health_on = False
         self._health_skip = False
         self._health_sig: Optional[tuple] = None
+        # spans of the running fit (docs/OBSERVABILITY.md): the span
+        # the fit runs under and the ``compile`` spans it has left
+        self._fit_anchor: Optional[Tuple[str, int]] = None
+        self._builds = 0
 
     # ------------------------------------------------------------------
     def init_state(self, params, model_state=None) -> TrainState:
-        if self._mesh is not None and self._param_rules is not None:
-            from learningorchestra_tpu.parallel import sharding as rules_lib
+        with obs_trace.span("initState"):
+            if self._mesh is not None and self._param_rules is not None:
+                from learningorchestra_tpu.parallel import \
+                    sharding as rules_lib
 
-            shardings = rules_lib.param_shardings(
-                params, self._mesh, self._param_rules, fsdp=self._fsdp)
-            params = jax.device_put(params, shardings)
-            opt_state = self._init_opt_state_on_mesh(params)
-            rep = mesh_lib.replicated(self._mesh)
-            return TrainState(
-                step=jax.device_put(jnp.zeros((), jnp.int32), rep),
-                params=params, opt_state=opt_state,
-                model_state=jax.device_put(model_state or {}, rep))
-        state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                           opt_state=self._optimizer.init(params),
-                           model_state=model_state or {})
-        if self._mesh is not None:
-            state = jax.device_put(state, mesh_lib.replicated(self._mesh))
-        return state
+                shardings = rules_lib.param_shardings(
+                    params, self._mesh, self._param_rules, fsdp=self._fsdp)
+                params = jax.device_put(params, shardings)
+                opt_state = self._init_opt_state_on_mesh(params)
+                rep = mesh_lib.replicated(self._mesh)
+                return TrainState(
+                    step=jax.device_put(jnp.zeros((), jnp.int32), rep),
+                    params=params, opt_state=opt_state,
+                    model_state=jax.device_put(model_state or {}, rep))
+            state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                               opt_state=self._optimizer.init(params),
+                               model_state=model_state or {})
+            if self._mesh is not None:
+                state = jax.device_put(state, mesh_lib.replicated(self._mesh))
+            return state
 
     def _init_opt_state_on_mesh(self, params):
         """Optimizer state for rules-sharded ``params``: jit propagates
@@ -260,8 +267,12 @@ class Engine:
         weights = batch.get(data_lib.MASK_KEY)
 
         def loss_of(p):
+            # named with the update: PERF.md ranks AdamW and the master
+            # weights' cast to the compute dtype as one phase
+            with jax.named_scope("optimizer"):
+                p = self._cast(p)
             outputs, new_model_state = self._apply_fn(
-                self._cast(p), model_state, self._cast(batch), True, rng)
+                p, model_state, self._cast(batch), True, rng)
             res = self._loss_fn(outputs, batch, weights)
             # a loss_fn may return (loss, {metric: (sum, count)}) to
             # emit metrics it already computed — the fused-lm-head
@@ -301,9 +312,10 @@ class Engine:
                 jnp.maximum(loss_cnt.astype(jnp.float32), 1e-9)
             bad = jnp.logical_or(~jnp.isfinite(mean_loss),
                                  ~jnp.isfinite(optax.global_norm(grads)))
-        updates, new_opt = self._optimizer.update(
-            grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self._optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   opt_state=new_opt,
                                   model_state=new_model_state)
@@ -526,37 +538,50 @@ class Engine:
             self._step_flops, self._step_bytes or 0.0, steps, dt,
             n_dev))
 
-    def _observe_window(self, mono0: float, dt: float,
+    def _begin_fit(self) -> None:
+        self._fit_anchor = obs_trace.current()
+        self._builds = 0
+
+    def _call_in(self, ctx, fn: Callable, *args):
+        """``fn(*args)`` inside the open ``dispatch`` span ``ctx``. A
+        jit call returns when its executable is built and enqueued, so
+        when the tracer's listeners counted a build during the call,
+        the call's own interval is recorded as a ``compile`` span —
+        beside the epochs, not inside one — with what the call added
+        to the span's compile attrs, ``executable`` (the n-th building
+        call of this fit) and ``cold``/``cacheHit`` (XLA compiled it /
+        the persistent compilation cache held it)."""
+        before = {k: ctx.attrs.get(k, 0) for k in obs_trace.COMPILE_ATTRS}
+        t0 = time.monotonic()
+        out = fn(*args)
+        if ctx.attrs.get("builds", 0) != before["builds"]:
+            t1 = time.monotonic()
+            built = {k: round(ctx.attrs[k] - before[k], 6)
+                     for k in obs_trace.COMPILE_ATTRS if k in ctx.attrs}
+            self._builds += 1
+            hit = built.get("cacheHits", 0) > 0
+            trace_id, parent = self._fit_anchor
+            obs_trace.add("compile", trace_id, t0, t1, parent=parent,
+                          cold=not hit, cacheHit=hit,
+                          executable=self._builds,
+                          epoch=ctx.attrs.get("epoch"), **built)
+            obs_hist.observe("lo_compile_seconds", t1 - t0)
+        return out
+
+    def _observe_window(self, epoch_span, dt: float,
                         record: Dict[str, Any], bad_steps: int, *,
-                        step: int, epoch: int, first: bool,
-                        cold: bool,
-                        compile_end: Optional[float] = None) -> None:
-        """Feed the observability plane once per step-window: an
-        ``epoch`` span (+ a ``compile`` span on the first window,
-        its ``cold``/``cacheHit`` attrs distinguishing a first trace
-        from an executable-cache hit) under the job's current span,
-        and one timeline ring entry. Reuses values the fit loop /
-        health sentinel already pulled to the host — no extra device
-        syncs — and is best-effort: it must never sink a fit."""
+                        step: int, epoch: int, builds: int) -> None:
+        """Close a step-window's bookkeeping: the loss on its (live)
+        ``epoch`` span and one timeline ring entry. Reuses values the
+        fit loop / health sentinel already pulled to the host — no
+        extra device syncs — and is best-effort: it must never sink a
+        fit."""
         try:
-            cur = obs_trace.current()
-            if cur is None:
+            if self._fit_anchor is None:
                 return
-            trace_id, parent = cur
-            end = mono0 + dt
-            if first:
-                c_end = compile_end if compile_end is not None else end
-                obs_trace.add("compile", trace_id, mono0, c_end,
-                              parent=parent, cold=bool(cold),
-                              cacheHit=not cold)
-                if cold:
-                    obs_hist.observe("lo_compile_seconds",
-                                     c_end - mono0)
-            attrs: Dict[str, Any] = {"epoch": epoch}
+            trace_id = self._fit_anchor[0]
             if record.get("loss") is not None:
-                attrs["loss"] = round(float(record["loss"]), 6)
-            obs_trace.add("epoch", trace_id, mono0, end, parent=parent,
-                          **attrs)
+                epoch_span.set(loss=round(float(record["loss"]), 6))
             # roofline block (stamped on the record by
             # _roofline_record): rides the same ring entry so the
             # timeline answers "how fast vs the hardware" per window,
@@ -572,7 +597,7 @@ class Engine:
                     "samplesPerSecond", 0.0),
                 loss=record.get("loss"),
                 bad_steps=bad_steps if bad_steps else None,
-                retrace=bool(first and cold),
+                retrace=builds > 0,
                 **perf_block)
             if perf_block:
                 obs_perf.record_job(trace_id, dict(
@@ -580,14 +605,17 @@ class Engine:
         except Exception:  # noqa: BLE001 — observability is advisory
             pass
 
-    def _measure_flops(self, state, batch, rng, step_fn=None) -> None:
+    def _measure_flops(self, state, batch, rng, step_fn=None,
+                       epoch: int = 0) -> None:
         """Per-step flop + bytes-accessed estimate from the lowered HLO
         (cheap — no compile). Basis for the MFU line and the roofline
         block in every history record. Also feeds the X-ray plane: the
         retrace sentinel sees every (program, batch-signature) pair —
         a warm program under a NEW signature is a recompile — and the
         compiled step's memory/cost analysis is captured once per cold
-        executable key for ``GET /observability/compile/{name}``."""
+        executable key for ``GET /observability/compile/{name}``. The
+        lowering (a second full trace of the step) is a
+        ``measureFlops`` span: a warm fit has none."""
         key = tuple(sorted((k, tuple(v.shape)) for k, v in batch.items()))
         self._note_signature(key)
         if self._step_flops is not None and key == self._flops_key:
@@ -603,34 +631,36 @@ class Engine:
                 self._record_compile_xray(_XRAY_CACHE.get(shared_key))
                 return
         self._flops_key = key
-        try:
-            fn = step_fn if step_fn is not None else self._train_step
-            lowered = fn.lower(state, batch, rng)
-            compiled = None
-            cost = lowered.cost_analysis()
-            if not cost or not cost.get("flops"):
-                # some PJRT backends only report costs on the compiled
-                # executable (one extra compile, once per batch shape)
-                compiled = lowered.compile()
-                cost = compiled.cost_analysis()
-            flops = float(cost.get("flops", 0.0)) if cost else 0.0
-            self._step_flops = flops if flops > 0 else 0.0
-            bytes_acc = (float(cost.get("bytes accessed", 0.0))
-                         if cost else 0.0)
-            self._step_bytes = bytes_acc if bytes_acc > 0 else 0.0
-            self._capture_xray(shared_key, lowered, compiled, key)
-        except Exception:  # noqa: BLE001 — accounting must never sink a run
-            self._step_flops = 0.0
-            self._step_bytes = 0.0
-        if self._flops_floor_fn is not None:
+        with obs_trace.span("measureFlops", epoch=epoch):
             try:
-                # the floor corrects custom calls' ZERO reported flops;
-                # their bytes ARE counted (operands/results), so only
-                # the flop side is raised
-                floor = float(self._flops_floor_fn(batch))
-                self._step_flops = max(self._step_flops or 0.0, floor)
-            except Exception:  # noqa: BLE001
-                pass
+                fn = step_fn if step_fn is not None else self._train_step
+                lowered = fn.lower(state, batch, rng)
+                compiled = None
+                cost = lowered.cost_analysis()
+                if not cost or not cost.get("flops"):
+                    # some PJRT backends only report costs on the
+                    # compiled executable (one extra compile, once per
+                    # batch shape)
+                    compiled = lowered.compile()
+                    cost = compiled.cost_analysis()
+                flops = float(cost.get("flops", 0.0)) if cost else 0.0
+                self._step_flops = flops if flops > 0 else 0.0
+                bytes_acc = (float(cost.get("bytes accessed", 0.0))
+                             if cost else 0.0)
+                self._step_bytes = bytes_acc if bytes_acc > 0 else 0.0
+                self._capture_xray(shared_key, lowered, compiled, key)
+            except Exception:  # noqa: BLE001 — accounting never sinks a run
+                self._step_flops = 0.0
+                self._step_bytes = 0.0
+            if self._flops_floor_fn is not None:
+                try:
+                    # the floor corrects custom calls' ZERO reported
+                    # flops; their bytes ARE counted (operands/results),
+                    # so only the flop side is raised
+                    floor = float(self._flops_floor_fn(batch))
+                    self._step_flops = max(self._step_flops or 0.0, floor)
+                except Exception:  # noqa: BLE001
+                    pass
         if shared_key is not None and self._step_flops is not None:
             _FLOPS_CACHE[shared_key] = (self._step_flops,
                                         self._step_bytes or 0.0)
@@ -1111,21 +1141,12 @@ class Engine:
         bs = batcher.batch_size
         key = (steps, bs, batcher.shuffles)
         epoch_step = self._epoch_steps.get(key)
-        # cold = this fit will trace+compile its epoch program on the
-        # first dispatch; warm = a process-wide executable-cache hit
-        # (jax's dispatch cache makes the first call steady-state).
-        # The distinction rides on the compile span (docs/
-        # OBSERVABILITY.md).
-        compile_cold = False
         if epoch_step is None:
-            before_misses = _EXEC_STATS["misses"]
             epoch_step = self._epoch_steps[key] = self._shared_step(
                 "epoch",
                 lambda: self._build_epoch_step(steps, bs,
                                                batcher.shuffles),
                 extra=key)
-            compile_cold = (self._exec_key("epoch", key) is None or
-                            _EXEC_STATS["misses"] > before_misses)
         base_rng = jax.random.PRNGKey(seed)
         shuffle_rng = _shuffle_rng(batcher.seed)
         # one host->HBM transfer for the whole fit; epochs shuffle in
@@ -1137,22 +1158,28 @@ class Engine:
         token = getattr(batcher, "cache_token", None)
         entry = None
 
+        staged = []
+
         def stage() -> Dict[str, Any]:
+            staged.append(True)
             return {k: data_lib.stage_to_device(v, sharding)
                     for k, v in batcher.padded_arrays().items()}
 
-        if token is not None:
-            entry = arena_lib.get_default_arena().get_or_put(
-                ("fit_arrays", token, steps, bs, batcher.shuffles,
-                 self._mesh, sharding),
-                stage, tags=getattr(batcher, "cache_tags", ()),
-                # slice-scheduled fits budget against their slice's
-                # share of HBM, not the whole arena
-                group=self._mesh,
-                group_fraction=mesh_lib.mesh_fraction(self._mesh))
-            device_arrays = entry.arrays
-        else:
-            device_arrays = stage()
+        with obs_trace.span("stage") as stage_span:
+            if token is not None:
+                entry = arena_lib.get_default_arena().get_or_put(
+                    ("fit_arrays", token, steps, bs, batcher.shuffles,
+                     self._mesh, sharding),
+                    stage, tags=getattr(batcher, "cache_tags", ()),
+                    # slice-scheduled fits budget against their slice's
+                    # share of HBM, not the whole arena
+                    group=self._mesh,
+                    group_fraction=mesh_lib.mesh_fraction(self._mesh))
+                device_arrays = entry.arrays
+            else:
+                device_arrays = stage()
+            stage_span.set(bytes=_tree_nbytes(device_arrays),
+                           arenaHit=not staged)
         history: List[Dict[str, Any]] = []
         sent = self._new_sentinel()
         # last-good fallback when no checkpoint step exists yet (or
@@ -1171,59 +1198,70 @@ class Engine:
                 preempt.heartbeat(epoch=epoch,
                                   rollbacks=sent["rollbacks"])
                 t0 = time.perf_counter()
-                mono0 = time.monotonic()
-                if epoch == start_epoch and sent["rollbacks"] == 0:
-                    # sliced from the device copy so an arena hit never
-                    # re-materializes the padded host arrays
-                    one = {k: v[:bs] for k, v in device_arrays.items()}
-                    self._measure_flops(
-                        state, one, base_rng,
-                        step_fn=jax.jit(self._train_step_body))
-                arrays_in = device_arrays
-                if _armed_nan():
-                    arrays_in = _poison_rows(device_arrays, bs)
-                rb = sent["rollbacks"]
-                step_rng = (base_rng if rb == 0 else jax.random.fold_in(
-                    base_rng, _HEALTH_TAG + rb))
-                # once-per-epoch dispatch: the sentinel wrapper is
-                # off the per-step path, so it is always-on here
-                state, totals = obs_xray.guarded_call(
-                    epoch_step, state, arrays_in, step_rng, shuffle_rng,
-                    jnp.asarray(epoch + rb * _ROLLBACK_STRIDE))
-                jax.block_until_ready(state.params)
-                dt = time.perf_counter() - t0
-                bad_steps = self._pop_bad_steps(totals)
-                record = {k: float(s) / max(float(c), 1e-9)
-                          for k, (s, c) in totals.items()}
-                if policy is not None:
-                    proceed, state, event = self._health_epoch_end(
-                        policy, sent, epoch, bad_steps,
-                        record.get("loss", float("nan")), state,
-                        checkpointer, snapshot, log_fn)
-                    if not proceed:
-                        continue  # re-run this epoch from last-good
-                    if event is not None and bad_steps:
-                        record["nonfiniteSteps"] = bad_steps
-                    if checkpointer is None and \
-                            policy.action == "rollback":
-                        snapshot = to_host(state)
-                record.update(epoch=epoch, epochSeconds=round(dt, 4),
-                              samplesPerSecond=round(
-                                  batcher.num_samples / dt, 2))
-                # compile epoch has no steady-state window in scan
-                # mode; roofline numbers start with the second epoch
-                if epoch > start_epoch:
-                    self._roofline_record(record, steps, dt)
-                self._observe_window(
-                    mono0, dt, record, bad_steps,
-                    step=(epoch + 1) * steps, epoch=epoch,
-                    first=epoch == start_epoch,
-                    cold=compile_cold)
-                history.append(record)
-                if checkpointer is not None:
-                    self._save_checkpoint(checkpointer, state, epoch)
-                if log_fn is not None:
-                    log_fn(record)
+                with obs_trace.span("epoch", epoch=epoch) as epoch_span:
+                    if epoch == start_epoch and sent["rollbacks"] == 0:
+                        # sliced from the device copy so an arena hit
+                        # never re-materializes the padded host arrays
+                        one = {k: v[:bs] for k, v in device_arrays.items()}
+                        self._measure_flops(
+                            state, one, base_rng,
+                            step_fn=jax.jit(self._train_step_body),
+                            epoch=epoch)
+                    arrays_in = device_arrays
+                    if _armed_nan():
+                        arrays_in = _poison_rows(device_arrays, bs)
+                    rb = sent["rollbacks"]
+                    step_rng = (base_rng if rb == 0
+                                else jax.random.fold_in(
+                                    base_rng, _HEALTH_TAG + rb))
+                    epoch_idx = jnp.asarray(epoch + rb * _ROLLBACK_STRIDE)
+                    # once-per-epoch dispatch: the sentinel wrapper is
+                    # off the per-step path, so it is always-on here
+                    with obs_trace.span("dispatch", epoch=epoch) as dispatch:
+                        state, totals = self._call_in(
+                            dispatch, obs_xray.guarded_call, epoch_step,
+                            state, arrays_in, step_rng, shuffle_rng,
+                            epoch_idx)
+                    with obs_trace.span("deviceWait", epoch=epoch):
+                        jax.block_until_ready(state.params)
+                    dt = time.perf_counter() - t0
+                    with obs_trace.span("epochEnd", epoch=epoch):
+                        bad_steps = self._pop_bad_steps(totals)
+                        record = {k: float(s) / max(float(c), 1e-9)
+                                  for k, (s, c) in totals.items()}
+                        if policy is not None:
+                            proceed, state, event = \
+                                self._health_epoch_end(
+                                    policy, sent, epoch, bad_steps,
+                                    record.get("loss", float("nan")),
+                                    state, checkpointer, snapshot,
+                                    log_fn)
+                            if not proceed:
+                                continue  # re-run from last-good
+                            if event is not None and bad_steps:
+                                record["nonfiniteSteps"] = bad_steps
+                            if checkpointer is None and \
+                                    policy.action == "rollback":
+                                snapshot = to_host(state)
+                        record.update(
+                            epoch=epoch, epochSeconds=round(dt, 4),
+                            samplesPerSecond=round(
+                                batcher.num_samples / dt, 2))
+                        # compile epoch has no steady-state window in
+                        # scan mode; roofline numbers start with the
+                        # second epoch
+                        if epoch > start_epoch:
+                            self._roofline_record(record, steps, dt)
+                        self._observe_window(
+                            epoch_span, dt, record, bad_steps,
+                            step=(epoch + 1) * steps, epoch=epoch,
+                            builds=dispatch.attrs.get("builds", 0))
+                        history.append(record)
+                        if checkpointer is not None:
+                            self._save_checkpoint(checkpointer, state,
+                                                  epoch)
+                        if log_fn is not None:
+                            log_fn(record)
                 # fair scheduling: offer the mesh lease to waiting
                 # jobs of other pools (no-op outside the service
                 # layer); the epoch is checkpointed, so the hand-off
@@ -1291,6 +1329,7 @@ class Engine:
         the fit so ``GET /observability/memory`` can attribute the
         resident state while the job runs."""
         self._ledger_state(state)
+        self._begin_fit()
         try:
             return self._fit_impl(state, batcher, epochs=epochs,
                                   seed=seed, checkpointer=checkpointer,
@@ -1338,13 +1377,9 @@ class Engine:
                                      checkpointer, log_fn,
                                      start_epoch=start_epoch,
                                      policy=policy)
-        compile_cold = False
         if self._train_step is None:
-            before_misses = _EXEC_STATS["misses"]
             self._train_step = self._shared_step(
                 "train", self._build_train_step)
-            compile_cold = (self._exec_key("train", ()) is None or
-                            _EXEC_STATS["misses"] > before_misses)
         base_rng = jax.random.PRNGKey(seed)
         history: List[Dict[str, Any]] = []
         sent = self._new_sentinel()
@@ -1363,8 +1398,6 @@ class Engine:
         epoch = start_epoch
         while epoch < epochs:
             t0 = time.perf_counter()
-            mono0 = time.monotonic()
-            compile_mono_end: Optional[float] = None
             # metric accumulation stays on-device (async); one sync at
             # epoch end
             sums: Dict[str, Any] = {}
@@ -1381,70 +1414,97 @@ class Engine:
             # on the compile epoch the roofline window starts after the
             # first step completes (one extra sync, once per fit)
             t_steady, steady_steps = t0, 0
-            for batch in self._device_feed(
-                    batcher, epoch + rb * _ROLLBACK_STRIDE):
-                # per-step lifecycle point (dispatch is async, so this
-                # is host-side and nearly free): a cancelled/expired
-                # job stops mid-epoch instead of finishing it out
-                preempt.check_cancel()
-                preempt.heartbeat(epoch=epoch, step=host_step,
-                                  rollbacks=rb)
-                if poison:
-                    batch = _poison_batch(batch)
-                    poison = False
-                rng = jax.random.fold_in(eff_rng, host_step)
-                host_step += 1
-                if steps == 0 and epoch == start_epoch and rb == 0:
-                    self._measure_flops(state, batch, rng)
-                if guard:
-                    state, metrics = obs_xray.guarded_call(
-                        self._train_step, state, batch, rng)
-                else:
-                    state, metrics = self._train_step(state, batch, rng)
-                if steps == 0 and epoch == start_epoch:
-                    jax.block_until_ready(metrics)
-                    t_steady, steady_steps = time.perf_counter(), -1
-                    # the first step's dispatch+sync window is where
-                    # XLA compiled (on a cold trace) — the compile
-                    # span's boundary (docs/OBSERVABILITY.md)
-                    compile_mono_end = time.monotonic()
-                steps += 1
-                for k, (s, c) in metrics.items():
-                    sums[k] = sums.get(k, 0) + s
-                    counts[k] = counts.get(k, 0) + c
-            jax.block_until_ready(state.params)
-            now = time.perf_counter()
-            dt = now - t0
-            bad_steps = self._pop_bad_steps(sums, counts)
-            record = {k: float(sums[k]) / max(float(counts[k]), 1e-9)
-                      for k in sums}
-            if policy is not None:
-                proceed, state, event = self._health_epoch_end(
-                    policy, sent, epoch, bad_steps,
-                    record.get("loss", float("nan")), state,
-                    checkpointer, snapshot, log_fn)
-                if not proceed:
-                    # re-run this epoch from the rolled-back state; the
-                    # rng step counter rewinds with it
-                    host_step = int(state.step)
-                    continue
-                if event is not None and bad_steps:
-                    record["nonfiniteSteps"] = bad_steps
-                if checkpointer is None and policy.action == "rollback":
-                    snapshot = to_host(state)
-            record.update(epoch=epoch, epochSeconds=round(dt, 4),
-                          samplesPerSecond=round(batcher.num_samples / dt, 2))
-            steady_steps += steps
-            self._roofline_record(record, steady_steps, now - t_steady)
-            self._observe_window(
-                mono0, dt, record, bad_steps, step=host_step,
-                epoch=epoch, first=epoch == start_epoch,
-                cold=compile_cold, compile_end=compile_mono_end)
-            history.append(record)
-            if checkpointer is not None:
-                self._save_checkpoint(checkpointer, state, epoch)
-            if log_fn is not None:
-                log_fn(record)
+            builds = span_steps = 0
+            with obs_trace.span("epoch", epoch=epoch) as epoch_span, \
+                    contextlib.ExitStack() as spans:
+                # a step is too short for a span of its own: the fit's
+                # first step is one dispatch (its sync bounds the first
+                # build), the rest of the epoch's feed loop another
+                dispatch = spans.enter_context(
+                    obs_trace.span("dispatch", epoch=epoch))
+                for batch in self._device_feed(
+                        batcher, epoch + rb * _ROLLBACK_STRIDE):
+                    # per-step lifecycle point (dispatch is async, so
+                    # this is host-side and nearly free): a cancelled/
+                    # expired job stops mid-epoch instead of finishing
+                    # it out
+                    preempt.check_cancel()
+                    preempt.heartbeat(epoch=epoch, step=host_step,
+                                      rollbacks=rb)
+                    if poison:
+                        batch = _poison_batch(batch)
+                        poison = False
+                    rng = jax.random.fold_in(eff_rng, host_step)
+                    host_step += 1
+                    if steps == 0 and epoch == start_epoch and rb == 0:
+                        self._measure_flops(state, batch, rng,
+                                            epoch=epoch)
+                    if guard:
+                        state, metrics = self._call_in(
+                            dispatch, obs_xray.guarded_call,
+                            self._train_step, state, batch, rng)
+                    else:
+                        state, metrics = self._call_in(
+                            dispatch, self._train_step, state, batch,
+                            rng)
+                    steps += 1
+                    span_steps += 1
+                    if steps == 1 and epoch == start_epoch:
+                        dispatch.set(steps=span_steps)
+                        builds, span_steps = \
+                            dispatch.attrs.get("builds", 0), 0
+                        spans.close()
+                        with obs_trace.span("deviceWait", epoch=epoch):
+                            jax.block_until_ready(metrics)
+                        t_steady, steady_steps = time.perf_counter(), -1
+                        dispatch = spans.enter_context(
+                            obs_trace.span("dispatch", epoch=epoch))
+                    for k, (s, c) in metrics.items():
+                        sums[k] = sums.get(k, 0) + s
+                        counts[k] = counts.get(k, 0) + c
+                dispatch.set(steps=span_steps)
+                builds += dispatch.attrs.get("builds", 0)
+                spans.close()
+                with obs_trace.span("deviceWait", epoch=epoch):
+                    jax.block_until_ready(state.params)
+                now = time.perf_counter()
+                dt = now - t0
+                with obs_trace.span("epochEnd", epoch=epoch):
+                    bad_steps = self._pop_bad_steps(sums, counts)
+                    record = {
+                        k: float(sums[k]) / max(float(counts[k]), 1e-9)
+                        for k in sums}
+                    if policy is not None:
+                        proceed, state, event = self._health_epoch_end(
+                            policy, sent, epoch, bad_steps,
+                            record.get("loss", float("nan")), state,
+                            checkpointer, snapshot, log_fn)
+                        if not proceed:
+                            # re-run this epoch from the rolled-back
+                            # state; the rng step counter rewinds with
+                            # it
+                            host_step = int(state.step)
+                            continue
+                        if event is not None and bad_steps:
+                            record["nonfiniteSteps"] = bad_steps
+                        if checkpointer is None and \
+                                policy.action == "rollback":
+                            snapshot = to_host(state)
+                    record.update(
+                        epoch=epoch, epochSeconds=round(dt, 4),
+                        samplesPerSecond=round(
+                            batcher.num_samples / dt, 2))
+                    steady_steps += steps
+                    self._roofline_record(record, steady_steps,
+                                          now - t_steady)
+                    self._observe_window(
+                        epoch_span, dt, record, bad_steps, step=host_step,
+                        epoch=epoch, builds=builds)
+                    history.append(record)
+                    if checkpointer is not None:
+                        self._save_checkpoint(checkpointer, state, epoch)
+                    if log_fn is not None:
+                        log_fn(record)
             epoch += 1
             if epoch < epochs:  # fair scheduling (see _fit_scanned)
                 state, migrated = self._maybe_migrate(
@@ -1731,6 +1791,7 @@ class FusedEngine(Engine):
         + ``score_fn`` and fires once a config's EMA validation score
         trails the cohort best by more than ``earlystop["margin"]``."""
         self._ledger_state(state)
+        self._begin_fit()
         try:
             return self._fit_fused_impl(
                 state, batcher, epochs=epochs, seed=seed,
@@ -1766,8 +1827,12 @@ class FusedEngine(Engine):
         base_rng = jax.random.PRNGKey(seed)
         shuffle_rng = _shuffle_rng(batcher.seed)
         sharding = self._resolve_batch_sharding()
-        device_arrays = {k: data_lib.stage_to_device(v, sharding)
-                         for k, v in batcher.padded_arrays().items()}
+        with obs_trace.span("stage") as stage_span:
+            device_arrays = {
+                k: data_lib.stage_to_device(v, sharding)
+                for k, v in batcher.padded_arrays().items()}
+            stage_span.set(bytes=_tree_nbytes(device_arrays),
+                           arenaHit=False)
         active = np.ones(n, bool)
         stopped: List[Optional[int]] = [None] * n
         ema: List[Optional[float]] = [None] * n
@@ -1778,31 +1843,35 @@ class FusedEngine(Engine):
         es_min_epochs = max(1, int(es.get("min_epochs", 2)))
         es_alpha = float(es.get("alpha", 0.5))
         history: List[Dict[str, Any]] = []
-        traces_before = _FUSED_STATS["epochTraces"]
         for epoch in range(epochs):
             preempt.check_cancel()
             preempt.heartbeat(epoch=epoch, fusedConfigs=n)
             t0 = time.perf_counter()
-            mono0 = time.monotonic()
-            state, totals = epoch_step(
-                state, self._hyper, jnp.asarray(active), device_arrays,
-                base_rng, shuffle_rng, jnp.asarray(epoch))
-            jax.block_until_ready(state.params)
-            dt = time.perf_counter() - t0
-            record: Dict[str, Any] = {
-                k: (np.asarray(s, np.float64)
-                    / np.maximum(np.asarray(c, np.float64), 1e-9)
-                    ).round(6).tolist()
-                for k, (s, c) in totals.items()}
-            record.update(epoch=epoch, epochSeconds=round(dt, 4))
-            self._observe_window(
-                mono0, dt, {"epoch": epoch}, 0,
-                step=(epoch + 1) * steps, epoch=epoch,
-                first=epoch == 0,
-                cold=_FUSED_STATS["epochTraces"] > traces_before)
-            history.append(record)
-            if log_fn is not None:
-                log_fn(record)
+            with obs_trace.span("epoch", epoch=epoch) as epoch_span:
+                active_in = jnp.asarray(active)
+                epoch_idx = jnp.asarray(epoch)
+                with obs_trace.span("dispatch", epoch=epoch) as dispatch:
+                    state, totals = self._call_in(
+                        dispatch, epoch_step, state, self._hyper,
+                        active_in, device_arrays, base_rng, shuffle_rng,
+                        epoch_idx)
+                with obs_trace.span("deviceWait", epoch=epoch):
+                    jax.block_until_ready(state.params)
+                dt = time.perf_counter() - t0
+                with obs_trace.span("epochEnd", epoch=epoch):
+                    record: Dict[str, Any] = {
+                        k: (np.asarray(s, np.float64)
+                            / np.maximum(np.asarray(c, np.float64), 1e-9)
+                            ).round(6).tolist()
+                        for k, (s, c) in totals.items()}
+                    record.update(epoch=epoch, epochSeconds=round(dt, 4))
+                    self._observe_window(
+                        epoch_span, dt, {"epoch": epoch}, 0,
+                        step=(epoch + 1) * steps, epoch=epoch,
+                        builds=dispatch.attrs.get("builds", 0))
+                    history.append(record)
+                    if log_fn is not None:
+                        log_fn(record)
             if es_armed and epoch + 1 < epochs:
                 vals = self.evaluate_fused(state, eval_batcher)
                 for i in range(n):

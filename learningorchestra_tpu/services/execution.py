@@ -303,7 +303,8 @@ class ExecutionService:
                     parent_name)
                 instance = self._ctx.artifacts.load(parent_name,
                                                     parent_type)
-                treated = self._ctx.params.treat(method_parameters)
+                with obs_trace.span("paramsTreat"):
+                    treated = self._ctx.params.treat(method_parameters)
             ckpt = _prepare_checkpointer(self._ctx, name, type_string,
                                          treated)
             _inject_epoch_log(self._ctx, name, instance, method, treated)

@@ -261,9 +261,10 @@ class JobManager:
                             ) -> None:
         """Roll trace-derived wall-clock attribution into the job's
         metadata (docs/LIFECYCLE.md): ``leaseWaitSeconds`` (mesh
-        grant wait), ``compileSeconds`` (engine lowering/first-trace
-        time) and ``checkpointCommitSeconds`` (summed commit stalls) —
-        so clients see where the time went without the trace endpoint.
+        grant wait), ``compileSeconds`` (the job's ``compile`` spans:
+        the step calls that built an executable) and
+        ``checkpointCommitSeconds`` (summed commit stalls) — so clients
+        see where the time went without the trace endpoint.
         Mesh jobs additionally record ``peakHbmBytes`` — the process's
         device high-water mark while the job ran (an upper bound under
         slice concurrency) — and feed the footprint-calibration
@@ -522,6 +523,13 @@ class JobManager:
                                     result = fn()
                                 if on_success is not None:
                                     on_success(result)
+                                # before ``finished`` shows: a client
+                                # that polls the flag then reads
+                                # compileSeconds / perf finds them
+                                self._record_attribution(
+                                    name, footprint,
+                                    measure_hbm=needs_mesh,
+                                    token=token)
                                 if mark_finished:
                                     self._catalog.mark_finished(name)
                                 self._set_status(name,
@@ -533,10 +541,6 @@ class JobManager:
                                             {"queueWaitSeconds": round(
                                                 queue_wait, 6),
                                              "attempt": attempt_no})))
-                                self._record_attribution(
-                                    name, footprint,
-                                    measure_hbm=needs_mesh,
-                                    token=token)
                                 obs_export.log_event(
                                     "job", "finished", trace_id=name,
                                     elapsedSeconds=round(

@@ -383,6 +383,202 @@ def test_failing_or_slow_trace_export_never_fails_the_job(tmp_path):
         config_mod.reset_config()
 
 
+# ------------------------------------------- live fit spans (engine)
+def _tiny_lm(rows=16):
+    from learningorchestra_tpu.models import LanguageModel
+
+    lm = LanguageModel(vocab_size=64, d_model=32, n_layers=1, n_heads=2,
+                       max_len=16)
+    x = np.random.default_rng(0).integers(
+        1, 64, size=(rows, 16)).astype(np.int32)
+    return lm, x
+
+
+def _fit_under_profiler(tmp_path, traced):
+    """Names of the host-plane events a tiny fit leaves in a profiler
+    capture, by count."""
+    import collections
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    config_mod.set_config(config_mod.Config(
+        home=str(tmp_path / "lo_home"), compute_dtype="float32",
+        trace=traced))
+    try:
+        lm, x = _tiny_lm()
+        out = str(tmp_path / ("prof_on" if traced else "prof_off"))
+        jax.profiler.start_trace(out)
+        try:
+            with obs_trace.span("job", trace="prof"):
+                lm.fit(x, batch_size=4, epochs=3, shuffle=False)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        config_mod.reset_config()
+    (path,) = glob.glob(out + "/**/*.xplane.pb", recursive=True)
+    names = collections.Counter()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    return names
+
+
+def test_live_spans_reach_the_profiler_capture(tmp_path):
+    """A fit under ``jax.profiler.start_trace`` leaves its live spans
+    as host events of the same names in the ``.xplane.pb`` (one clock
+    with the device's ops), and none of them with ``LO_TRACE=0``."""
+    names = _fit_under_profiler(tmp_path, traced=True)
+    for want in ("epoch", "dispatch", "deviceWait", "epochEnd"):
+        assert names[want] == 3, (want, names[want])
+    assert names["job"] == 1 and names["initState"] == 1
+    obs_trace.reset()
+    names = _fit_under_profiler(tmp_path, traced=False)
+    for gone in ("job", "epoch", "dispatch", "deviceWait", "epochEnd"):
+        assert names[gone] == 0, (gone, names[gone])
+
+
+def test_compile_spans_follow_the_builds_jax_reports(tmp_config):
+    """``compile`` spans of a cold fit: one per dispatch during which
+    ``jax.monitoring`` reported a build, each with what the build cost,
+    none over a ``deviceWait``; a second fit of the same shapes builds
+    nothing."""
+    import jax
+
+    builds = []  # (thread, monotonic time) of every executable built
+
+    def on_build(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            builds.append((threading.get_ident(), time.monotonic()))
+
+    jax.monitoring.register_event_duration_secs_listener(on_build)
+    try:
+        lm, x = _tiny_lm()
+        with obs_trace.span("job", trace="cold"):
+            lm.fit(x, batch_size=4, epochs=3, shuffle=False)
+        with obs_trace.span("job", trace="warm"):
+            lm.fit(x, batch_size=4, epochs=3, shuffle=False)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_build)
+    me = threading.get_ident()
+    spans = obs_trace.spans_of("cold")
+    dispatches = [s for s in spans if s.name == "dispatch"]
+    assert len(dispatches) == 3
+    building = [d for d in dispatches
+                if any(t == me and d.start <= at <= d.end
+                       for t, at in builds)]
+    compiles = [s for s in spans if s.name == "compile"]
+    assert len(compiles) == len(building) >= 1
+    assert [c.attrs["executable"] for c in compiles] == \
+        list(range(1, len(compiles) + 1))
+    waits = [s for s in spans if s.name == "deviceWait"]
+    (job,) = [s for s in spans if s.name == "job"]
+    for c, d in zip(compiles, building):
+        assert c.attrs["traceSeconds"] > 0 and c.attrs["builds"] >= 1
+        assert c.attrs["epoch"] == d.attrs["epoch"]
+        # the dispatch call's own interval, beside the epochs
+        assert d.start <= c.start and c.end <= d.end
+        assert c.parent_id == job.span_id
+        assert c.attrs["traceSeconds"] <= c.duration
+        assert not any(w.start < c.end and c.start < w.end for w in waits)
+    # measureFlops (a second trace of the step) is a span of its own,
+    # outside every compile span
+    (flops,) = [s for s in spans if s.name == "measureFlops"]
+    assert not any(flops.start < c.end and c.start < flops.end
+                   for c in compiles)
+    warm = obs_trace.spans_of("warm")
+    assert len([s for s in warm if s.name == "dispatch"]) == 3
+    assert not [s for s in warm if s.name == "compile"
+                and s.attrs.get("cold")]
+    assert not [s for s in warm if s.name == "measureFlops"]
+
+
+def test_nested_jit_traces_are_counted_once(tmp_config):
+    """A jit traced inside another's trace reports its seconds first,
+    and the caller's hold them again: the span keeps the outermost."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x * 3)
+
+    x = jnp.ones((3,))
+    with obs_trace.span("root", trace="nest") as sp:
+        outer(x).block_until_ready()
+    assert sp.attrs["builds"] == 1
+    assert 0 < sp.attrs["traceSeconds"] <= sp.sp.duration
+    assert sp.attrs["backendCompileSeconds"] > 0
+
+
+def test_per_step_fit_spans_one_dispatch_for_the_loop(tmp_path):
+    """The per-step path: the fit's first step is one ``dispatch``,
+    the rest of each epoch's feed loop another (``steps`` on it), never
+    a span a step."""
+    config_mod.set_config(config_mod.Config(
+        home=str(tmp_path / "lo_home"), compute_dtype="float32",
+        scan_fit_max_bytes=0))
+    try:
+        lm, x = _tiny_lm(rows=32)  # 4 steps of 8 (the 8-device mesh)
+        with obs_trace.span("job", trace="steps"):
+            lm.fit(x, batch_size=8, epochs=2, shuffle=False)
+    finally:
+        config_mod.reset_config()
+    spans = obs_trace.spans_of("steps")
+    by_epoch = {e: [s.attrs["steps"] for s in spans
+                    if s.name == "dispatch" and s.attrs["epoch"] == e]
+                for e in (0, 1)}
+    assert by_epoch == {0: [1, 3], 1: [4]}
+    assert len([s for s in spans if s.name == "deviceWait"]) == 3
+    assert len([s for s in spans if s.name == "epochEnd"]) == 2
+    # here the step is first built by measureFlops, under the first
+    # dispatch; the loop span's ``builds`` also counts the metric sums'
+    # small adds, which are no step call's and leave no compile span
+    (flops,) = [s for s in spans if s.name == "measureFlops"]
+    first = next(s for s in spans if s.name == "dispatch")
+    assert flops.parent_id == first.span_id and flops.attrs["builds"] >= 1
+    assert len([s for s in spans if s.name == "compile"]) <= len(
+        [s for s in spans if s.name == "dispatch"
+         and s.attrs.get("builds")])
+
+
+def test_ring_drops_whole_old_epochs_before_anything_else(tmp_path):
+    config_mod.set_config(config_mod.Config(
+        home=str(tmp_path / "lo_home"), trace_ring=16))
+    try:
+        with obs_trace.span("job", trace="ring"):
+            with obs_trace.span("dataLoad"):
+                pass
+            for e in range(20):
+                with obs_trace.span("epoch", epoch=e) as ep:
+                    with obs_trace.span("dispatch", epoch=e):
+                        pass
+                    with obs_trace.span("epochEnd", epoch=e):
+                        with obs_trace.span("checkpointCommit"):
+                            pass
+                if e < 2:
+                    obs_trace.add("compile", "ring", 0.0, 0.1,
+                                  parent=ep.sp.parent_id, executable=e + 1)
+        spans = obs_trace.spans_of("ring")
+    finally:
+        config_mod.reset_config()
+    assert len(spans) <= 16
+    names = [s.name for s in spans]
+    assert names.count("compile") == 2 and "dataLoad" in names
+    kept = sorted(s.attrs["epoch"] for s in spans if s.name == "epoch")
+    assert kept == list(range(20 - len(kept), 20)) and len(kept) >= 2
+    # an epoch goes with its children: none is left without its parent
+    ids = {s.span_id for s in spans}
+    assert all(s.parent_id in ids for s in spans
+               if s.name in ("dispatch", "epochEnd", "checkpointCommit"))
+
+
 # -------------------------------------------------- end-to-end (REST)
 def test_train_job_trace_timeline_and_histograms(api):
     """The acceptance path: train 2 epochs with checkpoints, then read
@@ -438,6 +634,10 @@ def test_train_job_trace_timeline_and_histograms(api):
     compiles = [s.to_dict() for s in obs_trace.spans_of("t")
                 if s.name == "compile"]
     assert any(c["attrs"].get("cold") for c in compiles), compiles
+    # one ``compile`` span for each dispatch during which jax built
+    built = [s for s in obs_trace.spans_of("t")
+             if s.name == "dispatch" and s.attrs.get("builds")]
+    assert len(compiles) == len(built) >= 1
 
     # chrome export loads as trace_event JSON
     st, chrome, _ = api.dispatch(
@@ -472,12 +672,14 @@ def test_train_job_trace_timeline_and_histograms(api):
         assert want in hists, (want, sorted(hists))
         assert hists[want]["count"] >= 1
         assert hists[want]["buckets"]["+Inf"] == hists[want]["count"]
-    assert hists["lo_compile_seconds"]["count"] == 1  # cold only
+    # one observation per build (per ``compile`` span)
+    n = len(compiles)
+    assert hists["lo_compile_seconds"]["count"] == n
     text = api.metrics_prometheus().decode()
     assert "# TYPE lo_dispatch_seconds histogram" in text
-    assert 'lo_compile_seconds_bucket{le="+Inf"} 1' in text
+    assert f'lo_compile_seconds_bucket{{le="+Inf"}} {n}' in text
     assert "lo_compile_seconds_sum" in text
-    assert "lo_compile_seconds_count 1" in text
+    assert f"lo_compile_seconds_count {n}" in text
     # the old sum/count-only summaries are gone (TYPE must be unique)
     assert "lo_dispatch_seconds summary" not in text
     assert "lo_lease_wait_seconds summary" not in text
@@ -492,6 +694,60 @@ def test_train_job_trace_timeline_and_histograms(api):
     st, body, _ = api.dispatch(
         "GET", f"{PREFIX}/observability/timeline/never-ran", {}, None)
     assert st == 404, body
+
+
+def test_long_fit_keeps_its_setup_and_compile_spans(api):
+    """A fit of 200 epochs leaves some 1,200 spans in a ring of 512
+    (the default): whole old epochs go, and ``submit``, ``dataLoad``,
+    every ``compile`` span (``compileSeconds`` with them) and the
+    newest epochs stay."""
+    st, _, _ = api.dispatch(
+        "POST", f"{PREFIX}/function/python",
+        {}, {"name": "d", "functionParameters": {}, "function":
+             "import numpy as np\nrng = np.random.default_rng(0)\n"
+             "x = rng.normal(size=(64, 8)).astype(np.float32)\n"
+             "y = (x[:, 0] > 0).astype(np.int32)\n"
+             "response = {'x': x, 'y': y}\n"})
+    assert st == 201
+    _wait(api, "d", "function/python")
+    st, _, _ = api.dispatch(
+        "POST", f"{PREFIX}/model/tensorflow",
+        {}, {"modelName": "m",
+             "modulePath": "learningorchestra_tpu.models",
+             "class": "NeuralModel",
+             "classParameters": {"layer_configs": [
+                 # a width no other test of this file builds: a cold fit
+                 {"kind": "dense", "units": 5, "activation": "relu"},
+                 {"kind": "dense", "units": 2,
+                  "activation": "softmax"}]}})
+    assert st == 201
+    _wait(api, "m", "model/tensorflow")
+    st, _, _ = api.dispatch(
+        "POST", f"{PREFIX}/train/tensorflow",
+        {}, {"name": "long", "modelName": "m", "method": "fit",
+             "methodParameters": {"x": "$d.x", "y": "$d.y",
+                                  "epochs": 200, "batch_size": 16}})
+    assert st == 201
+    meta = _wait(api, "long", "train/tensorflow", timeout=240.0)
+    spans = obs_trace.spans_of("long")
+    assert len(spans) <= 512  # the default ring
+    names = [s.name for s in spans]
+    for want in ("submit", "validate", "job", "attempt", "dataLoad",
+                 "artifactLoad", "initState", "stage", "artifactSave"):
+        assert want in names, (want, sorted(set(names)))
+    compiles = [s for s in spans if s.name == "compile"]
+    st, m, _ = api.dispatch("GET", "/metrics", {}, None)
+    assert len(compiles) == \
+        m["latencyHistograms"]["lo_compile_seconds"]["count"] >= 1
+    assert compiles[0].attrs["epoch"] == 0  # older than every epoch kept
+    assert meta["compileSeconds"] == pytest.approx(
+        sum(c.duration for c in compiles), abs=1e-4)
+    kept = sorted(s.attrs["epoch"] for s in spans if s.name == "epoch")
+    assert kept == list(range(200 - len(kept), 200)) and len(kept) > 50
+    assert 0 not in kept
+    for child in ("dispatch", "deviceWait", "epochEnd"):
+        assert sorted(s.attrs["epoch"] for s in spans
+                      if s.name == child) == kept
 
 
 def test_serving_request_traces(api):

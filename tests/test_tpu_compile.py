@@ -14,6 +14,7 @@ compile in the test's own process.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +83,28 @@ def test_flash_attention_compiles_for_v5e(one_chip, qs, kvs, kwargs,
         fn = jax.value_and_grad(_loss(fn), argnums=(0, 1, 2))
     text = _compiled_text(fn, one_chip, qs, kvs, kvs)
     assert "tpu_custom_call" in text
+
+
+def test_flash_kernels_carry_their_own_names_for_v5e(one_chip):
+    """The chip's compiler takes a ``pallas_call``'s ``name=`` into the
+    instruction name, so a profiler trace tells the three training
+    kernels apart (``benchmark/layer_metrics/flash_*_ms.train.py``)
+    and not by the module scope they run under."""
+    fn = functools.partial(attn.flash_attention, interpret=False,
+                           causal=True)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):  # as the model's module does
+            return fn(q, k, v).astype(jnp.float32).sum()
+
+    q, kv = (2, 2048, 16, 128), (2, 2048, 4, 128)
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                          one_chip, q, kv, kv)
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*custom-call\(",
+                       text, re.M)
+    kernels = {name.split(".")[0] for name in calls}
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} <= kernels, calls
+    assert not any(name.startswith("attn") for name in calls), calls
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
