@@ -610,8 +610,11 @@ class FusedHeadOut(NamedTuple):
     """Training output of a ``fused_head_chunk`` TransformerLM: the
     final hidden states plus the lm_head kernel, so the loss can run
     the vocab projection + cross-entropy in token chunks and the
-    (tokens, vocab) logits tensor never materializes in HBM (its
-    effect on the step is not measured on today's code)."""
+    (tokens, vocab) logits tensor never materializes in HBM: at 8,190
+    tokens by 92,544 rows that tensor would be 3.0 GB in float32,
+    where a chunk's is 0.38 GB. The chunked head is 66.6 ms of a 203
+    ms step on one v5e chip (PERF.md section 5, my chip run, PR 25);
+    the full-logits path has not been run at that size."""
     hidden: Any     # (b, s, d) final-norm output
     kernel: Any     # (d, vocab) lm_head weight
     aux: Any        # MoE load-balance scalar
@@ -759,6 +762,82 @@ def _token_targets(batch, weights):
     return tgt, tok_mask
 
 
+def _head_chunk_sums(h_c, t_c, m_c, kernel):
+    """One chunk's logits and what the loss reads from them: the
+    log-sum-exp, the masked cross-entropy sum and the masked count of
+    argmax hits."""
+    with jax.named_scope("logits"):
+        # bf16 inputs, f32 accumulate — the MXU-native layout
+        lg = jnp.einsum("cd,dv->cv", h_c, kernel,
+                        preferred_element_type=jnp.float32)
+    lse = jax.scipy.special.logsumexp(lg, axis=-1)
+    correct = jnp.take_along_axis(lg, t_c[:, None], axis=1)[:, 0]
+    ok = (jnp.argmax(lg, axis=-1) == t_c).astype(jnp.float32)
+    return lg, lse, jnp.sum((lse - correct) * m_c), jnp.sum(ok * m_c)
+
+
+@jax.custom_vjp
+def _head_chunk_scan(hs, tg, mk, kernel):
+    """``(loss_sum, ok_sum)`` over token chunks ``hs (n, c, d)``,
+    targets ``tg (n, c)`` and mask ``mk (n, c)`` against the lm_head
+    ``kernel (d, vocab)``. This primal runs where no gradient is
+    asked; under one, :func:`_head_chunk_scan_fwd` takes its place."""
+    def body(carry, xs):
+        _, _, loss_c, ok_c = _head_chunk_sums(*xs, kernel)
+        return (carry[0] + loss_c, carry[1] + ok_c), None
+
+    zero = jnp.zeros((), jnp.float32)
+    sums, _ = jax.lax.scan(body, (zero, zero), (hs, tg, mk))
+    return sums
+
+
+def _head_chunk_scan_fwd(hs, tg, mk, kernel):
+    """The same scan, taking each chunk's gradients while its logits
+    exist: ``g = (softmax(lg) - onehot(t)) * m``, ``dh = g x kernel^T``
+    (stacked) and ``dw += h^T x g`` (carried in the kernel's dtype, as
+    the transposed scan carried it). The products take what the
+    transpose of the logits' product would: ``g`` in float32 at the
+    default precision, float32 accumulation. ``dh`` stays float32
+    until the cotangent has scaled it: values already on the bf16 grid,
+    times a scale just off a power of two (1/8190 tokens), all round
+    back to the grid point that is 1/8192 of them (PERF.md, PR 25).
+    Nothing of width ``vocab`` per token outlives its chunk."""
+    def body(carry, xs):
+        h_c, t_c, m_c = xs
+        loss_sum, ok_sum, dw = carry
+        lg, lse, loss_c, ok_c = _head_chunk_sums(h_c, t_c, m_c, kernel)
+        g = (jnp.exp(lg - lse[:, None])
+             - jax.nn.one_hot(t_c, lg.shape[-1])) * m_c[:, None]
+        with jax.named_scope("dh"):
+            dh_c = jax.lax.dot_general(
+                g, kernel, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        with jax.named_scope("dw"):
+            dw = dw + jax.lax.dot_general(
+                h_c, g, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(dw.dtype)
+        return (loss_sum + loss_c, ok_sum + ok_c, dw), dh_c
+
+    zero = jnp.zeros((), jnp.float32)
+    (loss_sum, ok_sum, dw), dh = jax.lax.scan(
+        body, (zero, zero, jnp.zeros_like(kernel)), (hs, tg, mk))
+    return (loss_sum, ok_sum), (dh, dw)
+
+
+def _head_chunk_scan_bwd(res, cts):
+    """Scale the gradients the forward took by ``loss_sum``'s
+    cotangent (``ok_sum`` carries none; targets and mask get none).
+    The kernel arrives in the hidden states' dtype, so ``dw``'s dtype
+    is also the one ``dh`` is rounded to, once, after the scaling."""
+    dh, dw = res
+    ct = cts[0].astype(jnp.float32)
+    return ((ct * dh).astype(dw.dtype), None, None,
+            (ct * dw).astype(dw.dtype))
+
+
+_head_chunk_scan.defvjp(_head_chunk_scan_fwd, _head_chunk_scan_bwd)
+
+
 @jax.named_scope("head_loss")
 def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
                      aux_coef: float):
@@ -766,12 +845,17 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
     chunks of the final hidden states through the lm_head matmul, so
     peak logits memory is (chunk, vocab) instead of (b*s, vocab) and
     the full logits tensor never round-trips HBM between forward and
-    loss (at d=512/vocab 32k the unfused epilogue is one (8192, 512)
-    x (512, 32000) matmul per step feeding an elementwise log-softmax
-    over 262M f32 logits; not measured on today's code). The backward
-    recomputes each chunk's logits via jax.checkpoint. Accuracy is
-    computed inside the same scan and emitted as a loss metric, so
-    the engine does not re-run the projection for it."""
+    loss. Under a gradient the scan is one pass
+    (:func:`_head_chunk_scan_fwd`): three products a chunk
+    (``head_loss/logits``, ``/dh``, ``/dw``), no backward loop and no
+    recomputed logits. Accuracy is computed inside the same scan and
+    emitted as a loss metric, so the engine does not re-run the
+    projection for it. On one v5e chip, a chunk of 1024 tokens at
+    d=2048 against 92,544 rows in bf16 (PERF.md section 5, my chip
+    run, PR 25): logits 2.02 ms, argmax 0.50, sum of exponentials
+    0.50, ``dh`` 2.49, ``dw`` 2.79, 8.3 ms in all where the
+    checkpointed scan it replaced took 10.8; one product is 1.97 ms
+    at the chip's peak."""
     tgt, tok_mask = _token_targets(batch, weights)
     hs = out.hidden[:, :-1]
     b, sm1, d = hs.shape
@@ -786,26 +870,9 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
         hs = jnp.pad(hs, ((0, pad), (0, 0)))
         tg = jnp.pad(tg, (0, pad))
         mk = jnp.pad(mk, (0, pad))
-    kernel = out.kernel.astype(hs.dtype)
-
-    def body(carry, xs):
-        h_c, t_c, m_c = xs
-        # bf16 inputs, f32 accumulate — the MXU-native layout
-        lg = jnp.einsum("cd,dv->cv", h_c, kernel,
-                        preferred_element_type=jnp.float32)
-        lse = jax.scipy.special.logsumexp(lg, axis=-1)
-        correct = jnp.take_along_axis(lg, t_c[:, None], axis=1)[:, 0]
-        ok = (jnp.argmax(lg, axis=-1) == t_c).astype(jnp.float32)
-        loss_sum, ok_sum = carry
-        return (loss_sum + jnp.sum((lse - correct) * m_c),
-                ok_sum + jnp.sum(ok * m_c)), None
-
-    (loss_sum, ok_sum), _ = jax.lax.scan(
-        jax.checkpoint(body),
-        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hs.reshape(n_chunks, chunk, d),
-         tg.reshape(n_chunks, chunk),
-         mk.reshape(n_chunks, chunk)))
+    loss_sum, ok_sum = _head_chunk_scan(
+        hs.reshape(n_chunks, chunk, d), tg.reshape(n_chunks, chunk),
+        mk.reshape(n_chunks, chunk), out.kernel.astype(hs.dtype))
     total = jnp.maximum(jnp.sum(mk), 1e-9)
     loss = loss_sum / total + aux_coef * out.aux.astype(jnp.float32)
     return loss, {"accuracy": (ok_sum, total)}
@@ -1474,8 +1541,11 @@ class LanguageModel:
     def _head_chunk(self) -> int:
         """Fused-head chunk size (0 = full logits). Auto rule: fuse
         when the vocab is large enough that the (tokens, vocab) f32
-        logits tensor dominates the step's HBM traffic (not measured
-        on today's code). Under sequence-parallel
+        logits tensor dominates the step's HBM traffic. Neither the
+        threshold of 8192 rows nor the chunk of 1024 tokens has been
+        swept on the chip: at 92,544 rows and 1024 tokens a chunk the
+        fused head is 33% of the step (PERF.md section 5, my chip run,
+        PR 25). Under sequence-parallel
         attention the loss runs its shard_map twin
         (:func:`_fused_head_loss_sharded`), keeping the sequence dim
         sharded. ``LO_LM_HEAD_CHUNK`` overrides (0 disables, N sets

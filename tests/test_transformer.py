@@ -297,8 +297,8 @@ def test_ulysses_16k_mixed_mesh_step_lowers(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# fused lm_head (chunked projection + CE: keeps the vocab-32k logits
-# tensor out of HBM; its effect is not measured on today's code)
+# fused lm_head (chunked projection + CE: keeps the (tokens, vocab)
+# logits tensor out of HBM; its chip numbers are in PERF.md section 5)
 # ----------------------------------------------------------------------
 def test_fused_head_matches_full_logits_loss_and_grads(tmp_path):
     """FusedHeadOut training path == full-logits path: same loss,
@@ -339,6 +339,116 @@ def test_fused_head_matches_full_logits_loss_and_grads(tmp_path):
         batch, None)
     assert float(extra["accuracy"][0]) == float(acc_s)
     assert float(extra["accuracy"][1]) == float(acc_c)
+
+
+def _head_case(dtype=jnp.float32, pad_rows=False):
+    """Toy hidden states, lm_head kernel and tokens for the flat fused
+    head, with no model and no mesh around them. The kernel leans on
+    the hidden states' first coordinates so that some argmax hits."""
+    rng = np.random.default_rng(3)
+    b, s, d, v = 4, 9, 16, 61
+    toks = rng.integers(1, v, size=(b, s)).astype(np.int32)
+    kernel = rng.normal(size=(d, v)).astype(np.float32) * 0.3
+    hidden = rng.normal(size=(b, s, d)).astype(np.float32)
+    hidden[:, :-1] += 2.0 * kernel.T[toks[:, 1:]]
+    weights = None
+    if pad_rows:
+        toks[2, 5:] = 0
+        toks[0, 8:] = 0
+        weights = jnp.asarray([1.0, 0.5, 2.0, 0.0], jnp.float32)
+    return (jnp.asarray(hidden, dtype), jnp.asarray(kernel),
+            {"x": jnp.asarray(toks)}, weights)
+
+
+def _head_losses(batch, weights, chunk, scale):
+    """(fused, full): the same scaled loss of (hidden, kernel) through
+    the chunk scan and through full logits of the same product."""
+    from learningorchestra_tpu.models import transformer as T
+
+    def fused(hidden, kernel):
+        out = T.FusedHeadOut(hidden, kernel, jnp.ones((), jnp.float32))
+        loss, extra = T._fused_head_loss(out, batch, weights, chunk, 0.01)
+        return scale * loss, extra["accuracy"]
+
+    def full(hidden, kernel):
+        logits = jnp.einsum("bsd,dv->bsv", hidden,
+                            kernel.astype(hidden.dtype),
+                            preferred_element_type=jnp.float32)
+        outputs = (logits, jnp.ones((), jnp.float32))
+        loss = T.next_token_loss(0.01)(outputs, batch, weights)
+        return scale * loss, T.token_accuracy(outputs, batch, weights)
+
+    return fused, full
+
+
+@pytest.mark.parametrize("case", [
+    dict(id="chunk_divides_tokens", chunk=8),
+    dict(id="chunk_leaves_a_tail", chunk=7),
+    dict(id="padding_and_row_weights", chunk=7, pad_rows=True),
+    dict(id="bf16_hidden", chunk=7, dtype=jnp.bfloat16, tol=2e-2),
+    dict(id="cotangent_of_3", chunk=7, pad_rows=True, scale=3.0),
+], ids=lambda c: c["id"])
+def test_fused_head_one_pass_matches_full_logits(case):
+    """The one-pass fused head (gradients taken in the forward scan)
+    against the full-logits loss: equal loss, equal gradients for the
+    hidden state and the kernel, equal accuracy sums; and its primal,
+    called with no gradient asked, gives the same loss."""
+    tol = case.get("tol", 1e-5)
+    hidden, kernel, batch, weights = _head_case(
+        case.get("dtype", jnp.float32), case.get("pad_rows", False))
+    fused, full = _head_losses(batch, weights, case["chunk"],
+                               case.get("scale", 1.0))
+    (lz, acc_z), gz = jax.value_and_grad(
+        fused, argnums=(0, 1), has_aux=True)(hidden, kernel)
+    (lf, acc_f), gf = jax.value_and_grad(
+        full, argnums=(0, 1), has_aux=True)(hidden, kernel)
+    np.testing.assert_allclose(float(lz), float(lf), rtol=1e-5)
+    np.testing.assert_allclose(float(fused(hidden, kernel)[0]),
+                               float(lf), rtol=1e-5)
+    for a, b in zip(gz, gf):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * np.abs(b).max())
+    assert float(acc_z[0]) == float(acc_f[0]) > 0
+    np.testing.assert_allclose(float(acc_z[1]), float(acc_f[1]),
+                               rtol=1e-6)
+
+
+def _scans_and_products(jaxpr, found, inside_scan=False):
+    """Collect (primitive name, output shape, inside a scan?) of every
+    ``scan`` and ``dot_general`` of a jaxpr and of the jaxprs its
+    equations hold."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("scan", "dot_general"):
+            found.append((name, eqn.outvars[0].aval.shape, inside_scan))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scans_and_products(sub, found, inside_scan or name == "scan")
+    return found
+
+
+def test_fused_head_gradient_is_one_scan_of_three_products():
+    """Structure, so that the recomputed logits cannot come back unseen
+    on a CPU: the gradient of the fused head is ONE scan over the
+    chunks holding three products of the chunk's size (logits, dh, dw)
+    and no product outside it; the primal holds one product."""
+    hidden, kernel, batch, weights = _head_case()
+    chunk, (d, v) = 7, kernel.shape
+    fused, _ = _head_losses(batch, weights, chunk, 1.0)
+
+    grad = _scans_and_products(jax.make_jaxpr(jax.grad(
+        lambda h, k: fused(h, k)[0], argnums=(0, 1)))(
+            hidden, kernel).jaxpr, [])
+    assert [f for f in grad if f[0] == "scan"] == [("scan", (), False)]
+    assert sorted(f[1:] for f in grad if f[0] == "dot_general") == \
+        sorted([((chunk, v), True), ((chunk, d), True), ((d, v), True)])
+
+    primal = _scans_and_products(jax.make_jaxpr(
+        lambda h, k: fused(h, k)[0])(hidden, kernel).jaxpr, [])
+    assert [f[0] for f in primal] == ["scan", "dot_general"]
+    assert primal[1][1:] == ((chunk, v), True)
 
 
 def test_fused_head_auto_rule_and_training(tmp_path):
