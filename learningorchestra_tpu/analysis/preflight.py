@@ -220,6 +220,32 @@ def _trace_init(configs: List[Any],
         return None, None
 
 
+def _language_model_findings(module_path: Any, class_name: Any,
+                             class_parameters: Any) -> List[Finding]:
+    """A ``LanguageModel`` spec is checked by the constructor itself:
+    it builds no parameter, and what it refuses (an unknown setting or
+    objective, experts held that the router does not span, a head
+    width, block length or attention that do not go together) is what
+    the model job would fail on."""
+    if module_path != "learningorchestra_tpu.models" or \
+            class_name != "LanguageModel" or \
+            not isinstance(class_parameters, dict):
+        return []
+    if any(_is_hash_expr(v) or (isinstance(v, str) and v.startswith("$"))
+           for v in class_parameters.values()):
+        return []   # resolved at run time: bypass
+    try:
+        from learningorchestra_tpu.models import LanguageModel
+
+        LanguageModel(**class_parameters)
+    except (TypeError, ValueError) as e:
+        return [Finding(SEVERITY_ERROR, "language-model-config",
+                        "classParameters", str(e))]
+    except Exception:  # noqa: BLE001 — analyzer limitation: bypass
+        return []
+    return []
+
+
 def check_model(module_path: Any, class_name: Any,
                 class_parameters: Any,
                 mode: str = "subprocess") -> List[Finding]:
@@ -230,6 +256,8 @@ def check_model(module_path: Any, class_name: Any,
     findings = lint_parameter_code(
         class_parameters if isinstance(class_parameters, dict) else None,
         mode)
+    findings.extend(_language_model_findings(module_path, class_name,
+                                             class_parameters))
     configs = _neural_spec(module_path, class_name, class_parameters)
     if configs is None:
         return findings

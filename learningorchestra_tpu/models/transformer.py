@@ -8,7 +8,7 @@ net-new TPU-first model family the parallelism library was built for:
 
 - param naming matches ``parallel.sharding.TRANSFORMER_RULES`` exactly
   (``embed/embedding``, ``q_proj|k_proj|v_proj|o_proj/kernel``,
-  ``gate|up_proj|down_proj/kernel``, ``experts/wi|wo``,
+  ``gate|up_proj|down_proj/kernel``, ``experts/w_gate|w_up|w_down``,
   ``lm_head/kernel``), so TP/FSDP/EP sharding is a table lookup;
 - attention is pluggable per config: ``dot`` (XLA-fused reference),
   ``flash`` (Pallas kernel, shard_map'd over heads so TP keeps the
@@ -17,7 +17,12 @@ net-new TPU-first model family the parallelism library was built for:
 - rotary position embeddings + RMSNorm + gated-SiLU MLP — the modern
   decoder block, all MXU-shaped matmuls;
 - optional mixture-of-experts MLP (``n_experts > 0``) through
-  ``parallel.moe`` with expert parallelism over ``ep``.
+  ``parallel.moe``: gated experts behind a softmax router, the experts
+  HELD here (``experts_held``, ``expert_offset``) as one grouped
+  product with no dropped token; the older capacity schedule over
+  ``ep``;
+- ``head_dim`` and per-head QK-norm as settings, and a second training
+  objective, block diffusion (docs/DIFFUSION.md).
 
 ``LanguageModel`` wraps the flax module in the same keras-shaped
 method surface as :class:`~learningorchestra_tpu.models.neural.
@@ -78,21 +83,22 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 # flax modules
 # ----------------------------------------------------------------------
 class _Experts(nn.Module):
-    """Bare param holder so expert weights live at ``.../experts/*``
-    where the EP sharding rules expect them."""
-    n_experts: int
+    """Bare param holder so the gated experts' weights live at
+    ``.../experts/*`` where the EP sharding rules expect them."""
+    n_experts: int      # the experts HELD here
     d_model: int
     d_ff: int
 
     @nn.compact
     def __call__(self):
-        wi = self.param(
-            "wi", nn.initializers.normal(1.0 / math.sqrt(self.d_model)),
-            (self.n_experts, self.d_model, self.d_ff))
-        wo = self.param(
-            "wo", nn.initializers.normal(1.0 / math.sqrt(self.d_ff)),
-            (self.n_experts, self.d_ff, self.d_model))
-        return wi, wo
+        def stacked(name, fan_in, fan_out):
+            return self.param(
+                name, nn.initializers.normal(1.0 / math.sqrt(fan_in)),
+                (self.n_experts, fan_in, fan_out))
+
+        return {"w_gate": stacked("w_gate", self.d_model, self.d_ff),
+                "w_up": stacked("w_up", self.d_model, self.d_ff),
+                "w_down": stacked("w_down", self.d_ff, self.d_model)}
 
 
 class _LoRADense(nn.Module):
@@ -162,6 +168,12 @@ class _Attention(nn.Module):
     # RoPE frequency base; raise (e.g. 500000) to stretch usable
     # context (NTK-style scaling)
     rope_base: float = 10000.0
+    # RMS norm of q and k over the head's width, with a learned scale
+    # of head_dim each, before RoPE (the Qwen3 lineage)
+    qk_norm: bool = False
+    # block diffusion (docs/DIFFUSION.md): > 0 and the row is
+    # [noisy ; clean], 2L positions, under ops.attention's bd mask
+    bd_block: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -199,6 +211,9 @@ class _Attention(nn.Module):
             q = dense("q_proj", proj)(x).reshape(shape4)
             k = dense("k_proj", kv * self.head_dim)(x).reshape(kv_shape4)
             v = dense("v_proj", kv * self.head_dim)(x).reshape(kv_shape4)
+        if self.qk_norm:
+            q = nn.RMSNorm(name="q_norm")(q)
+            k = nn.RMSNorm(name="k_norm")(k)
 
         if decode_pos is not None and jnp.ndim(decode_pos) == 0 \
                 and pad_offset is None:
@@ -385,7 +400,13 @@ class _Attention(nn.Module):
                     q, ck.value, cv.value, pos, pad_offset=pad_offset,
                     window=self.window).reshape(shape4)
         else:
-            if pad_offset is None:
+            if self.bd_block:
+                # both halves of [noisy ; clean] sit at positions 0..L-1
+                cos, sin = rope_tables(s // 2, self.head_dim,
+                                       base=self.rope_base)
+                cos, sin = jnp.tile(cos, (2, 1)), jnp.tile(sin, (2, 1))
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            elif pad_offset is None:
                 cos, sin = rope_tables(s, self.head_dim,
                                        base=self.rope_base)
                 q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
@@ -427,13 +448,14 @@ class _Attention(nn.Module):
             o = _dispatch_attention(q, k, v, impl=self.impl,
                                     causal=self.causal, mesh=self.mesh,
                                     window=self.window,
-                                    kv_valid=kv_valid)
+                                    kv_valid=kv_valid,
+                                    bd_block=self.bd_block)
         o = o.reshape(b, s, proj)
         return dense("o_proj", d_model)(o)
 
 
 def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
-                        window: int = 0, kv_valid=None):
+                        window: int = 0, kv_valid=None, bd_block: int = 0):
     """q: (b, s, h, d); k/v may carry FEWER (kv) heads under GQA.
     The single-chip flash path consumes them natively (the kernel
     folds the query group — K/V never materialize at h heads); every
@@ -444,11 +466,24 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
     local full-sequence attention. ``kv_valid`` (``(b, s)`` bool,
     padded-batch prefill) always routes to the dense reference path —
     the sharded/flash kernels take no per-row mask, a documented cost
-    of unequal-length batches (docs/SERVING.md)."""
+    of unequal-length batches (docs/SERVING.md). ``bd_block`` > 0 is a
+    block-diffusion row [noisy ; clean] (docs/DIFFUSION.md): the flash
+    kernels under that mask, or its dense masked softmax on ``dot``;
+    no window, no sequence parallelism."""
     mesh = mesh or mesh_lib.current_mesh()
     b, s, h, _ = q.shape
     kvh = k.shape[2]
     group = h // kvh
+    if bd_block:
+        if window or kv_valid is not None or impl in ("ring", "ulysses"):
+            raise ValueError(
+                "block diffusion runs on the dot and flash paths, with "
+                "no window and no padding mask")
+        flash = functools.partial(attn_ops.flash_bd_attention,
+                                  block_length=bd_block)
+    else:
+        flash = functools.partial(attn_ops.flash_attention,
+                                  causal=causal, window=window)
 
     def repeated():
         if group == 1:
@@ -484,8 +519,7 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
         sharded = tp > 1 or data_size > 1
         if not sharded:
             # GQA-native: unrepeated K/V straight into the kernel
-            return attn_ops.flash_attention(q, k, v, causal=causal,
-                                            window=window)
+            return flash(q, k, v)
         if b % data_size == 0 and h % tp == 0:
             if kvh % tp:
                 # kv heads don't divide tp: repeat up to full heads so
@@ -504,12 +538,13 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
             # check_vma=False: pallas_call emits ShapeDtypeStructs with
             # no varying-mesh-axes info, which the vma checker rejects
             fn = mesh_lib.shard_map(
-                lambda a, b_, c: attn_ops.flash_attention(
-                    a, b_, c, causal=causal, window=window),
-                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_vma=False)
+                flash, mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False)
             return fn(q, k, v)
     # "dot" and all fallbacks (no sp axis, non-divisible shapes)
+    if bd_block:
+        return attn_ops.bd_attention_reference(q, k, v,
+                                               block_length=bd_block)
     kr, vr = repeated()
     return ring_lib.full_attention_reference(q, kr, vr, causal=causal,
                                              window=window)
@@ -534,10 +569,17 @@ class _MLP(nn.Module):
 
 
 class _MoE(nn.Module):
+    """Gated experts behind a router over ``n_experts``; this module
+    HOLDS ``experts_held`` of them (0: all), from ``expert_offset`` on,
+    and gives their part of the layer (parallel/moe.py). Returns
+    ``(out, aux, counts)``: ``counts`` (held,) int32, the routed copies
+    each held expert received."""
     n_experts: int
     d_ff: int
     k: int = 2
     mesh: Any = None
+    experts_held: int = 0
+    expert_offset: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -545,13 +587,14 @@ class _MoE(nn.Module):
         gate = self.param("gate",
                           nn.initializers.normal(1.0 / math.sqrt(d_model)),
                           (d_model, self.n_experts))
-        wi, wo = _Experts(self.n_experts, d_model, self.d_ff,
-                          name="experts")()
-        params = {"gate": gate, "experts": {"wi": wi, "wo": wo}}
+        experts = _Experts(self.experts_held or self.n_experts, d_model,
+                           self.d_ff, name="experts")()
+        params = {"gate": gate, "experts": experts}
         mesh = self.mesh or mesh_lib.current_mesh()
         ep_mesh = mesh if (mesh_lib.EP in mesh.axis_names and
                            mesh.shape[mesh_lib.EP] > 1) else None
-        return moe_lib.moe_layer(params, x, k=self.k, mesh=ep_mesh)
+        return moe_lib.moe_layer(params, x, k=self.k, mesh=ep_mesh,
+                                 expert_offset=self.expert_offset)
 
 
 class _Block(nn.Module):
@@ -570,12 +613,18 @@ class _Block(nn.Module):
     lora_alpha: float = 16.0
     window: int = 0
     rope_base: float = 10000.0
+    experts_held: int = 0
+    expert_offset: int = 0
+    qk_norm: bool = False
+    bd_block: int = 0
 
     @nn.compact
     def __call__(self, x, train: bool, decode_pos=None, cache_len: int = 0,
                  pad_offset=None, kv_len=None, block_tables=None,
                  page_len: int = 0, kv_pages: int = 0,
                  kv_quant: bool = False, verify_limit=None):
+        """Returns ``(x, aux, counts)``; ``counts`` is the expert
+        layer's (held,) copies, of length 0 under a dense MLP."""
         h = nn.RMSNorm(name="attn_norm")(x)
         h = _Attention(self.n_heads, self.head_dim, self.attention,
                        self.causal, self.mesh,
@@ -584,7 +633,8 @@ class _Block(nn.Module):
                        lora_rank=self.lora_rank,
                        lora_alpha=self.lora_alpha,
                        window=self.window,
-                       rope_base=self.rope_base, name="attn")(
+                       rope_base=self.rope_base, qk_norm=self.qk_norm,
+                       bd_block=self.bd_block, name="attn")(
             h, train, decode_pos=decode_pos, cache_len=cache_len,
             pad_offset=pad_offset, kv_len=kv_len,
             block_tables=block_tables, page_len=page_len,
@@ -595,15 +645,17 @@ class _Block(nn.Module):
         x = x + h
         h = nn.RMSNorm(name="mlp_norm")(x)
         aux = jnp.zeros((), jnp.float32)
+        counts = jnp.zeros((0,), jnp.int32)
         if self.n_experts > 0:
-            h, aux = _MoE(self.n_experts, self.d_ff, self.moe_k,
-                          self.mesh, name="moe")(h)
+            h, aux, counts = _MoE(self.n_experts, self.d_ff, self.moe_k,
+                                  self.mesh, self.experts_held,
+                                  self.expert_offset, name="moe")(h)
         else:
             h = _MLP(self.d_ff, fused_gate_up=self.fused_proj,
                      name="mlp")(h)
         if self.dropout and train:
             h = nn.Dropout(self.dropout, deterministic=False)(h)
-        return x + h, aux
+        return x + h, aux, counts
 
 
 class FusedHeadOut(NamedTuple):
@@ -618,6 +670,12 @@ class FusedHeadOut(NamedTuple):
     hidden: Any     # (b, s, d) final-norm output
     kernel: Any     # (d, vocab) lm_head weight
     aux: Any        # MoE load-balance scalar
+    # (layers, held) int32, the copies each held expert received; of
+    # no column under dense MLPs
+    moe_counts: Any = None
+    # block diffusion: the step's noise, {"masked": (b, L) bool,
+    # "t": (b,)}, put here by LanguageModel._apply_fn for the loss
+    noise: Any = None
 
 
 class _LMHead(nn.Module):
@@ -683,6 +741,16 @@ class TransformerLM(nn.Module):
     # activations, "dots" saves matmul outputs only (the standard TPU
     # memory/FLOPs trade), "full" recomputes everything in backward
     remat: str = "none"
+    # width of a head; 0 -> d_model // n_heads
+    head_dim: int = 0
+    qk_norm: bool = False
+    # of n_experts routed over, the experts held here (0: all) and the
+    # first of them (parallel/moe.py)
+    experts_held: int = 0
+    expert_offset: int = 0
+    # block diffusion: > 0 and ``tokens`` is [noisy ; clean], 2L
+    # positions (docs/DIFFUSION.md); the output is FusedHeadOut
+    bd_block: int = 0
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, decode_pos=None,
@@ -693,9 +761,12 @@ class TransformerLM(nn.Module):
         if self.attention not in ATTENTION_IMPLS:
             raise ValueError(f"unknown attention impl: {self.attention!r}")
         d_ff = self.d_ff or 4 * self.d_model
-        head_dim = self.d_model // self.n_heads
+        head_dim = self.head_dim or self.d_model // self.n_heads
         mesh = self.mesh or mesh_lib.current_mesh()
         fuse = self.fused_proj
+        if self.bd_block and not self.fused_head_chunk:
+            raise ValueError("the block-diffusion loss runs through the "
+                             "chunked head: fused_head_chunk must be > 0")
 
         with jax.named_scope("embed"):
             x = nn.Embed(self.vocab_size, self.d_model,
@@ -728,25 +799,32 @@ class TransformerLM(nn.Module):
                                  prevent_cse=True,
                                  static_argnums=(2, 3, 4, 7, 8, 9, 10))
         aux_total = jnp.zeros((), jnp.float32)
+        counts = []
         for i in range(self.n_layers):
-            x, aux = block_cls(self.n_heads, head_dim, d_ff,
-                               self.attention, self.causal,
-                               self.n_experts, self.moe_k,
-                               self.dropout, self.mesh,
-                               self.n_kv_heads, fuse,
-                               self.lora_rank, self.lora_alpha,
-                               self.sliding_window, self.rope_base,
-                               name=f"layer_{i}")(
+            x, aux, layer_counts = block_cls(
+                self.n_heads, head_dim, d_ff,
+                self.attention, self.causal,
+                self.n_experts, self.moe_k,
+                self.dropout, self.mesh,
+                self.n_kv_heads, fuse,
+                self.lora_rank, self.lora_alpha,
+                self.sliding_window, self.rope_base,
+                self.experts_held, self.expert_offset,
+                self.qk_norm, self.bd_block,
+                name=f"layer_{i}")(
                 x, train, decode_pos, cache_len, pad_offset, kv_len,
                 block_tables, page_len, kv_pages, kv_quant,
                 verify_limit)
             aux_total = aux_total + aux
+            counts.append(layer_counts)
         x = nn.RMSNorm(name="final_norm")(x)
         head = _LMHead(self.vocab_size, name="lm_head")
-        if self.fused_head_chunk and train and decode_pos is None:
+        if self.fused_head_chunk and decode_pos is None and \
+                (train or self.bd_block):
             return FusedHeadOut(hidden=x,
                                 kernel=head(x, return_kernel=True),
-                                aux=aux_total)
+                                aux=aux_total,
+                                moe_counts=jnp.stack(counts))
         return head(x), aux_total
 
 
@@ -838,6 +916,64 @@ def _head_chunk_scan_bwd(res, cts):
 _head_chunk_scan.defvjp(_head_chunk_scan_fwd, _head_chunk_scan_bwd)
 
 
+def bd_noise(key, shape: Tuple[int, int], t_low: float = 0.1):
+    """The step's block-diffusion noise for ``shape = (rows, L)``
+    (docs/DIFFUSION.md): row r draws ``t[r]``, uniform on [t_low, 1),
+    from ``fold_in(fold_in(key, 1), r)`` and its L uniforms ``u[r, :]``
+    from ``fold_in(fold_in(key, 2), r)``; position p is masked where
+    ``u[r, p] < t[r]``. ``key`` is the step's own key, which the engine
+    folds from the fit's seed and the global step; a row's noise does
+    not depend on how many rows the batch has (padding rows, shards)."""
+    with jax.named_scope("bd_noise"):
+        rows = jnp.arange(shape[0])
+        k_t, k_u = jax.random.fold_in(key, 1), jax.random.fold_in(key, 2)
+        t = jax.vmap(lambda r: jax.random.uniform(
+            jax.random.fold_in(k_t, r), (), jnp.float32, t_low, 1.0))(rows)
+        u = jax.vmap(lambda r: jax.random.uniform(
+            jax.random.fold_in(k_u, r), shape[1:], jnp.float32))(rows)
+        return t, u < t[:, None]
+
+
+def _moe_counters(out: FusedHeadOut) -> Dict[str, Any]:
+    """The expert layers' router load as epoch-record counters: per
+    layer, the routed copies that landed on held experts and the
+    busiest held expert's copies (each a mean over the epoch's steps:
+    a sum beside a count of 1 a step)."""
+    counts = out.moe_counts
+    if counts is None or counts.shape[-1] == 0:
+        return {}
+    one = jnp.ones((), jnp.float32)
+    counters = {}
+    for i in range(counts.shape[0]):
+        c = counts[i].astype(jnp.float32)
+        counters[f"moeHeldCopies_l{i}"] = (jnp.sum(c), one)
+        counters[f"moeBusiestCopies_l{i}"] = (jnp.max(c), one)
+    return counters
+
+
+def _head_targets(out: FusedHeadOut, batch, weights):
+    """(hidden (b, n, d), targets (b, n), weights (b, n), denominator,
+    counters) of the chunked head, by objective. Next token: position
+    p predicts token p+1, weight 1 where that is no padding, over the
+    sum of the weights (denominator ``None``). Block diffusion
+    (``out.noise``): the noisy half's position p predicts ``x0[p]``
+    where it was masked, weight ``1/t`` of its row, over all the row's
+    tokens that are no padding."""
+    tokens = batch["x"].astype(jnp.int32)
+    if out.noise is None:
+        tgt, tok_mask = _token_targets(batch, weights)
+        return out.hidden[:, :-1], tgt, tok_mask, None, {}
+    real = (tokens != 0).astype(jnp.float32)
+    if weights is not None:
+        real = real * weights.astype(jnp.float32)[:, None]
+    masked = out.noise["masked"].astype(jnp.float32) * real
+    w = masked / out.noise["t"][:, None]
+    counters = {"maskedPositions": (jnp.sum(masked),
+                                    jnp.ones((), jnp.float32))}
+    return (out.hidden[:, :tokens.shape[1]], tokens, w, jnp.sum(real),
+            counters)
+
+
 @jax.named_scope("head_loss")
 def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
                      aux_coef: float):
@@ -856,8 +992,7 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
     0.50, ``dh`` 2.49, ``dw`` 2.79, 8.3 ms in all where the
     checkpointed scan it replaced took 10.8; one product is 1.97 ms
     at the chip's peak."""
-    tgt, tok_mask = _token_targets(batch, weights)
-    hs = out.hidden[:, :-1]
+    hs, tgt, tok_mask, count, counters = _head_targets(out, batch, weights)
     b, sm1, d = hs.shape
     t_total = b * sm1
     chunk = max(1, min(chunk, t_total))  # no padding blowup on tiny shapes
@@ -874,8 +1009,14 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
         hs.reshape(n_chunks, chunk, d), tg.reshape(n_chunks, chunk),
         mk.reshape(n_chunks, chunk), out.kernel.astype(hs.dtype))
     total = jnp.maximum(jnp.sum(mk), 1e-9)
-    loss = loss_sum / total + aux_coef * out.aux.astype(jnp.float32)
-    return loss, {"accuracy": (ok_sum, total)}
+    loss = loss_sum / (total if count is None
+                       else jnp.maximum(count, 1e-9)) \
+        + aux_coef * out.aux.astype(jnp.float32)
+    # accuracy over the weighted targets (under block diffusion: the
+    # masked positions, each by its row's 1/t)
+    counters["accuracy"] = (ok_sum, total)
+    counters.update(_moe_counters(out))
+    return loss, counters
 
 
 @jax.named_scope("head_loss")
@@ -1002,7 +1143,10 @@ def next_token_loss(aux_coef: float = 0.01, head_chunk: int = 1024,
     ``(loss, {"accuracy": (sum, count)})`` — the engine merges
     loss-emitted metrics. With a sequence-parallel mesh the chunked
     scan runs inside ``shard_map`` (see
-    :func:`_fused_head_loss_sharded`)."""
+    :func:`_fused_head_loss_sharded`). A :class:`FusedHeadOut` that
+    carries ``noise`` is a block-diffusion step: the same chunked head
+    over the noisy half, masked positions weighted ``1/t``
+    (:func:`_head_targets`)."""
     import optax
 
     def loss_fn(outputs, batch, weights):
@@ -1017,7 +1161,8 @@ def next_token_loss(aux_coef: float = 0.01, head_chunk: int = 1024,
             # shard_map needs divisible mapped dims (incl. the vocab
             # columns under tp); odd shapes fall back to the flat
             # path (GSPMD gathers — correct, bigger)
-            if sp > 1 and b % data_size == 0 and s % sp == 0 \
+            if outputs.noise is None and sp > 1 \
+                    and b % data_size == 0 and s % sp == 0 \
                     and vocab % tp == 0:
                 return _fused_head_loss_sharded(
                     outputs, batch, weights, head_chunk, aux_coef, m)
@@ -1089,10 +1234,10 @@ class TransformerEncoder(nn.Module):
             else None,
             None)
         for i in range(self.n_layers):
-            x, _ = _Block(self.n_heads, head_dim, d_ff,
-                          self.attention, False, 0, 2,
-                          self.dropout, self.mesh, self.n_kv_heads,
-                          name=f"layer_{i}")(x, train)
+            x, _, _ = _Block(self.n_heads, head_dim, d_ff,
+                             self.attention, False, 0, 2,
+                             self.dropout, self.mesh, self.n_kv_heads,
+                             name=f"layer_{i}")(x, train)
         x = nn.RMSNorm(name="final_norm")(x)
         mask = (tokens != 0).astype(jnp.float32)[..., None]
         pooled = jnp.sum(x * mask, axis=1) / jnp.maximum(
@@ -1445,7 +1590,10 @@ class LanguageModel:
                     "n_experts", "moe_k",
                     "dropout", "aux_coef", "head_chunk", "remat",
                     "fused_proj", "lora_rank", "lora_alpha",
-                    "sliding_window", "rope_base")
+                    "sliding_window", "rope_base", "head_dim", "qk_norm",
+                    "experts_held", "expert_offset", "objective",
+                    "block_length", "mask_token_id")
+    OBJECTIVES = ("next_token", "block_diffusion")
 
     def __init__(self, vocab_size: int, d_model: int = 256,
                  n_layers: int = 4, n_heads: int = 4,
@@ -1456,8 +1604,51 @@ class LanguageModel:
                  remat: Optional[str] = None, fused_proj: bool = False,
                  lora_rank: int = 0, lora_alpha: float = 16.0,
                  sliding_window: int = 0, rope_base: float = 10000.0,
+                 head_dim: int = 0, qk_norm: bool = False,
+                 experts_held: int = 0, expert_offset: int = 0,
+                 objective: str = "next_token", block_length: int = 4,
+                 mask_token_id: Optional[int] = None,
                  name: str = "language_model"):
         self.name = name
+        self.head_dim = int(head_dim)
+        self.qk_norm = bool(qk_norm)
+        if self.head_dim < 0 or self.head_dim % 2:
+            raise ValueError(f"head_dim must be even and >= 0 (0: d_model "
+                             f"// n_heads), got {head_dim}")
+        # of the n_experts the router spans, this model holds
+        # experts_held (0: all), from expert_offset on: one expert-
+        # parallel rank's share of every expert layer
+        self.experts_held = int(experts_held)
+        self.expert_offset = int(expert_offset)
+        held = self.experts_held or int(n_experts)
+        if min(self.experts_held, self.expert_offset) < 0 or \
+                self.expert_offset + held > int(n_experts):
+            raise ValueError(
+                f"experts_held={experts_held} from expert_offset="
+                f"{expert_offset} are not among n_experts={n_experts}")
+        if objective not in self.OBJECTIVES:
+            raise ValueError(f"objective must be one of {self.OBJECTIVES}, "
+                             f"got {objective!r}")
+        # "block_diffusion" trains by masked diffusion over blocks of
+        # block_length tokens (docs/DIFFUSION.md); mask_token_id is the
+        # id a masked position shows (None: the vocabulary's last,
+        # resolved here and saved as a number)
+        self.objective = objective
+        self.block_length = int(block_length)
+        if self.block_length < 1:
+            raise ValueError(f"block_length must be >= 1, got "
+                             f"{block_length}")
+        self.mask_token_id = int(vocab_size) - 1 if mask_token_id is None \
+            else int(mask_token_id)
+        if objective == "block_diffusion" and (
+                attention in ("ring", "ulysses") or sliding_window
+                or int(max_len) % self.block_length
+                or not 0 <= self.mask_token_id < int(vocab_size)):
+            raise ValueError(
+                "objective='block_diffusion' runs on the dot and flash "
+                "paths with no sliding window, rows of whole blocks "
+                f"(max_len={max_len}, block_length={block_length}) and a "
+                f"mask_token_id inside the vocabulary")
         self.head_chunk = head_chunk
         self.fused_proj = bool(fused_proj)
         self.lora_rank = int(lora_rank)
@@ -1553,9 +1744,13 @@ class LanguageModel:
         env = os.environ.get("LO_LM_HEAD_CHUNK")
         if env is not None:
             return max(0, int(env))
-        if self.head_chunk is not None:
+        if self.head_chunk is not None and (
+                self.head_chunk or self.objective == "next_token"):
             return max(0, int(self.head_chunk))
-        return 1024 if self.vocab_size >= 8192 else 0
+        # the block-diffusion loss exists only through the chunked head
+        fused = self.vocab_size >= 8192 or \
+            self.objective == "block_diffusion"
+        return 1024 if fused else 0
 
     def _param_rules(self, mesh):
         """TP sharding rules, head-granular: a projection whose HEAD
@@ -1605,7 +1800,10 @@ class LanguageModel:
             f"LO_TLM_FUSED_PROJ={env!r} (want 1/true/yes or "
             f"0/false/no)")
 
-    def _module_for(self, seq_len: Optional[int] = None) -> TransformerLM:
+    def _module_for(self, seq_len: Optional[int] = None,
+                    bd: bool = False) -> TransformerLM:
+        """``bd``: the module of a block-diffusion step, whose rows are
+        [noisy ; clean] (``seq_len`` is then L, the row of tokens)."""
         return TransformerLM(
             vocab_size=self.vocab_size, d_model=self.d_model,
             n_layers=self.n_layers, n_heads=self.n_heads,
@@ -1618,7 +1816,10 @@ class LanguageModel:
             fused_proj=self._resolved_fused_proj(),
             lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
             sliding_window=self.sliding_window,
-            rope_base=self.rope_base)
+            rope_base=self.rope_base, head_dim=self.head_dim,
+            qk_norm=self.qk_norm, experts_held=self.experts_held,
+            expert_offset=self.expert_offset,
+            bd_block=self.block_length if bd else 0)
 
     @property
     def module(self) -> TransformerLM:
@@ -1642,16 +1843,58 @@ class LanguageModel:
                                     self.dropout) else None
         # batch["x"].shape is static under jit, so "auto" attention
         # resolves against the real window length at trace time
-        module = self._module_for(int(batch["x"].shape[1]))
+        seq = int(batch["x"].shape[1])
+        if self.objective == "block_diffusion":
+            return self._apply_bd(params, batch["x"], train, rng,
+                                  rngs), model_state
+        module = self._module_for(seq)
         out = module.apply({"params": params}, batch["x"],
                            train=train, rngs=rngs)
         return out, model_state
+
+    def _apply_bd(self, params, x0, train, rng, rngs) -> FusedHeadOut:
+        """One block-diffusion pass (docs/DIFFUSION.md): the step's
+        noise from the step's key, the model once over [xt ; x0], and
+        the noise handed on to the loss. Evaluation has no step: its
+        noise is that of ``PRNGKey(seed)``."""
+        seq = x0.shape[1]
+        key = jax.random.PRNGKey(self.seed) if rng is None else rng
+        t, masked = bd_noise(key, x0.shape)
+        x0 = x0.astype(jnp.int32)
+        xt = jnp.where(masked, jnp.int32(self.mask_token_id), x0)
+        out = self._module_for(seq, bd=True).apply(
+            {"params": params}, jnp.concatenate([xt, x0], axis=1),
+            train=train, rngs=rngs)
+        return out._replace(noise={"masked": masked, "t": t})
 
     def _build_params(self, sample_x: np.ndarray) -> None:
         rng = jax.random.PRNGKey(self.seed)
         variables = self.module.init(rng, jnp.asarray(sample_x[:1]),
                                      train=False)
         self.params = dict(variables)["params"]
+
+    def _engine_cache_key(self):
+        """Identity of the traced program (``NeuralModel``'s rule): the
+        constructor's settings, the optimizer's, the evaluation noise's
+        seed and every ``LO_*`` environment setting (remat, fused
+        projections, head chunk and kernel tiles are resolved from them
+        while the step is traced). A second job of the same model, a
+        new instance loaded from the same artifact, then runs the first
+        job's jitted steps: no trace, no lowering, no read of the
+        compile cache (PERF.md section 6, PR 26: three traces of 14 to
+        21 s each on the chip's host for the expert model)."""
+        try:
+            key = ("lm", type(self).__qualname__,
+                   tuple((k, getattr(self, k)) for k in self._CONFIG_KEYS),
+                   int(self.seed),
+                   tuple(sorted(self.optimizer_spec.items())),
+                   self._mesh_override,
+                   tuple(sorted((k, v) for k, v in os.environ.items()
+                                if k.startswith("LO_"))))
+            hash(key)
+            return key
+        except TypeError:  # an unhashable setting: no sharing
+            return None
 
     def _get_engine(self) -> engine_lib.Engine:
         if self._engine is None:
@@ -1673,7 +1916,22 @@ class LanguageModel:
                 b, s = batch["x"].shape[:2]
                 matmul_params = (self.num_params()
                                  - self.vocab_size * self.d_model)
-                attn = 6.0 * self.n_layers * b * s * s * self.d_model
+                proj = self.n_heads * (self.head_dim
+                                       or self.d_model // self.n_heads)
+                attn = 6.0 * self.n_layers * b * s * s * proj
+                if self.n_experts:
+                    # a token meets moe_k of n_experts: of the held
+                    # experts' matrices, that share
+                    held = self.experts_held or self.n_experts
+                    expert = 3 * self.d_model * self.d_ff * self.n_layers
+                    matmul_params -= expert * held * (
+                        1.0 - self.moe_k / self.n_experts)
+                if self.objective == "block_diffusion":
+                    # 2L positions through the layers, the head over
+                    # L; each position sees L keys and a block
+                    head = self.vocab_size * self.d_model
+                    return (6.0 * max(matmul_params - head, 0) * b * 2 * s
+                            + 6.0 * head * b * s + 2 * attn)
                 return 6.0 * max(matmul_params, 0) * b * s + attn
 
             optimizer = build_optimizer(self.optimizer_spec)
@@ -1694,7 +1952,9 @@ class LanguageModel:
                     mesh, sharding_lib.batch_spec(mesh, seq_axis=seq_axis)),
                 predict_transform=lambda outputs: outputs[0],
                 flops_floor_fn=flops_floor,
-                grad_accum=self._accum)
+                grad_accum=self._accum,
+                counter_prefixes=("moe", "masked"),
+                cache_key=self._engine_cache_key())
         return self._engine
 
     def _set_grad_accum(self, grad_accum: Optional[int]) -> None:
@@ -1776,6 +2036,7 @@ class LanguageModel:
     def predict(self, x=None, batch_size: Optional[int] = None,
                 **_: Any) -> np.ndarray:
         """Next-token logits (n, seq, vocab)."""
+        self._require_autoregressive("predict")
         self._require_built()
         eng = self._get_engine()
         state = self._state or eng.init_state(self.params)
@@ -1812,6 +2073,7 @@ class LanguageModel:
         so rows stay rectangular; slice ``row[pad:]`` to recover the
         solo-shaped sequence.
         """
+        self._require_autoregressive("generate")
         self._require_built()
         if num_beams > 1:
             if temperature > 0:
@@ -2150,6 +2412,7 @@ class LanguageModel:
           the session cache at ``slot`` (traced index — one compile
           covers every slot, so slot reuse never recompiles).
         """
+        self._require_autoregressive("serving")
         fns = self._serve_cache_fns
         sig = (slots, cache_len, temperature, top_k, top_p)
         if sig not in fns:
@@ -2258,6 +2521,7 @@ class LanguageModel:
         five functions over half the pool bytes, with dequant fused
         into the gather/step.
         """
+        self._require_autoregressive("serving")
         fns = self._serve_paged_fns
         sig = (slots, cache_len, page_len, n_pages, temperature,
                top_k, top_p, kv_dtype)
@@ -2430,6 +2694,7 @@ class LanguageModel:
         window overrunning a stream's pages can never corrupt a
         neighbor (the host discards the overrun emissions).
         """
+        self._require_autoregressive("serving")
         fns = self._serve_spec_fns
         sig = ("verify", slots, cache_len, page_len, n_pages, spec_k,
                temperature, top_k, top_p, kv_dtype)
@@ -2511,6 +2776,7 @@ class LanguageModel:
         complete prefix whatever the acceptance count was. Greedy
         proposals make the proposal distribution one-hot, which is
         what keeps acceptance sampling exact (see serve_fns_spec)."""
+        self._require_autoregressive("serving")
         fns = self._serve_spec_fns
         sig = ("draft", slots, cache_len, spec_k)
         if sig in fns:
@@ -2537,6 +2803,15 @@ class LanguageModel:
 
         fns[sig] = propose
         return fns[sig]
+
+    def _require_autoregressive(self, what: str) -> None:
+        """Generation by blocks (several denoising passes a block, a
+        step that yields a block and not a token) is not built yet
+        (ROADMAP): a block-diffusion model trains and evaluates."""
+        if self.objective != "next_token":
+            raise NotImplementedError(
+                f"{what} of an objective={self.objective!r} model: "
+                f"block-wise denoising generation is not implemented")
 
     def _require_built(self) -> None:
         if self.params is None:
@@ -2654,10 +2929,15 @@ class LanguageModel:
             sample = np.zeros((1, 8), np.int32)
             weights = os.path.join(path, "weights.msgpack")
             with obs_trace.span("paramInit"):
-                model._build_params(sample)
+                # the tree's structure alone: every leaf is read from
+                # the file, so nothing is initialised to be overwritten
+                template = jax.eval_shape(
+                    lambda: model.module.init(
+                        jax.random.PRNGKey(model.seed),
+                        jnp.asarray(sample), train=False))["params"]
             with obs_trace.span("weightsRead",
                                 bytes=os.path.getsize(weights)):
                 restored = ckpt.load_pytree(weights,
-                                            {"params": model.params})
+                                            {"params": dict(template)})
             model.params = restored["params"]
         return model

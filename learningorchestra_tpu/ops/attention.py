@@ -183,6 +183,18 @@ def _q_index_map(block_q: int, block_k: int, window: int,
     return index
 
 
+def _bd_visible(row, col, strict, block: int):
+    """Block-diffusion visibility of CLEAN key ``col`` to query ``row``
+    (both positions within the row of tokens): the causal diagonal
+    rounded up to blocks of ``block``, ``blk(col) <= blk(row)``, and
+    for a noisy query (``strict``, a scalar of the tile) strictly
+    below it, ``blk(col) < blk(row)``. The tile-level skip stays the
+    causal one: with tile edges that are multiples of ``block`` no
+    tile above the diagonal holds a visible key."""
+    row_start = row - lax.rem(row, block)
+    return col < row_start + jnp.where(strict, 0, block)
+
+
 def _resolve_blocks(block_q: Optional[int], block_k: Optional[int],
                     sq: int, sk: int) -> Tuple[int, int]:
     return (int(block_q) if block_q else _auto_block(sq),
@@ -197,7 +209,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 *, scale: float, causal: bool, kv_len: int,
                 block_q: int, block_k: int, window: int = 0,
                 nk_total: int = 0, nq_head: int = 0,
-                offset: int = 0):
+                offset: int = 0, bd_block: int = 0, bd_group: int = 0):
     # grouped-query folding: the q-row axis stacks `group` query heads
     # per kv head, so the tile's POSITION within its head is
     # i % nq_head (== i when ungrouped) — all causal/window math uses
@@ -242,7 +254,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal or window > 0:
             row = ih * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-        if causal:
+        if bd_block:
+            valid = jnp.logical_and(
+                valid, _bd_visible(row, col, i // nq_head < bd_group,
+                                   bd_block))
+        elif causal:
             valid = jnp.logical_and(valid, row >= col + offset)
         if window > 0:
             valid = jnp.logical_and(valid,
@@ -280,7 +296,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _fwd_pallas(q, k, v, *, scale: float, causal: bool,
                 block_q: int, block_k: int, interpret: bool,
                 window: int = 0, group: int = 1, seq_q: int = 0,
-                offset: int = 0
+                offset: int = 0, bd_block: int = 0, bd_group: int = 0
                 ) -> Tuple[jax.Array, jax.Array]:
     """q: (b·kv, group·sq_p, d) pre-padded/folded (``_fold_q``);
     k/v: (b·kv, sk, d). Returns (o, lse) in the folded layout.
@@ -302,7 +318,8 @@ def _fwd_pallas(q, k, v, *, scale: float, causal: bool,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, kv_len=sk,
         block_q=block_q, block_k=block_k, window=window, nk_total=nk,
-        nq_head=nq_head, offset=offset)
+        nq_head=nq_head, offset=offset, bd_block=bd_block,
+        bd_group=bd_group)
     kv_map = _kv_index_map(block_q, block_k, window, causal, nk,
                            nq_head, offset)
     lanes = 128
@@ -335,7 +352,7 @@ def _fwd_pallas(q, k, v, *, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_bd_fwd" if bd_block else "flash_fwd",
     )(q, k, v)
     return o[..., :d], lse[..., 0]
 
@@ -348,7 +365,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    *, scale: float, causal: bool, kv_len: int,
                    block_q: int, block_k: int, window: int = 0,
                    nk_total: int = 0, nq_head: int = 0,
-                   offset: int = 0):
+                   offset: int = 0, bd_block: int = 0, bd_group: int = 0):
     """Grid (bh, q_blocks, kv_band): Q/dO resident, K/V stream the
     band (same clamped-index revisit scheme as the forward; grouped
     folding puts `group` query heads on the q axis — see
@@ -388,7 +405,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal or window > 0:
             row = ih * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-        if causal:
+        if bd_block:
+            valid = jnp.logical_and(
+                valid, _bd_visible(row, col, i // nq_head < bd_group,
+                                   bd_block))
+        elif causal:
             valid = jnp.logical_and(valid, row >= col + offset)
         if window > 0:
             valid = jnp.logical_and(valid,
@@ -412,7 +433,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *, scale: float, causal: bool, kv_len: int,
                     block_q: int, block_k: int, window: int = 0,
                     nq_total: int = 0, band_ni: int = 0,
-                    offset: int = 0):
+                    offset: int = 0, bd_block: int = 0, bd_group: int = 0):
     """Grid (bh·kv, kv_blocks, group·q_band): K/V resident, Q/dO
     stream the band of q tiles whose rows can see this kv tile
     (causal: from the diagonal down; window: at most W-1 rows past
@@ -458,7 +479,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal or window > 0:
             row = i_eff * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-        if causal:
+        if bd_block:
+            valid = jnp.logical_and(
+                valid, _bd_visible(row, col, i // band_ni < bd_group,
+                                   bd_block))
+        elif causal:
             valid = jnp.logical_and(valid, row >= col + offset)
         if window > 0:
             valid = jnp.logical_and(valid,
@@ -484,7 +509,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
                 block_q: int, block_k: int, interpret: bool,
                 dlse=None, window: int = 0, group: int = 1,
-                seq_q: int = 0, offset: int = 0):
+                seq_q: int = 0, offset: int = 0, bd_block: int = 0,
+                bd_group: int = 0):
     """Folded layout (see ``_fwd_pallas``): q/o/do (b·kv, g·sq_p, d),
     lse (b·kv, g·sq_p), k/v (b·kv, sk, d). Returns (dq, dk, dv) in
     the same folded layout. ``seq_q`` is the per-head padded q length.
@@ -533,7 +559,8 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           kv_len=sk, block_q=block_q, block_k=block_k,
                           window=window, nk_total=nk, nq_head=nq_head,
-                          offset=offset),
+                          offset=offset, bd_block=bd_block,
+                          bd_group=bd_group),
         grid=(bh, group * nq_head, nj),
         in_specs=[q_spec_i, kv_spec_j, kv_spec_j, q_spec_i, row_spec_i,
                   row_spec_i],
@@ -544,7 +571,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bd_bwd_dq" if bd_block else "flash_bwd_dq",
     )(q, k, v, do, lse_l, delta_l)
 
     # second kernel: K/V resident, Q streams — grid dims (b, j, i)
@@ -558,7 +585,8 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           kv_len=sk, block_q=block_q, block_k=block_k,
                           window=window, nq_total=nq_head,
-                          band_ni=band_ni, offset=offset),
+                          band_ni=band_ni, offset=offset,
+                          bd_block=bd_block, bd_group=bd_group),
         grid=(bh, sk_p // block_k, group * band_ni),
         in_specs=[q_spec_g2, kv_spec_g2, kv_spec_g2, q_spec_g2,
                   row_spec_g2, row_spec_g2],
@@ -570,7 +598,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bd_bwd_dkv" if bd_block else "flash_bwd_dkv",
     )(q, k, v, do, lse_l, delta_l)
     return (dq[..., :d], dk[:, :sk, :d], dv[:, :sk, :d])
 
@@ -703,6 +731,191 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, window,
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+# ----------------------------------------------------------------------
+# block diffusion: [noisy ; clean] queries over the clean keys
+# ----------------------------------------------------------------------
+def _fold_q_bd(x, kvh: int, group: int, sq_p: int):
+    """(b, 2L, h, d) -> (b*kv, 2*group*sq_p, d): as ``_fold_q`` with
+    the two halves of the doubled row as a further, outer part of the
+    group, the noisy half's ``group`` heads first. The kernels tell a
+    tile's half by ``i // nq_head < group``."""
+    b, s2, h, d = x.shape
+    half = s2 // 2
+    x = x.reshape(b, 2, half, kvh, group, d)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, sq_p - half)) + ((0, 0),) * 3)
+    x = x.transpose(0, 3, 1, 4, 2, 5)
+    return x.reshape(b * kvh, 2 * group * sq_p, d)
+
+
+def _unfold_q_bd(x, b: int, kvh: int, group: int, sq_p: int, half: int):
+    """Inverse of ``_fold_q_bd`` for (.., d) outputs and (..,) rows."""
+    tail = x.shape[2:]
+    x = x.reshape((b, kvh, 2, group, sq_p) + tail)
+    x = jnp.moveaxis(x, (2, 4, 1, 3), (1, 2, 3, 4))[:, :, :half]
+    return x.reshape((b, 2 * half, kvh * group) + tail)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bd(q, k, v, scale, block_q, block_k, interpret, bd_block):
+    """q: (b, 2L, h, d), the noisy half first; k, v: (b, L, kv, d),
+    the CLEAN keys. Returns (out (b, 2L, h, d), lse (b, 2L, h)): lse
+    carries gradient (the noisy half's merge with its own block)."""
+    out, _ = _flash_bd_fwd(q, k, v, scale, block_q, block_k, interpret,
+                           bd_block)
+    return out
+
+
+def _flash_bd_fwd(q, k, v, scale, block_q, block_k, interpret, bd_block):
+    b, s2, h, d = q.shape
+    half = s2 // 2
+    kvh = k.shape[2]
+    group = h // kvh
+    bq = min(block_q, _round_up(half, 8))
+    sq_p = _round_up(half, bq)
+    qf = _fold_q_bd(q, kvh, group, sq_p)
+    kf, vf = _merge_kv(k), _merge_kv(v)
+    o, lse = _fwd_pallas(qf, kf, vf, scale=scale, causal=True,
+                         block_q=bq, block_k=block_k,
+                         interpret=interpret, group=2 * group,
+                         seq_q=sq_p, bd_block=bd_block, bd_group=group)
+    meta = (b, half, kvh, group, bq, sq_p)
+    return ((_unfold_q_bd(o, b, kvh, group, sq_p, half),
+             _unfold_q_bd(lse, b, kvh, group, sq_p, half)),
+            (qf, kf, vf, o, lse, meta))
+
+
+def _flash_bd_bwd(scale, block_q, block_k, interpret, bd_block, res, g):
+    qf, kf, vf, o, lse, meta = res
+    b, half, kvh, group, bq, sq_p = meta
+    do, dlse = g
+    dq, dk, dv = _bwd_pallas(
+        qf, kf, vf, o, lse, _fold_q_bd(do, kvh, group, sq_p),
+        scale=scale, causal=True, block_q=bq, block_k=block_k,
+        interpret=interpret,
+        dlse=_fold_q_bd(dlse[..., None], kvh, group, sq_p)[..., 0],
+        group=2 * group, seq_q=sq_p, bd_block=bd_block, bd_group=group)
+    return (_unfold_q_bd(dq, b, kvh, group, sq_p, half).astype(qf.dtype),
+            _split_kv(dk, b, kvh).astype(kf.dtype),
+            _split_kv(dv, b, kvh).astype(vf.dtype))
+
+
+_flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
+
+
+def _bd_block_diagonal(q, k, v, block: int, scale: float):
+    """The noisy half's attention to its OWN block of noisy keys:
+    q (b, L, h, d), k/v (b, L, kv, d) -> (out float32 (b, L, h, d),
+    lse (b, L, h)). ``block`` keys a query: plain XLA."""
+    b, sq, h, d = q.shape
+    kvh = k.shape[2]
+    nb = sq // block
+    qb = q.reshape(b, nb, block, kvh, h // kvh, d)
+    kb = k.reshape(b, nb, block, kvh, d)
+    vb = v.reshape(b, nb, block, kvh, d)
+    s = jnp.einsum("bnqcgd,bnkcd->bncgqk", qb, kb,
+                   preferred_element_type=jnp.float32) * scale
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    o = jnp.einsum("bncgqk,bnkcd->bnqcgd", p, vb.astype(jnp.float32))
+    return (o.reshape(b, sq, h, d),
+            jnp.moveaxis(lse, 4, 2).reshape(b, sq, h))
+
+
+def _check_bd(q, k, block_length: int):
+    s2, h, kvh = q.shape[1], q.shape[2], k.shape[2]
+    if h % kvh:
+        raise ValueError(f"q has {h} heads but k/v have {kvh}")
+    if block_length < 1 or s2 % 2 or (s2 // 2) % block_length:
+        raise ValueError(
+            f"block diffusion wants [noisy ; clean] rows of 2L positions "
+            f"with block_length | L; got {s2} positions, block_length "
+            f"{block_length}")
+    return s2 // 2
+
+
+def flash_bd_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                       block_length: int,
+                       block_q: Optional[int] = None,
+                       block_k: Optional[int] = None,
+                       interpret: Optional[bool] = None) -> jax.Array:
+    """Attention of a block-diffusion training row (docs/DIFFUSION.md).
+
+    ``q`` (b, 2L, h, d) and ``k``/``v`` (b, 2L, kv, d) hold the noisy
+    copy ``xt`` of a row in positions 0..L-1 and the clean row ``x0``
+    in L..2L-1. With ``blk(p) = p // block_length`` of the position
+    within the row: a noisy query sees the noisy keys of its own block
+    and the clean keys of the blocks before it; a clean query sees the
+    clean keys of its own block and of those before; no query of the
+    clean half sees a noisy key.
+
+    One kernel call takes both halves' queries over the clean keys (the
+    kernels ``flash_bd_fwd``, ``flash_bd_bwd_dq``, ``flash_bd_bwd_dkv``:
+    the flash kernels under this mask, tiles above the diagonal skipped
+    as under the causal one); the noisy half's own block, ``block_length``
+    keys a query, is plain XLA, and the two parts merge by their
+    log-sum-exps. GQA-native like :func:`flash_attention`."""
+    half = _check_bd(q, k, block_length)
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    if interpret is None:
+        interpret = _auto_interpret()
+    if block_q is None and block_k is None and half >= 4096 \
+            and _auto_block(half) == 512:
+        # rows of 4096 and more, 8 query heads folded on a KV head:
+        # tiles of 1024 take 31.5 ms forward and backward where 512
+        # take 35.3 (one layer of the sdar cell, PERF.md PR 26); the
+        # diagonal's blunter skip costs less than the grid's steps
+        block_q = block_k = 1024
+    block_q, block_k = _resolve_blocks(block_q, block_k, half, half)
+    if block_q % block_length or block_k % block_length:
+        raise ValueError(
+            f"tile edges ({block_q}, {block_k}) must be multiples of "
+            f"block_length {block_length}")
+    o, lse = _flash_bd(q, k[:, half:], v[:, half:], float(scale), block_q,
+                       block_k, bool(interpret), int(block_length))
+    with jax.named_scope("bd_diag"):
+        o_d, lse_d = _bd_block_diagonal(q[:, :half], k[:, :half],
+                                        v[:, :half], int(block_length),
+                                        float(scale))
+        # a query of block 0 sees no clean key: lse = NEG_INF there,
+        # and its own block is the whole of its output
+        lse_c = lse[:, :half]
+        top = jnp.maximum(lse_c, lse_d)
+        w_c = jnp.exp(lse_c - top)[..., None]
+        w_d = jnp.exp(lse_d - top)[..., None]
+        noisy = (w_c * o[:, :half].astype(jnp.float32) + w_d * o_d) \
+            / (w_c + w_d)
+    return jnp.concatenate([noisy.astype(o.dtype), o[:, half:]], axis=1)
+
+
+def bd_visible_mask(half: int, block_length: int) -> jax.Array:
+    """(2L, 2L) bool: which key each query of a [noisy ; clean] row
+    sees (the rule in :func:`flash_bd_attention`)."""
+    pos = jnp.arange(2 * half)
+    noisy = pos < half
+    blk = (pos % half) // block_length
+    qn, kn = noisy[:, None], noisy[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return jnp.where(qn, jnp.where(kn, qb == kb, kb < qb),
+                     jnp.logical_and(~kn, kb <= qb))
+
+
+def bd_attention_reference(q, k, v, *, block_length: int) -> jax.Array:
+    """Dense masked softmax of the same rule: the ``dot`` path (CPU,
+    short rows) and the kernels' oracle. Holds (2L, 2L) scores."""
+    half = _check_bd(q, k, block_length)
+    group = q.shape[2] // k.shape[2]
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    kr = jnp.repeat(k, group, axis=2) if group > 1 else k
+    vr = jnp.repeat(v, group, axis=2) if group > 1 else v
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(bd_visible_mask(half, block_length)[None, None], s,
+                  NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, vr.astype(jnp.float32))
+    return o.astype(q.dtype)
 
 
 # ----------------------------------------------------------------------

@@ -1,28 +1,33 @@
-"""Mixture-of-experts with expert parallelism over the ``ep`` axis.
+"""Mixture-of-experts: gated (SwiGLU) experts behind a softmax router.
 
-Two dispatch schedules with IDENTICAL routing semantics (top-k,
-shared per-expert capacity with choice-0 priority, token-order
-tie-break, renormalized gate weights):
+``moe_layer`` routes every token over ALL ``n_experts`` (float32
+softmax, top-k, renormalised over the k) and computes the part of the
+result that the experts HELD here give: ``experts_held`` of them, from
+``expert_offset`` on (all of them by default). What the absent experts
+would add is left out: that is one expert-parallel rank's share of the
+layer, and the shares of all ranks add up to the whole layer
+(tests/test_parallel.py). No token is dropped: there is no capacity.
 
-- ``route="sparse"`` (default) — sort/segment routing: the (T·k)
-  token-copies are stably sorted by expert id (choice-major, so
-  earlier choices win capacity), each copy's slot inside its expert's
-  (capacity, d) buffer comes from a cumsum of per-expert counts, and
-  dispatch/combine are two O(T·k·d) scatter/gathers. Peak routing
-  memory is O(E·C·d + T·k) — no (T, E, C) tensor ever exists, so
-  T=8k, E=32 routes fine.
-- ``route="dense"`` — GShard-style (T, E, C) one-hot dispatch where
-  routing is three einsums; simplest lowering to all-to-alls under
-  GSPMD but O(T·E·C) memory. Kept for small-shape parity checks.
+The held experts' products run as ONE grouped product
+(``ops/grouped_matmul.py``): the routed copies are sorted by expert
+(:func:`grouped_layout`), each expert's run padded to whole row tiles,
+gathered, multiplied (gate, up, down) and scattered back weighted. The
+row buffer is static and holds the worst case (every choice of every
+token held here), so every copy is computed whatever the router does;
+the products skip the tiles that hold no copy, the gather and the
+scatter-add do not.
 
-Both are static-shape and jit/vjp-safe (sort indices are constants of
-the backward pass; gradients flow through values and gate weights).
+On a mesh whose ``ep`` axis is larger than 1 the older schedule stays
+until a four-chip cell measures its replacement (ROADMAP S17): experts
+sharded over ``ep``, every expert padded to a shared capacity
+(``capacity_factor``), copies past it DROPPED, the exchange left to
+GSPMD. Same router, same gated experts.
 
-Functional params layout (stacked experts, shardable by
-sharding.TRANSFORMER_RULES):
-  ``gate``          (d_model, n_experts)   — replicated
-  ``experts/wi``    (n_experts, d_model, d_ff)
-  ``experts/wo``    (n_experts, d_ff, d_model)
+Parameters (stacked experts, shardable by sharding.TRANSFORMER_RULES):
+  ``gate``             (d_model, n_experts)   the router, replicated
+  ``experts/w_gate``   (experts_held, d_model, d_ff)
+  ``experts/w_up``     (experts_held, d_model, d_ff)
+  ``experts/w_down``   (experts_held, d_ff, d_model)
 """
 
 from __future__ import annotations
@@ -34,83 +39,143 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from learningorchestra_tpu.ops import grouped_matmul as gmm_ops
 from learningorchestra_tpu.parallel import sharding as sharding_lib
 from learningorchestra_tpu.runtime import mesh as mesh_lib
 
 
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
-                    dtype=jnp.float32) -> Dict[str, Any]:
-    kg, ki, ko = jax.random.split(rng, 3)
+                    experts_held: int = 0, dtype=jnp.float32,
+                    ) -> Dict[str, Any]:
+    held = experts_held or n_experts
+    kr, kg, ku, kd = jax.random.split(rng, 4)
     scale_in = 1.0 / math.sqrt(d_model)
     scale_out = 1.0 / math.sqrt(d_ff)
+
+    def normal(key, shape, scale):
+        return (jax.random.normal(key, shape) * scale).astype(dtype)
+
     return {
-        "gate": (jax.random.normal(kg, (d_model, n_experts)) *
-                 scale_in).astype(dtype),
+        "gate": normal(kr, (d_model, n_experts), scale_in),
         "experts": {
-            "wi": (jax.random.normal(ki, (n_experts, d_model, d_ff)) *
-                   scale_in).astype(dtype),
-            "wo": (jax.random.normal(ko, (n_experts, d_ff, d_model)) *
-                   scale_out).astype(dtype),
+            "w_gate": normal(kg, (held, d_model, d_ff), scale_in),
+            "w_up": normal(ku, (held, d_model, d_ff), scale_in),
+            "w_down": normal(kd, (held, d_ff, d_model), scale_out),
         },
     }
 
 
-def _topk_renorm(logits: jax.Array, k: int,
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Shared router head: softmax -> top-k -> renormalize + Switch
-    aux loss. ONE implementation so the sparse and dense schedules
-    cannot drift apart. Returns (gate_idx (T,k), gate_vals (T,k),
-    aux)."""
+def route(logits: jax.Array, k: int,
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router's head: float32 softmax over all experts, the k
+    largest, renormalised over the k, and the Switch load-balancing
+    term (``E * mean(share of first choices * mean probability)``).
+    Returns (idx (T, k), weights (T, k) float32, aux)."""
     e = logits.shape[-1]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-    # load-balancing aux loss (Switch: E * mean(frac_tokens*mean_prob))
-    top1 = jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32)
+    vals, idx = jax.lax.top_k(probs, k)
+    vals = vals / jnp.maximum(jnp.sum(vals, axis=-1, keepdims=True), 1e-9)
+    top1 = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32)
     aux = e * jnp.mean(jnp.mean(top1, axis=0) * jnp.mean(probs, axis=0))
-    return gate_idx, gate_vals, aux
+    return idx, vals, aux
 
 
-def top_k_gating(logits: jax.Array, k: int, capacity: int,
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (dispatch (T,E,C) {0,1}, combine (T,E,C) weights,
-    aux_loss scalar) from router logits (T, E)."""
-    t, e = logits.shape
-    gate_idx, gate_vals, aux = _topk_renorm(logits, k)
+def held_counts(idx: jax.Array, held: int, offset: int) -> jax.Array:
+    """(held,) int32: the routed copies each held expert received. A
+    compare and a sum: ``bincount`` is a scatter of every copy (two of
+    them made the layout 2.07 ms where the sort of all copies is 0.27,
+    at 131,072 copies on a v5e: PERF.md section 5, PR 26)."""
+    local = idx.reshape(-1, 1) - offset
+    return jnp.sum(local == jnp.arange(held), axis=0, dtype=jnp.int32)
 
-    dispatch = jnp.zeros((t, e, capacity), jnp.float32)
-    combine = jnp.zeros((t, e, capacity), jnp.float32)
-    # expert fill persists across the k choices so capacity is shared
-    fill = jnp.zeros((e,), jnp.int32)
-    for choice in range(k):
-        idx = gate_idx[:, choice]                          # (T,)
-        onehot = jax.nn.one_hot(idx, e, dtype=jnp.int32)   # (T, E)
-        # position of each token within its chosen expert's buffer
-        pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) + fill[None, :]
-        fill = fill + jnp.sum(onehot, axis=0)
-        pos = jnp.sum(pos_in_e * onehot, axis=-1)          # (T,)
-        keep = pos < capacity
-        pos = jnp.clip(pos, 0, capacity - 1)
-        hot = (jax.nn.one_hot(idx, e, dtype=jnp.float32)[:, :, None] *
-               jax.nn.one_hot(pos, capacity, dtype=jnp.float32)[:, None, :])
-        hot = hot * keep[:, None, None]
-        dispatch = dispatch + hot
-        combine = combine + hot * gate_vals[:, choice, None, None]
-    return dispatch, combine, aux
+
+def tiles_needed(counts: jax.Array, tile_m: int) -> jax.Array:
+    """Row tiles of each group: its copies in whole tiles, one at
+    least (the grouped product writes every group's gradient block)."""
+    return jnp.maximum(-(-counts // tile_m), 1)
+
+
+def grouped_layout(idx: jax.Array, held: int, offset: int, rows: int,
+                   tile_m: int, counts: Optional[jax.Array] = None):
+    """Where each routed copy goes in a buffer of ``rows`` rows sorted
+    by held expert, every expert's run starting on a tile edge.
+
+    Returns ``(src, valid, tile_group, n_active)``: for each row the
+    flat index ``t * k + choice`` of the copy it holds and whether it
+    holds one; the held expert of each row tile (non-decreasing; past
+    the used tiles the last expert again); the number of used tiles,
+    (1,) int32. Copies keep token order within an expert. The caller
+    sees to it that they fit (``tiles_needed`` summed, against
+    ``rows // tile_m``)."""
+    n_copies = idx.size
+    local = idx.reshape(-1) - offset
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    if counts is None:
+        counts = held_counts(idx, held, offset)
+    starts = jnp.cumsum(counts) - counts
+    tiles = tiles_needed(counts, tile_m)
+    tile_ends = jnp.cumsum(tiles)
+    tile_starts = tile_ends - tiles
+    n_tiles = rows // tile_m
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right"),
+        held - 1).astype(jnp.int32)
+    row = jnp.arange(rows, dtype=jnp.int32)
+    g = tile_group[row // tile_m]
+    within = row - tile_starts[g] * tile_m
+    valid = (within < counts[g]) & (row // tile_m < tile_ends[-1])
+    src = order[jnp.clip(starts[g] + within, 0, n_copies - 1)]
+    return src, valid, tile_group, tile_ends[-1:].astype(jnp.int32)
+
+
+def _auto_tile(copies_per_expert: float) -> int:
+    """Rows a tile: 256 where an expert sees that many copies (the
+    padding of a run's last tile is then an eighth of 1,024 copies),
+    the largest power of two under the expected copies otherwise, 8 at
+    least (the sublane tiling)."""
+    tile = 8
+    while tile < 256 and tile * 2 <= copies_per_expert:
+        tile *= 2
+    return tile
+
+
+def _held_experts(experts: Dict[str, Any], tokens: jax.Array,
+                  idx: jax.Array, weights: jax.Array, counts: jax.Array,
+                  offset: int, rows: int, tile_m: int) -> jax.Array:
+    """The held experts' weighted outputs summed per token, float32
+    (T, d), through a buffer of ``rows`` rows."""
+    t, k = idx.shape
+    held = experts["w_gate"].shape[0]
+    with jax.named_scope("moe/route"):
+        src, valid, tile_group, n_active = grouped_layout(
+            idx, held, offset, rows, tile_m, counts)
+        # a row that holds no copy reads past the tokens (filled with
+        # zeros) and is dropped by the scatter
+        tok = jnp.where(valid, src // k, t)
+        w_row = jnp.where(valid, weights.reshape(-1)[src], 0.0)
+    product = lambda a, w: gmm_ops.grouped_matmul(  # noqa: E731
+        a, w, tile_group, n_active, tile_m=tile_m)
+    with jax.named_scope("moe/experts"):
+        xs = jnp.take(tokens, tok, axis=0, mode="fill", fill_value=0)
+        h = jax.nn.silu(product(xs, experts["w_gate"])) \
+            * product(xs, experts["w_up"])
+        ys = product(h, experts["w_down"])
+    with jax.named_scope("moe/combine"):
+        return jnp.zeros((t, tokens.shape[1]), jnp.float32).at[tok].add(
+            ys.astype(jnp.float32) * w_row[:, None], mode="drop")
 
 
 def sparse_route(gate_idx: jax.Array, gate_vals: jax.Array, e: int,
                  capacity: int,
                  ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Sort/segment routing plan (no (T,E,C) tensor).
+    """Sort/segment routing plan of the ``ep`` schedule.
 
     Returns ``(tok, slot, keep, w)``, each (T·k,), in expert-sorted
     order: ``tok`` is each kept copy's source token, ``slot`` its flat
     index into the (E·C, d) expert buffer, ``keep`` the capacity mask,
-    ``w`` the gate weight. Stable choice-major sort reproduces the
-    dense schedule's priority exactly (choice 0 first, then token id).
-    """
+    ``w`` the gate weight. Stable choice-major sort: earlier choices
+    win capacity, then token order."""
     t, k = gate_idx.shape
     flat_e = gate_idx.T.reshape(-1)           # (k·T,) choice-major
     flat_w = gate_vals.T.reshape(-1)
@@ -126,55 +191,80 @@ def sparse_route(gate_idx: jax.Array, gate_vals: jax.Array, e: int,
     return flat_tok[order], slot, keep, flat_w[order]
 
 
-def moe_layer(params: Dict[str, Any], x: jax.Array, *, k: int = 2,
-              capacity_factor: float = 1.25,
-              mesh: Optional[Mesh] = None, route: str = "sparse",
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (..., d_model) -> (same shape, aux_loss).
+def capacity_experts(experts: Dict[str, Any], tokens: jax.Array,
+                     idx: jax.Array, weights: jax.Array, *,
+                     capacity_factor: float = 1.25,
+                     mesh: Optional[Mesh] = None) -> jax.Array:
+    """The ``ep`` schedule: every expert padded to a shared capacity,
+    copies past it dropped; with ``mesh`` the expert-stacked buffers
+    are constrained to ``ep`` and GSPMD makes the exchange."""
+    t, k = idx.shape
+    d = tokens.shape[1]
+    e = experts["w_gate"].shape[0]
+    capacity = max(1, int(capacity_factor * k * t / e))
+    tok, slot, keep, w = sparse_route(idx, weights, e, capacity)
+    buf = jnp.zeros((e * capacity, d), tokens.dtype)
+    expert_in = buf.at[slot].add(
+        tokens[tok] * keep[:, None].astype(tokens.dtype)
+    ).reshape(e, capacity, d)
+    if mesh is not None:
+        expert_in = sharding_lib.constrain(
+            expert_in, mesh, mesh_lib.EP, None, None)
 
-    With ``mesh`` given, expert-stacked tensors are constrained to the
-    ``ep`` axis so GSPMD executes each expert's FFN on its own mesh
-    slice (dispatch/combine become all-to-alls / collective scatters).
-    """
+    def product(a, kernel, spec):
+        return jnp.einsum(spec, a, kernel.astype(tokens.dtype),
+                          preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(product(expert_in, experts["w_gate"], "ecd,edf->ecf"))
+         * product(expert_in, experts["w_up"], "ecd,edf->ecf")
+         ).astype(tokens.dtype)
+    expert_out = product(h, experts["w_down"], "ecf,efd->ecd")
+    if mesh is not None:
+        expert_out = sharding_lib.constrain(
+            expert_out.astype(tokens.dtype), mesh, mesh_lib.EP, None, None)
+    copies = expert_out.astype(jnp.float32).reshape(e * capacity, d)[slot]
+    copies = copies * (w * keep.astype(jnp.float32))[:, None]
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(copies)
+
+
+def moe_layer(params: Dict[str, Any], x: jax.Array, *, k: int = 2,
+              expert_offset: int = 0, mesh: Optional[Mesh] = None,
+              capacity_factor: float = 1.25,
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (..., d_model) -> (same shape, aux, counts).
+
+    ``counts`` (held,) int32: the routed copies each held expert
+    received this call (a counter: no gradient). ``mesh`` selects the
+    ``ep`` schedule (module docstring), which holds every expert."""
     orig_shape = x.shape
     d = orig_shape[-1]
     tokens = x.reshape(-1, d)
     t = tokens.shape[0]
-    e = params["gate"].shape[-1]
-    capacity = max(1, int(capacity_factor * k * t / e))
-
-    logits = tokens @ params["gate"].astype(tokens.dtype)
-    if route == "sparse":
-        gate_idx, gate_vals, aux = _topk_renorm(logits, k)
-        tok, slot, keep, w = sparse_route(gate_idx, gate_vals, e, capacity)
-        buf = jnp.zeros((e * capacity, d), tokens.dtype)
-        expert_in = buf.at[slot].add(
-            tokens[tok] * keep[:, None].astype(tokens.dtype)
-        ).reshape(e, capacity, d)
-    else:
-        dispatch, combine, aux = top_k_gating(logits, k, capacity)
-        expert_in = jnp.einsum("tec,td->ecd",
-                               dispatch.astype(tokens.dtype), tokens)
-
+    experts = params["experts"]
+    n_experts = params["gate"].shape[-1]
+    held = experts["w_gate"].shape[0]
+    if expert_offset < 0 or expert_offset + held > n_experts:
+        raise ValueError(
+            f"experts {expert_offset}..{expert_offset + held - 1} are not "
+            f"among the router's {n_experts}")
+    with jax.named_scope("moe/route"):
+        logits = jnp.dot(tokens, params["gate"].astype(tokens.dtype),
+                         preferred_element_type=jnp.float32)
+        idx, weights, aux = route(logits, k)
+        counts = jax.lax.stop_gradient(
+            held_counts(idx, held, expert_offset))
     if mesh is not None:
-        expert_in = sharding_lib.constrain(
-            expert_in, mesh, mesh_lib.EP, None, None)
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in,
-                               params["experts"]["wi"].astype(tokens.dtype),
-                               preferred_element_type=jnp.float32))
-    h = h.astype(tokens.dtype)
-    expert_out = jnp.einsum("ecf,efd->ecd", h,
-                            params["experts"]["wo"].astype(tokens.dtype),
-                            preferred_element_type=jnp.float32)
-    if mesh is not None:
-        expert_out = sharding_lib.constrain(
-            expert_out.astype(tokens.dtype), mesh, mesh_lib.EP, None, None)
+        if held != n_experts:
+            raise ValueError("the ep schedule holds every expert: "
+                             f"{held} of {n_experts} held")
+        out = capacity_experts(experts, tokens, idx, weights,
+                               capacity_factor=capacity_factor, mesh=mesh)
+        return out.reshape(orig_shape).astype(x.dtype), aux, counts
 
-    if route == "sparse":
-        copies = expert_out.astype(jnp.float32).reshape(e * capacity, d)[slot]
-        copies = copies * (w * keep.astype(jnp.float32))[:, None]
-        out = jnp.zeros((t, d), jnp.float32).at[tok].add(copies)
-    else:
-        out = jnp.einsum("tec,ecd->td", combine.astype(jnp.float32),
-                         expert_out.astype(jnp.float32))
-    return out.reshape(orig_shape).astype(x.dtype), aux
+    tile_m = _auto_tile(t * k / n_experts)
+    # every choice of every token in whole tiles, and each expert's
+    # last tile (an empty expert's one)
+    rows = -(-t * min(k, held) // tile_m) * tile_m + held * tile_m
+    out = _held_experts(experts, tokens, idx, weights, counts,
+                        expert_offset, rows, tile_m)
+    return out.reshape(orig_shape).astype(x.dtype), aux, counts

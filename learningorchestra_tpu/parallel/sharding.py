@@ -38,8 +38,8 @@ TRANSFORMER_RULES: Sequence[Rule] = (
     (r".*(o_proj|wo|down_proj)/kernel$", P(mesh_lib.TP, None)),
     (r".*embed/embedding$", P(None, mesh_lib.TP)),
     (r".*lm_head/kernel$", P(None, mesh_lib.TP)),
-    (r".*experts/(wi|gate)$", P(mesh_lib.EP, None, mesh_lib.TP)),
-    (r".*experts/wo$", P(mesh_lib.EP, mesh_lib.TP, None)),
+    (r".*experts/(w_gate|w_up)$", P(mesh_lib.EP, None, mesh_lib.TP)),
+    (r".*experts/w_down$", P(mesh_lib.EP, mesh_lib.TP, None)),
     (r".*(bias|scale)$", P()),
 )
 

@@ -152,8 +152,14 @@ class Engine:
                  predict_transform: Optional[Callable] = None,
                  flops_floor_fn: Optional[Callable] = None,
                  grad_accum: int = 1,
-                 cache_key: Any = None):
+                 cache_key: Any = None,
+                 counter_prefixes: Tuple[str, ...] = ()):
         self._apply_fn = apply_fn
+        # loss-emitted sums whose names start so are COUNTERS of the
+        # model (router load, masked positions): like every emitted
+        # metric they reach the epoch record as a mean over the steps,
+        # and they are also set on the epoch's ``epochEnd`` span
+        self._counter_prefixes = tuple(counter_prefixes)
         self._loss_fn = loss_fn
         self._optimizer = optimizer
         self._mesh = mesh
@@ -237,15 +243,22 @@ class Engine:
         over the mesh here. Left there, a checkpoint restore — which
         commits every leaf to its target's sharding — would pin them
         to one device and the next step would refuse the mixed
-        placement."""
+        placement. On a mesh of ONE device such a leaf already sits on
+        every device of the mesh, but as a single-device array: the
+        step returns it under the mesh's sharding, the second call's
+        argument types then differ from the first's, and the fit built
+        its program twice (PERF.md F7). So it is put under the mesh's
+        sharding there too."""
         opt_state = jax.jit(self._optimizer.init)(params)
         mesh_devices = set(self._mesh.devices.flat)
         rep = mesh_lib.replicated(self._mesh)
 
         def on_mesh(x):
             # a tracer (eval_shape of init_state) has no placement
-            if isinstance(x, jax.core.Tracer) or \
-                    x.sharding.device_set == mesh_devices:
+            if isinstance(x, jax.core.Tracer) or (
+                    x.sharding.device_set == mesh_devices and not
+                    isinstance(x.sharding,
+                               jax.sharding.SingleDeviceSharding)):
                 return x
             return jax.device_put(x, rep)
 
@@ -582,6 +595,11 @@ class Engine:
             trace_id = self._fit_anchor[0]
             if record.get("loss") is not None:
                 epoch_span.set(loss=round(float(record["loss"]), 6))
+            if self._counter_prefixes:
+                # called inside the ``epochEnd`` span: its attrs
+                obs_trace.annotate(**{
+                    k: round(float(v), 3) for k, v in record.items()
+                    if k.startswith(self._counter_prefixes)})
             # roofline block (stamped on the record by
             # _roofline_record): rides the same ring entry so the
             # timeline answers "how fast vs the hardware" per window,
@@ -606,7 +624,7 @@ class Engine:
             pass
 
     def _measure_flops(self, state, batch, rng, step_fn=None,
-                       epoch: int = 0) -> None:
+                       epoch: int = 0, count_only: bool = False) -> None:
         """Per-step flop + bytes-accessed estimate from the lowered HLO
         (cheap — no compile). Basis for the MFU line and the roofline
         block in every history record. Also feeds the X-ray plane: the
@@ -615,7 +633,9 @@ class Engine:
         compiled step's memory/cost analysis is captured once per cold
         executable key for ``GET /observability/compile/{name}``. The
         lowering (a second full trace of the step) is a
-        ``measureFlops`` span: a warm fit has none."""
+        ``measureFlops`` span: a warm fit has none. ``count_only``
+        (the scanned path of an engine with a ``flops_floor_fn``) takes
+        that function's count and lowers nothing."""
         key = tuple(sorted((k, tuple(v.shape)) for k, v in batch.items()))
         self._note_signature(key)
         if self._step_flops is not None and key == self._flops_key:
@@ -632,6 +652,24 @@ class Engine:
                 return
         self._flops_key = key
         with obs_trace.span("measureFlops", epoch=epoch):
+            if count_only:
+                # the scanned path would trace, lower and build ONE step
+                # only to count it (its ``step_fn`` runs nothing). A
+                # model that brings its own count is taken at its word:
+                # for the expert block that second trace was 22 s, its
+                # lowering 8 s and its executable 62 s cold of every
+                # job's set-up on the chip (PERF.md section 6, PR 26),
+                # and where Pallas kernels run XLA's count is the lower
+                # of the two anyway. Bytes and the compile X-ray are
+                # not taken on this path.
+                try:
+                    self._step_flops = float(self._flops_floor_fn(batch))
+                except Exception:  # noqa: BLE001 — accounting never sinks a run
+                    self._step_flops = 0.0
+                self._step_bytes = 0.0
+                if shared_key is not None:
+                    _FLOPS_CACHE[shared_key] = (self._step_flops, 0.0)
+                return
             try:
                 fn = step_fn if step_fn is not None else self._train_step
                 lowered = fn.lower(state, batch, rng)
@@ -1206,7 +1244,8 @@ class Engine:
                         self._measure_flops(
                             state, one, base_rng,
                             step_fn=jax.jit(self._train_step_body),
-                            epoch=epoch)
+                            epoch=epoch,
+                            count_only=self._flops_floor_fn is not None)
                     arrays_in = device_arrays
                     if _armed_nan():
                         arrays_in = _poison_rows(device_arrays, bs)

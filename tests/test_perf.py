@@ -215,7 +215,7 @@ def test_registry_upsert_lru_and_disabled(monkeypatch):
 
 
 # ------------------------- engine extraction + custom-call floor
-def _measure(floor_fn=None):
+def _measure(floor_fn=None, **kw):
     import jax
     import jax.numpy as jnp
 
@@ -234,7 +234,7 @@ def _measure(floor_fn=None):
         _record_compile_xray=lambda *a, **k: None)
     batch = {"x": np.ones((64, 64), np.float32)}
     Engine._measure_flops(eng, np.float32(0.0), batch,
-                          jax.random.PRNGKey(0), step_fn=step)
+                          jax.random.PRNGKey(0), step_fn=step, **kw)
     return eng
 
 
@@ -256,6 +256,14 @@ def test_flops_floor_raises_flops_but_not_bytes():
     # a floor below the measured value never lowers it
     low = _measure(floor_fn=lambda batch: 1.0)
     assert low._step_flops == pytest.approx(base._step_flops)
+
+
+def test_count_only_takes_the_models_count_and_lowers_nothing():
+    """The scanned path of a model that counts its own step: the step
+    that exists only to be measured is not lowered, so no bytes are
+    read from it (a lowering reports them, as the tests above show)."""
+    eng = _measure(floor_fn=lambda batch: 123.0, count_only=True)
+    assert (eng._step_flops, eng._step_bytes) == (123.0, 0.0)
 
 
 # ------------------------------------ fit history + timeline block

@@ -121,3 +121,58 @@ def test_ring_hop_kernel_compiles_for_v5e(one_chip, grad):
     shape = (2, 1024, 16, 64)
     text = _compiled_text(fn, one_chip, shape, shape, shape)
     assert "tpu_custom_call" in text
+
+
+def _custom_calls(text):
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*custom-call\(",
+                       text, re.M)
+    return {name.split(".")[0] for name in calls}
+
+
+def test_bd_flash_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
+    """Attention under the block-diffusion mask at SDAR's widths: 2
+    rows of [noisy ; clean] 2 x 4096 positions, 32 query heads on 4 KV
+    heads of 128, blocks of 4; forward and both backward kernels, each
+    under its own name."""
+    fn = functools.partial(attn.flash_bd_attention, block_length=4,
+                           interpret=False)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):  # as the model's module does
+            return fn(q, k, v).astype(jnp.float32).sum()
+
+    q, kv = (2, 8192, 32, 128), (2, 8192, 4, 128)
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                          one_chip, q, kv, kv)
+    assert {"flash_bd_fwd", "flash_bd_bwd_dq", "flash_bd_bwd_dkv"} \
+        <= _custom_calls(text)
+
+
+@pytest.mark.parametrize("tile_m", [128, 256])
+def test_grouped_product_compiles_for_v5e_at_the_cells_shape(one_chip,
+                                                             tile_m):
+    """The expert layer's grouped product at SDAR's widths: 16 held
+    experts of 2048 x 768 and back, the worst-case buffer of a step's
+    16,384 positions (every one of their 8 choices held here); forward,
+    and the backward's two kernels."""
+    from learningorchestra_tpu.ops import grouped_matmul as gmm
+
+    rows = 16384 * 8 + 16 * tile_m
+
+    def loss(x, w_up, w_down, tile_group, n_active):
+        with jax.named_scope("moe/experts"):  # as parallel/moe.py does
+            h = gmm.grouped_matmul(x, w_up, tile_group, n_active,
+                                   tile_m=tile_m, interpret=False)
+            y = gmm.grouped_matmul(h, w_down, tile_group, n_active,
+                                   tile_m=tile_m, interpret=False)
+        return y.astype(jnp.float32).sum()
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((rows, 2048)), sds((16, 2048, 768)), sds((16, 768, 2048)),
+        sds((rows // tile_m,), jnp.int32), sds((1,), jnp.int32)
+    ).compile().as_text()
+    assert {"moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw"} \
+        <= _custom_calls(text)
