@@ -673,6 +673,9 @@ class FusedHeadOut(NamedTuple):
     # (layers, held) int32, the copies each held expert received; of
     # no column under dense MLPs
     moe_counts: Any = None
+    # (layers,) int32, the tiles of the layer's row buffer that hold
+    # those copies (parallel/moe.py:tiles_used); None under dense MLPs
+    moe_tiles: Any = None
     # block diffusion: the step's noise, {"masked": (b, L) bool,
     # "t": (b,)}, put here by LanguageModel._apply_fn for the loss
     noise: Any = None
@@ -821,10 +824,16 @@ class TransformerLM(nn.Module):
         head = _LMHead(self.vocab_size, name="lm_head")
         if self.fused_head_chunk and decode_pos is None and \
                 (train or self.bd_block):
+            tiles = None
+            if self.n_experts > 0:
+                tiles = jnp.stack([moe_lib.tiles_used(
+                    c, x.shape[0] * x.shape[1], self.moe_k, self.n_experts)
+                    for c in counts])
             return FusedHeadOut(hidden=x,
                                 kernel=head(x, return_kernel=True),
                                 aux=aux_total,
-                                moe_counts=jnp.stack(counts))
+                                moe_counts=jnp.stack(counts),
+                                moe_tiles=tiles)
         return head(x), aux_total
 
 
@@ -936,9 +945,10 @@ def bd_noise(key, shape: Tuple[int, int], t_low: float = 0.1):
 
 def _moe_counters(out: FusedHeadOut) -> Dict[str, Any]:
     """The expert layers' router load as epoch-record counters: per
-    layer, the routed copies that landed on held experts and the
-    busiest held expert's copies (each a mean over the epoch's steps:
-    a sum beside a count of 1 a step)."""
+    layer, the routed copies that landed on held experts, the busiest
+    held expert's copies, and the tiles of the row buffer those copies
+    fill, which is where the passes over the buffer end (each a mean
+    over the epoch's steps: a sum beside a count of 1 a step)."""
     counts = out.moe_counts
     if counts is None or counts.shape[-1] == 0:
         return {}
@@ -948,6 +958,8 @@ def _moe_counters(out: FusedHeadOut) -> Dict[str, Any]:
         c = counts[i].astype(jnp.float32)
         counters[f"moeHeldCopies_l{i}"] = (jnp.sum(c), one)
         counters[f"moeBusiestCopies_l{i}"] = (jnp.max(c), one)
+        counters[f"moeTilesUsed_l{i}"] = (
+            out.moe_tiles[i].astype(jnp.float32), one)
     return counters
 
 

@@ -13,9 +13,20 @@ The held experts' products run as ONE grouped product
 (:func:`grouped_layout`), each expert's run padded to whole row tiles,
 gathered, multiplied (gate, up, down) and scattered back weighted. The
 row buffer is static and holds the worst case (every choice of every
-token held here), so every copy is computed whatever the router does;
-the products skip the tiles that hold no copy, the gather and the
-scatter-add do not.
+token held here), so every copy is computed whatever the router does.
+The layout puts every copy in the buffer's first ``n_active`` tiles,
+and nothing that touches the buffer goes past them. The products skip
+the tiles that hold no copy. The passes around them are loops over
+chunks of whole tiles whose trip count is the used prefix, forward and
+backward (written by hand: a ``while_loop`` has no reverse mode): the
+gather into the buffer (:func:`dispatch`; backward a scatter-add onto
+the tokens), ``silu(gate) * up`` between the products (:func:`gated`)
+and the weighted scatter-add out of it (:func:`combine`; backward a
+gather of the output's gradient). The rows past the last used chunk
+are never written and never read, so the passes cost what the copies
+cost, by a chunk at a time; when every choice of every token is held
+they run over the whole buffer. One path: no second buffer, no
+``cond``.
 
 On a mesh whose ``ep`` axis is larger than 1 the older schedule stays
 until a four-chip cell measures its replacement (ROADMAP S17): experts
@@ -32,8 +43,9 @@ Parameters (stacked experts, shardable by sharding.TRANSFORMER_RULES):
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -118,15 +130,20 @@ def grouped_layout(idx: jax.Array, held: int, offset: int, rows: int,
     tile_ends = jnp.cumsum(tiles)
     tile_starts = tile_ends - tiles
     n_tiles = rows // tile_m
+    tile = jnp.arange(n_tiles, dtype=jnp.int32)
     tile_group = jnp.minimum(
-        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right"),
+        jnp.searchsorted(tile_ends, tile, side="right"),
         held - 1).astype(jnp.int32)
-    row = jnp.arange(rows, dtype=jnp.int32)
-    g = tile_group[row // tile_m]
-    within = row - tile_starts[g] * tile_m
-    valid = (within < counts[g]) & (row // tile_m < tile_ends[-1])
-    src = order[jnp.clip(starts[g] + within, 0, n_copies - 1)]
-    return src, valid, tile_group, tile_ends[-1:].astype(jnp.int32)
+    # what a tile's rows share is looked up a tile and spread over its
+    # rows: a lookup a row is a millisecond at 135,168 rows on a v5e
+    within = ((tile - tile_starts[tile_group]) * tile_m)[:, None] \
+        + jnp.arange(tile_m, dtype=jnp.int32)
+    valid = (within < counts[tile_group][:, None]) \
+        & (tile < tile_ends[-1])[:, None]
+    src = order[jnp.clip(starts[tile_group][:, None] + within, 0,
+                         n_copies - 1)]
+    return (src.reshape(rows), valid.reshape(rows), tile_group,
+            tile_ends[-1:].astype(jnp.int32))
 
 
 def _auto_tile(copies_per_expert: float) -> int:
@@ -140,30 +157,218 @@ def _auto_tile(copies_per_expert: float) -> int:
     return tile
 
 
+# rows of the buffer a trip of the loops covers (8 tiles of 256 rows):
+# half a chunk of a pass holds no copy on average, and a trip has a
+# cost of its own; 1,024 to 4,096 read within 6% of each other on a v5e
+# (PERF.md section 6, PR 27)
+_CHUNK_ROWS = 2048
+
+
+def buffer_shape(t: int, k: int, n_experts: int, held: int,
+                 ) -> Tuple[int, int, int]:
+    """``(tile_m, rows, chunk)`` of the row buffer of ``t`` tokens: every
+    choice of every token in whole tiles and each held expert's last
+    tile (an empty expert's one), in whole chunks (a buffer under one
+    chunk is one chunk)."""
+    tile_m = _auto_tile(t * k / n_experts)
+    rows = -(-t * min(k, held) // tile_m) * tile_m + held * tile_m
+    chunk = min(rows, _CHUNK_ROWS)
+    return tile_m, -(-rows // chunk) * chunk, chunk
+
+
+def tiles_used(counts: jax.Array, t: int, k: int, n_experts: int,
+               ) -> jax.Array:
+    """() int32: the tiles of the row buffer that hold the copies of
+    ``counts`` (held,), ``grouped_layout``'s ``n_active``: where the
+    passes over the buffer end, by a chunk."""
+    return jnp.sum(tiles_needed(
+        counts, _auto_tile(t * k / n_experts))).astype(jnp.int32)
+
+
+def _over_used_chunks(body: Callable, init, n_active: jax.Array,
+                      tile_m: int, chunk: int):
+    """``carry = body(rows_at, carry)`` for each chunk of ``chunk`` rows
+    that holds any of the first ``n_active`` tiles; ``rows_at(a)`` is
+    the chunk's rows of a buffer-long ``a``, ``rows_at(a, new)`` is
+    ``a`` with them replaced."""
+    def trip(i, carry):
+        def rows_at(a, new=None):
+            if new is None:
+                return jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)
+            return jax.lax.dynamic_update_slice_in_dim(
+                a, new.astype(a.dtype), i * chunk, 0)
+
+        return body(rows_at, carry)
+
+    return jax.lax.fori_loop(0, -(-n_active[0] * tile_m // chunk), trip,
+                             init)
+
+
+def _take(table: jax.Array, index: jax.Array) -> jax.Array:
+    """``table[index]``, zero where ``index`` is past the table."""
+    return jnp.take(table, index, axis=0, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 4, 5))
+def dispatch(tokens: jax.Array, copy: jax.Array, k: int,
+             n_active: jax.Array, tile_m: int, chunk: int,
+             ) -> Tuple[jax.Array, jax.Array]:
+    """The row buffer ``xs (rows, d)``: ``xs[r] = tokens[copy[r] // k]``
+    (``copy[r]``: the routed copy ``token * k + choice`` the row holds,
+    ``t * k`` and a row of zeros for none), for the rows of the used
+    chunks; the rows past them are not written (zero). ``chunk``
+    divides ``rows``. Given TWICE, once for each product that reads
+    it: their two gradients then come back apart and are summed a chunk
+    at a time, where one buffer's would be summed over all of it."""
+    def body(rows_at, xs):
+        return rows_at(xs, _take(tokens, rows_at(copy) // k))
+
+    xs = _over_used_chunks(
+        body, jnp.zeros((copy.shape[0], tokens.shape[1]), tokens.dtype),
+        n_active, tile_m, chunk)
+    return xs, xs
+
+
+def _dispatch_fwd(tokens, copy, k, n_active, tile_m, chunk):
+    return dispatch(tokens, copy, k, n_active, tile_m, chunk), \
+        (tokens, copy, n_active)
+
+
+def _dispatch_bwd(k, tile_m, chunk, res, dxs):
+    tokens, copy, n_active = res
+    with jax.named_scope("moe/experts"):
+        # summed in the tokens' type, as the transpose of ``take`` is
+        def body(rows_at, dtokens):
+            return dtokens.at[rows_at(copy) // k].add(
+                (rows_at(dxs[0]) + rows_at(dxs[1])).astype(dtokens.dtype),
+                mode="drop")
+
+        dtokens = _over_used_chunks(body, jnp.zeros_like(tokens),
+                                    n_active, tile_m, chunk)
+    return dtokens, None, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _gate(g: jax.Array, u: jax.Array) -> jax.Array:
+    return jax.nn.silu(g) * u
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def gated(g: jax.Array, u: jax.Array, n_active: jax.Array, tile_m: int,
+          chunk: int) -> jax.Array:
+    """``silu(g) * u`` for the rows of the used chunks, zero past
+    them."""
+    def body(rows_at, h):
+        return rows_at(h, _gate(rows_at(g), rows_at(u)))
+
+    return _over_used_chunks(body, jnp.zeros_like(g), n_active, tile_m,
+                             chunk)
+
+
+def _gated_fwd(g, u, n_active, tile_m, chunk):
+    return gated(g, u, n_active, tile_m, chunk), (g, u, n_active)
+
+
+def _gated_bwd(tile_m, chunk, res, dh):
+    g, u, n_active = res
+    with jax.named_scope("moe/experts"):
+        # the gradients start as ``g`` and ``u`` themselves: a chunk of
+        # them is read where its gradient is then written, so the loop
+        # fills no buffer of its own; past the used chunks they are
+        # what the products wrote there, zero
+        def body(rows_at, carry):
+            dg, du = carry
+            dg_rows, du_rows = jax.vjp(_gate, rows_at(dg), rows_at(du))[1](
+                rows_at(dh))
+            return rows_at(dg, dg_rows), rows_at(du, du_rows)
+
+        dg, du = _over_used_chunks(body, (g, u), n_active, tile_m, chunk)
+    return dg, du, None
+
+
+gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def combine(ys: jax.Array, weights: jax.Array, copy: jax.Array,
+            n_active: jax.Array, tile_m: int, chunk: int) -> jax.Array:
+    """``out (t, d)`` float32: ``out[copy[r] // k] += ys[r] *
+    weights.flat[copy[r]]`` over the rows of the used chunks
+    (``weights (t, k)``; a ``copy[r]`` past the copies is dropped)."""
+    t, k = weights.shape
+    flat = weights.reshape(-1)
+
+    def body(rows_at, out):
+        c = rows_at(copy)
+        return out.at[c // k].add(
+            rows_at(ys).astype(jnp.float32) * _take(flat, c)[:, None],
+            mode="drop")
+
+    return _over_used_chunks(
+        body, jnp.zeros((t, ys.shape[1]), jnp.float32), n_active, tile_m,
+        chunk)
+
+
+def _combine_fwd(ys, weights, copy, n_active, tile_m, chunk):
+    return combine(ys, weights, copy, n_active, tile_m, chunk), \
+        (ys, weights, copy, n_active)
+
+
+def _combine_bwd(tile_m, chunk, res, dout):
+    ys, weights, copy, n_active = res
+    k = weights.shape[1]
+    flat = weights.reshape(-1)
+    with jax.named_scope("moe/combine"):
+        def body(rows_at, carry):
+            dys, dflat = carry
+            c = rows_at(copy)
+            d = _take(dout, c // k)
+            dflat = dflat.at[c].add(
+                jnp.sum(d * rows_at(ys).astype(jnp.float32), axis=-1),
+                mode="drop")
+            return rows_at(dys, d * _take(flat, c)[:, None]), dflat
+
+        # ``dys`` does not start as ``ys`` the way ``gated``'s gradients
+        # start as its inputs: the chunk's read (for ``dflat``) and its
+        # write are two fusions here, and in a whole step XLA then
+        # copied the buffer twice a trip to keep them apart (PERF.md
+        # section 6, PR 27)
+        dys, dflat = _over_used_chunks(
+            body, (jnp.zeros_like(ys), jnp.zeros(flat.shape, jnp.float32)),
+            n_active, tile_m, chunk)
+    return dys, dflat.reshape(weights.shape).astype(weights.dtype), \
+        None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _held_experts(experts: Dict[str, Any], tokens: jax.Array,
                   idx: jax.Array, weights: jax.Array, counts: jax.Array,
-                  offset: int, rows: int, tile_m: int) -> jax.Array:
+                  offset: int, rows: int, tile_m: int, chunk: int,
+                  ) -> jax.Array:
     """The held experts' weighted outputs summed per token, float32
-    (T, d), through a buffer of ``rows`` rows."""
+    (T, d), through a buffer of ``rows`` rows in chunks of ``chunk``."""
     t, k = idx.shape
     held = experts["w_gate"].shape[0]
     with jax.named_scope("moe/route"):
         src, valid, tile_group, n_active = grouped_layout(
             idx, held, offset, rows, tile_m, counts)
-        # a row that holds no copy reads past the tokens (filled with
-        # zeros) and is dropped by the scatter
-        tok = jnp.where(valid, src // k, t)
-        w_row = jnp.where(valid, weights.reshape(-1)[src], 0.0)
+        # a row that holds no copy reads past the copies (zeros) and is
+        # dropped by the scatters
+        copy = jnp.where(valid, src, t * k)
+    passes = (n_active, tile_m, chunk)
     product = lambda a, w: gmm_ops.grouped_matmul(  # noqa: E731
         a, w, tile_group, n_active, tile_m=tile_m)
     with jax.named_scope("moe/experts"):
-        xs = jnp.take(tokens, tok, axis=0, mode="fill", fill_value=0)
-        h = jax.nn.silu(product(xs, experts["w_gate"])) \
-            * product(xs, experts["w_up"])
+        xs_gate, xs_up = dispatch(tokens, copy, k, *passes)
+        h = gated(product(xs_gate, experts["w_gate"]),
+                  product(xs_up, experts["w_up"]), *passes)
         ys = product(h, experts["w_down"])
     with jax.named_scope("moe/combine"):
-        return jnp.zeros((t, tokens.shape[1]), jnp.float32).at[tok].add(
-            ys.astype(jnp.float32) * w_row[:, None], mode="drop")
+        return combine(ys, weights, copy, *passes)
 
 
 def sparse_route(gate_idx: jax.Array, gate_vals: jax.Array, e: int,
@@ -261,10 +466,7 @@ def moe_layer(params: Dict[str, Any], x: jax.Array, *, k: int = 2,
                                capacity_factor=capacity_factor, mesh=mesh)
         return out.reshape(orig_shape).astype(x.dtype), aux, counts
 
-    tile_m = _auto_tile(t * k / n_experts)
-    # every choice of every token in whole tiles, and each expert's
-    # last tile (an empty expert's one)
-    rows = -(-t * min(k, held) // tile_m) * tile_m + held * tile_m
+    tile_m, rows, chunk = buffer_shape(t, k, n_experts, held)
     out = _held_experts(experts, tokens, idx, weights, counts,
-                        expert_offset, rows, tile_m)
+                        expert_offset, rows, tile_m, chunk)
     return out.reshape(orig_shape).astype(x.dtype), aux, counts

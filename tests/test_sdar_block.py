@@ -216,6 +216,130 @@ def test_the_row_buffer_is_the_worst_case_and_nothing_else():
     assert f"[{rows},{LM['d_model']}]" in text.replace(" ", "")
 
 
+def _routing(case):
+    """(idx (40, k), held, offset) of a routing whose used tiles (of 8
+    rows, in chunks of two) are what the case's name says."""
+    t = 40
+    away = jnp.full((t,), 7, jnp.int32)      # an expert not held here
+    if case == "one_tile":                   # 5 copies on the one held
+        first = jnp.where(jnp.arange(t) < 5, 3, 6)
+        return jnp.stack([first, away], axis=1), 1, 3
+    if case == "ends_inside_a_chunk":        # 9, 3 and 10 copies: 5 tiles
+        first = jnp.select([jnp.arange(t) < 9, jnp.arange(t) < 12,
+                            jnp.arange(t) < 22], [2, 3, 4], 6)
+        return jnp.stack([first, away], axis=1), 3, 2
+    # every expert held, every copy kept
+    idx = jax.lax.top_k(jax.random.normal(jax.random.PRNGKey(4), (t, 4)),
+                        2)[1].astype(jnp.int32)
+    return idx, 4, 0
+
+
+@pytest.mark.parametrize("case", ["one_tile", "ends_inside_a_chunk",
+                                  "every_copy_held"])
+def test_the_passes_over_the_used_chunks_are_the_whole_buffer_passes(case):
+    """The loops over the used chunks give what ``take``, ``silu(g) *
+    u`` and ``.at[].add`` over the whole buffer give, in value and in
+    the gradients towards the tokens, the products' rows and the
+    router's weights; what lies past the used tiles is no number on
+    their side, to show that it is never read."""
+    tile, chunk, d = 8, 16, 16
+    idx, held, offset = _routing(case)
+    t, k = idx.shape
+    rows = -(-t * min(k, held) // tile) * tile + held * tile
+    rows = -(-rows // chunk) * chunk
+    src, valid, _, n_active = moe.grouped_layout(idx, held, offset, rows,
+                                                 tile)
+    used = int(n_active[0]) * tile
+    assert {"one_tile": used == tile,
+            "ends_inside_a_chunk": used % chunk and chunk < used < rows,
+            "every_copy_held": int(jnp.sum(valid)) == t * k}[case]
+    copy = jnp.where(valid, src, t * k)
+    live = (jnp.arange(rows) < used)[:, None]
+    passes = (n_active, tile, chunk)
+    inputs = {"tokens": _tokens(t, d, seed=11),
+              "weights": jax.nn.softmax(_tokens(t, k, seed=12)),
+              "ys": _tokens(rows, d, seed=13), "g": _tokens(rows, d, seed=14),
+              "u": _tokens(rows, d, seed=15)}
+    in_buffer = ("ys", "g", "u")
+    cot = [_tokens(rows, d, seed=16 + i) for i in range(3)] \
+        + [_tokens(t, d, seed=19)]
+
+    def chunked(a):
+        xs_gate, xs_up = moe.dispatch(a["tokens"], copy, k, *passes)
+        return (xs_gate, xs_up, moe.gated(a["g"], a["u"], *passes),
+                moe.combine(a["ys"], a["weights"], copy, *passes))
+
+    def whole(a):
+        xs = jnp.take(a["tokens"], copy // k, axis=0, mode="fill",
+                      fill_value=0)
+        w_row = jnp.take(a["weights"].reshape(-1), copy, mode="fill",
+                         fill_value=0)
+        return (xs, xs, jax.nn.silu(a["g"]) * a["u"],
+                jnp.zeros((t, d)).at[copy // k].add(
+                    a["ys"] * w_row[:, None], mode="drop"))
+
+    def run(fn, past):
+        args = {name: jnp.where(live, a, past) if name in in_buffer else a
+                for name, a in inputs.items()}
+        weigh = [jnp.where(live, c, past) for c in cot[:3]] + cot[3:]
+        grads = jax.grad(lambda a: sum(
+            jnp.sum(out * c) for out, c in zip(fn(a), weigh)))(args)
+        return dict(zip(("xs_gate", "xs_up", "h", "out"), fn(args)),
+                    **{"d " + name: g for name, g in grads.items()})
+
+    got, want = run(chunked, jnp.nan), run(whole, 0.0)
+    assert got.keys() == want.keys() and len(got) == 9
+    for name in got:
+        a, b = got[name], want[name]
+        if name in ("h", "d ys", "d g", "d u"):
+            # past the used tiles nothing reads them
+            a, b = a[:used], b[:used]
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                                   err_msg=name)
+
+
+def _primitives(jaxpr, into):
+    """The primitives of a jaxpr and of what it calls, the kernels'
+    own bodies left out (``pl.when`` is a ``cond`` there)."""
+    for eqn in jaxpr.eqns:
+        into.append((eqn.primitive.name, eqn.params.get("name")))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, into)
+    return into
+
+
+def test_the_layer_is_one_path_of_loops_around_three_products():
+    """Three ``while`` (dispatch, ``silu x up`` and combine: the
+    passes' trip count is observed) and no ``cond`` (no second buffer
+    to fall back on); a gradient of the
+    layer calls each product's ``moe_gmm_dw`` once, three in all: the
+    kernels are not in the loops."""
+    params, _ = _layer(LM)
+    x = _tokens(96, LM["d_model"])
+
+    def layer(p, x):
+        return jnp.sum(moe.moe_layer(
+            p, x, k=LM["moe_k"], expert_offset=LM["expert_offset"])[0])
+
+    forward = _primitives(jax.make_jaxpr(layer)(params, x).jaxpr, [])
+    names = [name for name, _ in forward]
+    assert names.count("while") == 3 and "cond" not in names
+    backward = _primitives(
+        jax.make_jaxpr(jax.grad(layer, (0, 1)))(params, x).jaxpr, [])
+    assert "cond" not in [name for name, _ in backward]
+    kernels = [label for name, label in backward if name == "pallas_call"]
+    assert kernels.count("moe_gmm_dw") == 3
+    assert kernels.count("moe_gmm_dx") == 3
+    assert kernels.count("moe_gmm_fwd") == 3
+
+
 # ----------------------------------------------------------------------
 # attention under the block-diffusion mask
 # ----------------------------------------------------------------------
@@ -397,11 +521,16 @@ def test_counters_reach_the_epoch_record_and_the_epoch_end_span():
     assert len(ends) == 1
     attrs = ends[0].attrs
     for key in ("moeHeldCopies_l0", "moeBusiestCopies_l1",
-                "maskedPositions"):
+                "moeTilesUsed_l0", "maskedPositions"):
         assert attrs[key] == pytest.approx(hist[key][0], abs=1e-3), key
     # 2 rows of 64 positions, 2 choices each, 4 of 8 experts held
     assert 0 < attrs["moeBusiestCopies_l0"] <= attrs["moeHeldCopies_l0"] \
         <= 2 * 64 * 2
+    # the copies in whole tiles, a tile at least for each held expert,
+    # within the buffer's tiles
+    tile, rows, _ = moe.buffer_shape(2 * 64, 2, 8, 4)
+    assert max(4, attrs["moeHeldCopies_l0"] / tile) \
+        <= attrs["moeTilesUsed_l0"] <= rows // tile
 
 
 # ----------------------------------------------------------------------

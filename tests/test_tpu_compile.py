@@ -176,3 +176,49 @@ def test_grouped_product_compiles_for_v5e_at_the_cells_shape(one_chip,
     ).compile().as_text()
     assert {"moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw"} \
         <= _custom_calls(text)
+
+
+def test_expert_layer_gradient_compiles_for_v5e_with_the_chunked_passes(
+        one_chip, monkeypatch):
+    """One expert layer of the sdar cell under per-block recomputation,
+    forward and backward: 16,384 positions, 8 of 128 experts a token,
+    16 held. The dispatch, ``silu x up`` and the combine are ``while``
+    loops over the used chunks of the row buffer, around the products'
+    kernels, each of which stays one call over the whole buffer
+    (``moe_gmm_dw`` three times a layer: the benchmark counts its steps
+    by that)."""
+    from learningorchestra_tpu.ops import grouped_matmul as gmm
+    from learningorchestra_tpu.parallel import moe
+
+    # the backend here is the CPU: the kernels are asked of Mosaic
+    monkeypatch.setattr(gmm, "_auto_interpret", lambda: False)
+
+    def layer(p, x):
+        return moe.moe_layer(p, x, k=8)[0]
+
+    def loss(p, x):
+        y = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.nothing_saveable)(p, x)
+        return y.astype(jnp.float32).sum()
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"gate": sds((2048, 128), jnp.float32),
+              "experts": {"w_gate": sds((16, 2048, 768)),
+                          "w_up": sds((16, 2048, 768)),
+                          "w_down": sds((16, 768, 2048))}}
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, sds((16384, 2048))).compile().as_text()
+    assert {"moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw"} \
+        <= _custom_calls(text)
+    assert len(re.findall(r"%moe_gmm_dw[.\d]* = [^\n]*custom-call\(",
+                          text)) == 3
+    # the three passes forward, dispatch and ``silu x up`` recomputed
+    # (the combine's recomputation has no reader and is gone), the
+    # three backward
+    assert len(re.findall(r" while\(", text)) >= 8
+    assert " conditional(" not in text
+    # the loops update the row buffers in place (``gated``'s gradients
+    # start as its inputs): no copy of a whole one
+    assert not re.search(r"= bf16\[135168,\d+\]\{[^}]*\} copy\(", text)
