@@ -111,7 +111,7 @@ def _peak_hbm(devices) -> list:
 
 def affine_tokens(rng, n: int, seq: int, vocab: int):
     """Learnable streams: an affine next-token map from a random start
-    per sequence (the data of bench.py's tlm phase)."""
+    per sequence."""
     import numpy as np
 
     start = rng.integers(0, vocab, size=(n, 1))

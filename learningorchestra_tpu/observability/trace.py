@@ -446,7 +446,7 @@ def tree(trace_id: str) -> Optional[Dict[str, Any]]:
 def durations_by_name(trace_id: str) -> Dict[str, float]:
     """Summed duration (seconds) of finished spans, by span name —
     the attribution source for job metadata (``compileSeconds``,
-    ``checkpointCommitSeconds``) and bench breakdowns."""
+    ``checkpointCommitSeconds``)."""
     totals: Dict[str, float] = {}
     for sp in spans_of(trace_id):
         if sp.end is not None:

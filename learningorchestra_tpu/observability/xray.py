@@ -65,8 +65,8 @@ _transfer_events: "collections.deque" = collections.deque(
 
 def enabled() -> bool:
     """Master switch for ledger registration + compile capture
-    (``LO_XRAY``, default on). One dict lookup per call — the
-    xray-overhead bench flips it inside a single process."""
+    (``LO_XRAY``, default on). Read on every call, so a process
+    may flip it while it runs."""
     return os.environ.get("LO_XRAY", "1") not in ("0", "false", "no")
 
 

@@ -1597,8 +1597,8 @@ class Engine:
 # Vectorized sweep fusion (docs/PERFORMANCE.md "Sweep fusion"): train N
 # same-architecture hyperparameter configs in ONE compiled program by
 # vmapping the train/eval step over a leading config axis. Counters are
-# module-level so the bench/CI gate can assert a fused sweep compiled
-# its epoch program exactly once (zero warm retraces across points).
+# module-level so a test can assert a fused sweep compiled its epoch
+# program exactly once (zero warm retraces across points).
 # ----------------------------------------------------------------------
 _FUSED_STATS = {"epochTraces": 0}
 

@@ -152,10 +152,10 @@ def compile_cache_path() -> str:
 
 def wire_compile_cache() -> Optional[str]:
     """THE compile-cache rule, for every entry point (``lo-server``,
-    ``chip_smoke.py``, ``bench.py`` phase children): when
-    ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and code
-    sets nothing; otherwise an accelerator backend caches under
-    :func:`compile_cache_path` and the CPU backend caches nothing
+    ``chip_smoke.py``): when ``JAX_COMPILATION_CACHE_DIR`` is set
+    jax reads it itself and code sets nothing; otherwise an
+    accelerator backend caches under :func:`compile_cache_path` and
+    the CPU backend caches nothing
     (tests/conftest.py keeps its own opt-in). Call before the first
     compile. Returns the directory in use, None when the cache is off."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")

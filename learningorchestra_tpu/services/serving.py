@@ -354,7 +354,7 @@ class LMServingSession(_SessionBase):
         # speculative propose. The label set is CLOSED (_ROLES — no
         # client influence), so unlike tenant series no cardinality
         # cap is needed: three trackers and three histogram series,
-        # ever. TTFT rides along for the bench/SLO surface.
+        # ever. TTFT rides along for the stats/SLO surface.
         self._role_latency: Dict[str, LatencyTracker] = {}
         self._ttft = LatencyTracker()
         # analytic decode footprint: each step reads every param and
@@ -2481,8 +2481,8 @@ class ServingManager:
                 pages_per = cache_len // page_len
                 # LO_SERVE_PAGES=0 auto-sizes the pool to the slot
                 # cache's HBM budget (slots x pages-per-stream, plus
-                # the reserved trash page) — the apples-to-apples
-                # setting the paged_serving bench gates on
+                # the reserved trash page), so paged and slot
+                # sessions compare at equal KV bytes
                 n_pages = V.valid_positive_int(
                     body.get("pages"), "pages",
                     default=int(cfg.serve_pages)

@@ -2,7 +2,7 @@
 """Repo self-lint: the framework's own source held to the standards
 it enforces on user code.
 
-Scans ``learningorchestra_tpu/``, ``scripts/``, ``bench.py`` and
+Scans ``learningorchestra_tpu/``, ``scripts/`` and
 ``__graft_entry__.py`` with a small AST pass, then runs the
 concurrency analyzer (``analysis/concurrency.py``) over the package.
 
@@ -59,7 +59,7 @@ from learningorchestra_tpu.analysis.findings import (  # noqa: E402
 
 PACKAGE = REPO / "learningorchestra_tpu"
 EXTRA_ROOTS = (REPO / "scripts",)
-EXTRA_FILES = (REPO / "bench.py", REPO / "__graft_entry__.py")
+EXTRA_FILES = (REPO / "__graft_entry__.py",)
 
 # the one module that legitimately exec()s (user code, in the jail)
 EXEC_ALLOWED = {PACKAGE / "services" / "sandbox.py"}

@@ -319,6 +319,16 @@ def _resize_until_accepted(jobs, name, want, timeout=20.0):
     return False
 
 
+def _perform_requested_resize(token):
+    """What the engine does at a boundary, minus the engine: perform
+    a latched placement change and report a resize as done."""
+    if preempt.migrate_requested():
+        want = token.resize_want
+        performed, devices = preempt.perform_migrate()
+        if performed and want is not None:
+            token.resize_done(True, devices)
+
+
 def _wait_counter(token, attr, value, timeout=60.0):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
@@ -483,12 +493,7 @@ def test_closed_loop_shrink_places_aged_waiter(catalog):
         started.set()
         token = preempt.current_cancel()
         while not stop.is_set():
-            if preempt.migrate_requested():
-                want = token.resize_want
-                performed, devices = preempt.perform_migrate()
-                if performed and want is not None:
-                    # the engine's success report, minus the engine
-                    token.resize_done(True, devices)
+            _perform_requested_resize(token)
             time.sleep(0.02)
         return "held"
 
@@ -524,6 +529,44 @@ def test_closed_loop_shrink_places_aged_waiter(catalog):
             jobs.wait("as_holder", timeout=30)
         finally:
             jobs.shutdown()
+
+
+def test_slo_page_pressure_shrinks_a_live_job_that_still_finishes(
+        catalog):
+    """The other pressure source end to end: with a page alert firing
+    and no waiter at all, the running autoscaler shrinks a live
+    elastic job 4→2 at its next boundary; the job is never cancelled
+    and returns its own result."""
+    class _Paging:
+        def page_firing(self):
+            return True
+
+    jobs = _make_jobs(catalog)
+    scaler = SliceAutoscaler(jobs, interval_seconds=0.1,
+                             backoff_seconds=0.1,
+                             watchdog_fn=lambda: _Paging()).start()
+
+    def victim():
+        token = preempt.current_cancel()
+        deadline = time.monotonic() + 60.0
+        while token.resizes < 1 and time.monotonic() < deadline:
+            _perform_requested_resize(token)
+            time.sleep(0.02)
+        return len(token.slice_devices)
+
+    try:
+        catalog.create_collection("as_victim", "train/neural")
+        jobs.submit("as_victim", victim, needs_mesh=True, pool="train",
+                    footprint=dict(_ELASTIC_FP))
+        assert jobs.wait("as_victim", timeout=90) == 2
+        token = jobs._job_info["as_victim"]["token"]
+        assert token.resizes == 1 and not token.cancelled()
+        assert [e["event"] for e in token.slice_history] == [
+            "grant", "resize"]
+        assert scaler.stats()["counters"]["shrinksRequested"] >= 1
+    finally:
+        scaler.stop()
+        jobs.shutdown()
 
 
 def test_scheduler_fairness_holds_with_elastic_jobs(catalog):

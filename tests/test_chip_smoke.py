@@ -1,6 +1,6 @@
-"""The launchers' contract around the chip (chip_smoke.py, bench.py,
-the compile-cache rule): no accelerator -> non-zero exit and no result,
-no fallback that hides the device, the bench parent stays off jax."""
+"""The launcher's contract around the chip (chip_smoke.py, the
+compile-cache rule): no accelerator -> non-zero exit and no result,
+no fallback that hides the device."""
 
 import importlib.util
 import json
@@ -68,66 +68,6 @@ def test_chip_smoke_tiny_rehearsal_runs_every_phase(
         ["slot", "paged"]
     # the store (GBs of checkpoints at full size) is gone afterwards
     assert not os.path.exists(tmp_path / "lo_home")
-
-
-_BENCH_STUB = r"""
-import importlib.util, json, sys
-sys.path.insert(0, {repo!r})  # bench imports __graft_entry__
-spec = importlib.util.spec_from_file_location(
-    "lo_bench", {repo!r} + "/bench.py")
-bench = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(bench)
-bench._accelerator_probe = lambda: {probe!r}
-bench._run_phase = lambda phase, env=None: {phase_result}
-rc = bench.main([])
-print("JAX_IMPORTED", "jax" in sys.modules, file=sys.stderr)
-sys.exit(rc)
-"""
-
-
-def _run_bench(tmp_path, probe, phase_result='{"stub": phase}'):
-    code = _BENCH_STUB.format(repo=REPO, probe=probe,
-                              phase_result=phase_result)
-    return subprocess.run([sys.executable, "-c", code],
-                          capture_output=True, text=True, timeout=120,
-                          cwd=str(tmp_path))
-
-
-def test_bench_full_run_without_accelerator_fails_with_no_report(
-        tmp_path):
-    out = _run_bench(tmp_path, (False, "jax found only the CPU backend"))
-    assert out.returncode != 0
-    assert out.stdout.strip() == ""
-    assert "no accelerator" in out.stderr
-    assert "only the CPU backend" in out.stderr
-    assert not os.path.exists(tmp_path / "bench_report.json")
-
-
-def test_bench_full_run_with_a_failed_phase_reports_then_fails(
-        tmp_path):
-    out = _run_bench(
-        tmp_path, (True, "tpu | TPU v5 lite | 1"),
-        '({"error": "boom"} if phase == "tlm" else {"stub": phase})')
-    assert out.returncode != 0
-    compact = _json_lines(out.stdout)[-1]
-    assert compact["failed_phases"] == ["transformer_lm"]
-
-
-def test_bench_parent_never_imports_jax(tmp_path):
-    """The parent must stay off jax: a parent that touched a backend
-    would hold the chip its phase children need."""
-    out = _run_bench(tmp_path, (True, "tpu | TPU v5 lite | 1"))
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "JAX_IMPORTED False" in out.stderr
-
-
-def test_bench_probe_rejects_the_cpu_backend(monkeypatch):
-    """The real probe, in its real child: a process that reaches only
-    the CPU backend is 'no accelerator', with the reason."""
-    bench = _load("bench")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    ok, why = bench._accelerator_probe()
-    assert not ok and "only the CPU backend" in why
 
 
 def test_compile_cache_rule(monkeypatch):

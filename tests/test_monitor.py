@@ -560,6 +560,56 @@ def test_alerts_fire_resolve_healthz_and_gauges(slo_server,
     assert re.search(r"^lo_alerts_firing 0", raw.decode(), re.M)
 
 
+def test_serving_step_latency_fault_pages_through_a_live_session(
+        slo_server, tmp_config):
+    """The chaos site end to end: an armed ``serving_step`` latency
+    fault slows a live predict session's iterations, the requests'
+    own latencies land in ``lo_serving_request_seconds``, the
+    watchdog pages on them with the session's trace, and /healthz
+    follows the alert up and down."""
+    import numpy as np
+
+    from learningorchestra_tpu.models.estimators import (
+        LogisticRegressionJAX)
+    from learningorchestra_tpu.services import faults
+
+    ctx = slo_server.api.ctx
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(64, 4)).astype(np.float32)
+    clf = LogisticRegressionJAX(epochs=1, batch_size=32)
+    clf.fit(x, (x[:, 0] > 0).astype(np.int64))
+    ctx.artifacts.save(clf, "mon_clf", "train/tensorflow")
+    status, body = _call(slo_server, "POST", f"{API}/serve/mon_clf", {})
+    assert status == 201, body
+    watchdog = ctx.monitor.watchdog
+    t0 = time.time()
+    watchdog.evaluate(now=t0)
+    try:
+        # 0.3 s an iteration, three times the 100 ms objective
+        tmp_config.fault_inject = "serving_step:3:latency:0.3"
+        faults.reset()
+        for _ in range(3):
+            status, body = _call(
+                slo_server, "POST", f"{API}/serve/mon_clf/predict",
+                {"x": [[0.1, 0.2, 0.3, 0.4]]})
+            assert status == 200, body
+        watchdog.evaluate(now=t0 + 1.0)
+        (alert,) = watchdog.firing()
+        assert alert["name"] == "servingP99" and alert["value"] >= 300.0
+        assert alert["trace"].startswith("serve/mon_clf")
+        status, body = _call(slo_server, "GET", "/healthz")
+        assert status == 503 and body["status"] == "failing"
+        # the budget of three is spent: no restart, the window drains
+        watchdog.evaluate(now=t0 + 3.0)
+        assert not watchdog.page_firing()
+        status, body = _call(slo_server, "GET", "/healthz")
+        assert status == 200 and body["status"] == "ok"
+    finally:
+        tmp_config.fault_inject = ""
+        faults.reset()
+        _call(slo_server, "DELETE", f"{API}/serve/mon_clf")
+
+
 def test_healthz_503_while_draining(slo_server):
     slo_server.api.ctx.begin_drain()
     status, body = _call(slo_server, "GET", "/healthz")

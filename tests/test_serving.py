@@ -964,6 +964,82 @@ def test_bf16_paged_session_is_unchanged_by_quant_plumbing(tmp_path):
         _close_api(api)
 
 
+def _streams_per_decode_step(api, create_body, n_requests=8):
+    """Create a session of "slm", queue ``n_requests`` equal one-page
+    requests while its serve loop is held, let it go, and return how
+    many streams a decode step carried on average, with the session's
+    KV bytes. Holding the loop makes the count exact: every request is
+    queued before the first admission."""
+    status, resp, _ = api.dispatch(
+        "POST", f"{PREFIX}/serve/slm", {},
+        dict(create_body, temperature=0.7, topK=12))
+    assert status == 201, resp
+    session = api.ctx.serving._sessions["slm"]
+    go = threading.Event()
+    serve_once = session._serve_once
+
+    def held():
+        assert go.wait(timeout=60)
+        return serve_once()
+
+    session._serve_once = held
+    rng = np.random.default_rng(17)
+    prompts = [[int(t) for t in rng.integers(1, 48, size=4)]
+               for _ in range(n_requests)]
+    codes = [None] * n_requests
+
+    def client(i):
+        codes[i] = api.dispatch(
+            "POST", f"{PREFIX}/serve/slm/predict", {},
+            {"prompt": prompts[i], "maxNewTokens": 4, "seed": i})[0]
+
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(n_requests)]
+    for t in threads:
+        t.start()
+    try:
+        assert _wait_until(
+            lambda: session.stats()["queueDepth"] == n_requests)
+    finally:
+        go.set()
+    for t in threads:
+        t.join(timeout=120)
+    assert codes == [200] * n_requests
+    out = (session.decode_tokens_total / session.decode_steps,
+           session._cache_bytes)
+    api.dispatch("DELETE", f"{PREFIX}/serve/slm", {}, None)
+    return out
+
+
+@pytest.mark.parametrize("base,wide,bytes_ratio,floor", [
+    # a 2-slot cache against a pool of its 64 token rows (8 pages of
+    # 8) and the trash page on top: a request of 8 tokens funds one
+    # page, not a slot of 32
+    ({"maxSlots": 2, "cacheLen": 32},
+     {"kv": "paged", "pageLen": 8, "maxSlots": 8, "cacheLen": 32,
+      "pages": 9}, 9 / 8, 2.0),
+    # a pool of 4 pages, a lane a page, against an int8 pool of fewer
+    # bytes: an int8 page and its f32 scale rows are under half a
+    # bf16 page
+    ({"kv": "paged", "pageLen": 8, "maxSlots": 4, "cacheLen": 32,
+      "pages": 5},
+     {"kv": "paged", "pageLen": 8, "maxSlots": 8, "cacheLen": 32,
+      "pages": 9, "kvDtype": "int8"}, 1.0, 1.8),
+], ids=["paged_vs_slot", "int8_vs_bf16"])
+def test_equal_kv_bytes_hold_more_streams_per_decode_step(
+        api, base, wide, bytes_ratio, floor):
+    """Capacity at equal KV memory, counted and not timed: the same
+    eight one-page requests, all queued before the first admission,
+    ride more streams a decode step through the wider layout, whose
+    pool is no larger in bytes."""
+    _fit_lm(api)
+    base_streams, base_bytes = _streams_per_decode_step(api, base)
+    wide_streams, wide_bytes = _streams_per_decode_step(api, wide)
+    assert wide_bytes <= bytes_ratio * base_bytes
+    assert base_streams <= base["maxSlots"]
+    assert wide_streams >= floor * base_streams
+
+
 # ---------------------------- disaggregated serving + speculative decode
 _CYCLE = 16  # cycle length of the learnable successor stream
 

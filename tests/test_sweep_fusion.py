@@ -138,8 +138,7 @@ def test_fused_matches_unfused_trials(tmp_path):
 
 def test_fused_sweep_traces_once():
     """One cohort = one traced fused epoch program, regardless of how
-    many sweep points it carries (the zero-warm-retrace claim the CI
-    sweep-smoke gate asserts end-to-end)."""
+    many sweep points it carries."""
     x, y = _data()
     before = engine_lib.fused_epoch_traces()
     sweep = GridSearch(_estimator(),
@@ -148,6 +147,28 @@ def test_fused_sweep_traces_once():
     sweep.fit(x, y, epochs=3, batch_size=16)
     assert sweep.fusion_info_["fusedTrials"] == 4
     assert engine_lib.fused_epoch_traces() - before == 1
+
+
+def test_warm_fused_sweep_retraces_nothing():
+    """A second sweep of the same cohort (a new GridSearch, a new
+    estimator of the same architecture) finds the first one's fused
+    epoch program: it traces nothing. Three points, so that no other
+    test of this file has built the program first."""
+    x, y = _data()
+    grid = {"learning_rate": [1e-4, 1e-3, 1e-2]}
+
+    def run():
+        sweep = GridSearch(_estimator(), grid, validation_split=0.25,
+                           refit=False)
+        sweep.fit(x, y, epochs=3, batch_size=16)
+        assert sweep.fusion_info_["fusedTrials"] == 3
+        return sweep
+
+    cold = run()
+    before = engine_lib.fused_epoch_traces()
+    warm = run()
+    assert engine_lib.fused_epoch_traces() == before
+    assert warm.best_params_ == cold.best_params_
 
 
 def test_heterogeneous_grid_falls_back_bit_for_bit(tmp_path):

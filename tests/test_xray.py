@@ -165,6 +165,72 @@ def test_arena_entries_ledger_and_release(tmp_path):
         config_mod.reset_config()
 
 
+def test_fit_ledgers_train_state_while_it_runs(tmp_path, monkeypatch):
+    """A fit holds a ``train-state`` entry from its first step to its
+    exit: read at the moment the engine releases it, then after."""
+    from learningorchestra_tpu.models.neural import NeuralModel
+
+    config_mod.set_config(config_mod.Config(
+        home=str(tmp_path / "lo_home"), compute_dtype="float32"))
+    held = []
+    release = xray.release
+
+    def spy(owner, key):
+        if owner == "train-state":
+            held.append(xray.by_owner().get("train-state", 0))
+        return release(owner, key)
+
+    monkeypatch.setattr(xray, "release", spy)
+    try:
+        rng = np.random.default_rng(0)
+        x = rng.normal(size=(64, 6)).astype(np.float32)
+        model = NeuralModel([
+            {"kind": "dense", "units": 7, "activation": "relu"},
+            {"kind": "dense", "units": 2, "activation": "softmax"}])
+        model.fit(x, (x[:, 0] > 0).astype(np.int32), epochs=1,
+                  batch_size=16, shuffle=False)
+        # at least the parameters and Adam's two moments of them
+        n_params = 6 * 7 + 7 + 7 * 2 + 2
+        assert held and held[0] >= 3 * 4 * n_params
+        assert xray.by_owner().get("train-state", 0) == 0
+    finally:
+        config_mod.reset_config()
+
+
+def test_inflight_async_snapshot_is_ledgered_then_released(tmp_path):
+    """An async checkpoint's host snapshot is a ``snapshot`` entry of
+    its bytes while the commit is in flight, and none once it landed."""
+    from learningorchestra_tpu.runtime.async_ckpt import (
+        AsyncCheckpointManager)
+    from learningorchestra_tpu.runtime.checkpoint import Checkpointer
+
+    config_mod.set_config(config_mod.Config(
+        home=str(tmp_path / "lo_home")))
+    gate = threading.Event()
+
+    class Gated(Checkpointer):
+        def _commit_host(self, step, host):
+            assert gate.wait(timeout=60)
+            return super()._commit_host(step, host)
+
+    mgr = AsyncCheckpointManager(Gated(str(tmp_path / "ckpt")),
+                                 inflight=2)
+    try:
+        mgr.save(1, {"w": np.ones((256, 256), np.float32)})
+        assert xray.by_owner()["snapshot"] >= 256 * 256 * 4
+        (row,) = [r for r in xray.memory_report()["entries"]
+                  if r["owner"] == "snapshot"]
+        assert row["host"] is True
+        gate.set()
+        mgr.wait_until_finished()
+        assert xray.by_owner().get("snapshot", 0) == 0
+        assert mgr.latest_step() == 1
+    finally:
+        gate.set()
+        mgr.close()
+        config_mod.reset_config()
+
+
 # -------------------------------------------------- retrace sentinel
 def test_retrace_sentinel_counts_signature_changes():
     prog = ("engine", 1)
