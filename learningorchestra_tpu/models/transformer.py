@@ -47,6 +47,7 @@ from jax.sharding import PartitionSpec as P
 
 from learningorchestra_tpu.observability import trace as obs_trace
 from learningorchestra_tpu.ops import attention as attn_ops
+from learningorchestra_tpu.ops import ssd as ssd_ops
 from learningorchestra_tpu.parallel import moe as moe_lib
 from learningorchestra_tpu.parallel import ring as ring_lib
 from learningorchestra_tpu.parallel import sharding as sharding_lib
@@ -56,6 +57,10 @@ from learningorchestra_tpu.runtime import engine as engine_lib
 from learningorchestra_tpu.runtime import mesh as mesh_lib
 
 ATTENTION_IMPLS = ("dot", "flash", "ring", "ulysses")
+LAYER_TYPES = ("attention", "mamba")
+# parameters the engine leaves in float32 under a bf16 step: a Mamba-2
+# mixer's decay exponents come from them (docs/STATE_SPACE.md)
+FLOAT32_LEAVES = ("A_log", "dt_bias")
 
 
 # ----------------------------------------------------------------------
@@ -174,6 +179,13 @@ class _Attention(nn.Module):
     # block diffusion (docs/DIFFUSION.md): > 0 and the row is
     # [noisy ; clean], 2L positions, under ops.attention's bd mask
     bd_block: int = 0
+    # no rotary or any other position term (``position_embedding_type:
+    # "nope"``), and the softmax's scale as a number (0: 1/sqrt(head_dim));
+    # both on the training and prefill path only (LanguageModel refuses
+    # to decode a model that sets them)
+    rope: bool = True
+    scale: float = 0.0
+    eps: float = 1e-6        # of the QK-norms
 
     @property
     def kv_heads(self) -> int:
@@ -212,8 +224,8 @@ class _Attention(nn.Module):
             k = dense("k_proj", kv * self.head_dim)(x).reshape(kv_shape4)
             v = dense("v_proj", kv * self.head_dim)(x).reshape(kv_shape4)
         if self.qk_norm:
-            q = nn.RMSNorm(name="q_norm")(q)
-            k = nn.RMSNorm(name="k_norm")(k)
+            q = nn.RMSNorm(epsilon=self.eps, name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=self.eps, name="k_norm")(k)
 
         if decode_pos is not None and jnp.ndim(decode_pos) == 0 \
                 and pad_offset is None:
@@ -400,7 +412,11 @@ class _Attention(nn.Module):
                     q, ck.value, cv.value, pos, pad_offset=pad_offset,
                     window=self.window).reshape(shape4)
         else:
-            if self.bd_block:
+            if not self.rope:
+                if pad_offset is not None or decode_pos is not None:
+                    raise NotImplementedError(
+                        "attention without RoPE runs on the training path")
+            elif self.bd_block:
                 # both halves of [noisy ; clean] sit at positions 0..L-1
                 cos, sin = rope_tables(s // 2, self.head_dim,
                                        base=self.rope_base)
@@ -449,13 +465,15 @@ class _Attention(nn.Module):
                                     causal=self.causal, mesh=self.mesh,
                                     window=self.window,
                                     kv_valid=kv_valid,
-                                    bd_block=self.bd_block)
+                                    bd_block=self.bd_block,
+                                    scale=self.scale or None)
         o = o.reshape(b, s, proj)
         return dense("o_proj", d_model)(o)
 
 
 def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
-                        window: int = 0, kv_valid=None, bd_block: int = 0):
+                        window: int = 0, kv_valid=None, bd_block: int = 0,
+                        scale: Optional[float] = None):
     """q: (b, s, h, d); k/v may carry FEWER (kv) heads under GQA.
     The single-chip flash path consumes them natively (the kernel
     folds the query group — K/V never materialize at h heads); every
@@ -469,11 +487,16 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
     of unequal-length batches (docs/SERVING.md). ``bd_block`` > 0 is a
     block-diffusion row [noisy ; clean] (docs/DIFFUSION.md): the flash
     kernels under that mask, or its dense masked softmax on ``dot``;
-    no window, no sequence parallelism."""
+    no window, no sequence parallelism. ``scale`` (None: 1/sqrt(d))
+    is the softmax's, on the dot and flash paths."""
     mesh = mesh or mesh_lib.current_mesh()
     b, s, h, _ = q.shape
     kvh = k.shape[2]
     group = h // kvh
+    extra = {} if scale is None else {"scale": float(scale)}
+    if scale is not None and (bd_block or impl in ("ring", "ulysses")):
+        raise ValueError("a given attention scale runs on the dot and "
+                         "flash paths of the next-token objective")
     if bd_block:
         if window or kv_valid is not None or impl in ("ring", "ulysses"):
             raise ValueError(
@@ -483,7 +506,7 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
                                   block_length=bd_block)
     else:
         flash = functools.partial(attn_ops.flash_attention,
-                                  causal=causal, window=window)
+                                  causal=causal, window=window, **extra)
 
     def repeated():
         if group == 1:
@@ -493,7 +516,8 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
     if kv_valid is not None:
         kr, vr = repeated()
         return ring_lib.full_attention_reference(
-            q, kr, vr, causal=causal, window=window, kv_valid=kv_valid)
+            q, kr, vr, causal=causal, window=window, kv_valid=kv_valid,
+            **extra)
     data_size = mesh_lib.data_parallel_size(mesh)
     sp = mesh.shape.get(mesh_lib.SP, 1)
     tp = mesh.shape.get(mesh_lib.TP, 1)
@@ -547,7 +571,7 @@ def _dispatch_attention(q, k, v, *, impl: str, causal: bool, mesh=None,
                                                block_length=bd_block)
     kr, vr = repeated()
     return ring_lib.full_attention_reference(q, kr, vr, causal=causal,
-                                             window=window)
+                                             window=window, **extra)
 
 
 class _MLP(nn.Module):
@@ -597,6 +621,108 @@ class _MoE(nn.Module):
                                  expert_offset=self.expert_offset)
 
 
+def _check_layer_types(layer_types, n_layers: int) -> None:
+    if layer_types is not None and (
+            len(layer_types) != int(n_layers)
+            or set(layer_types) - set(LAYER_TYPES)):
+        raise ValueError(
+            f"layer_types must name one of {LAYER_TYPES} for each of the "
+            f"{n_layers} layers, got {layer_types!r}")
+
+
+def _scaled(x, factor: float):
+    """``x * factor`` with the factor in float32 and the product rounded
+    once: a factor cast to bf16 first is another number (0.22 becomes
+    0.2197, every residual write 0.12% short; PERF.md, PR 32)."""
+    if factor == 1.0:
+        return x
+    return (x.astype(jnp.float32) * factor).astype(x.dtype)
+
+
+def _residual(x, h, multiplier: float):
+    """``x + multiplier * h``; at 1.0 the sum the blocks always took."""
+    return x + _scaled(h, multiplier)
+
+
+class _Mamba2(nn.Module):
+    """A Mamba-2 mixer (docs/STATE_SPACE.md): one projection to the gate
+    ``z``, the conv's channels ``[x | B | C]`` and a step ``dt`` a head;
+    a causal depthwise conv and SiLU over ``[x | B | C]``; the state-space
+    scan (``ops/ssd.py``) over ``n_heads`` heads of ``head_dim`` with a
+    state of ``head_dim x d_state`` each and ``B``, ``C`` shared by the
+    heads (one group); ``RMSNorm(y * silu(z))`` over all channels with a
+    learned scale; the projection back. Returns ``(out, stats)``:
+    ``stats (2,)`` float32, the RMS of the states held at the rows' end
+    and the mean decay ``a_t``, for the counters."""
+    n_heads: int
+    head_dim: int
+    d_state: int
+    d_conv: int = 4
+    chunk: int = 256
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, u):
+        b, s, d_model = u.shape
+        heads, hd, n = self.n_heads, self.head_dim, self.d_state
+        d_inner = heads * hd
+        conv_dim = d_inner + 2 * n
+        f32 = jnp.float32
+        with jax.named_scope("ssm/in_proj"):
+            zxbcdt = nn.Dense(d_inner + conv_dim + heads, use_bias=False,
+                              name="in_proj")(u)
+        z = zxbcdt[..., :d_inner]
+        xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
+        dt = zxbcdt[..., d_inner + conv_dim:]
+        with jax.named_scope("ssm/conv"):
+            # conv_kernel[k] weighs position t - (d_conv - 1) + k
+            w = self.param("conv_kernel", nn.initializers.normal(
+                1.0 / math.sqrt(self.d_conv)), (self.d_conv, conv_dim))
+            bias = self.param("conv_bias", nn.initializers.zeros,
+                              (conv_dim,))
+            padded = jnp.pad(xbc.astype(f32),
+                             ((0, 0), (self.d_conv - 1, 0), (0, 0)))
+            acc = bias.astype(f32)
+            for k in range(self.d_conv):
+                acc = acc + padded[:, k:k + s] * w[k].astype(f32)
+            xbc = nn.silu(acc).astype(u.dtype)
+        x = xbc[..., :d_inner].reshape(b, s, heads, hd)
+        B = xbc[..., d_inner:d_inner + n]
+        C = xbc[..., d_inner + n:]
+
+        def log_uniform(lo, hi, inverse_softplus=False):
+            def init(key, shape, dtype=f32):
+                v = jnp.exp(jax.random.uniform(
+                    key, shape, f32, math.log(lo), math.log(hi)))
+                if inverse_softplus:
+                    v = v + jnp.log(-jnp.expm1(-v))
+                return v.astype(dtype)
+            return init
+
+        # the Mamba-2 convention: dt in 0.001..0.1 after the softplus,
+        # exp(A_log) in 1..16
+        dt_bias = self.param("dt_bias", log_uniform(1e-3, 1e-1, True),
+                             (heads,))
+        a_log = self.param(
+            "A_log", lambda key, shape, dtype=f32: jnp.log(
+                jax.random.uniform(key, shape, f32, 1.0, 16.0)
+            ).astype(dtype), (heads,))
+        skip = self.param("D", nn.initializers.ones, (heads,))
+        with jax.named_scope("ssm/scan"):
+            dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+            A = -jnp.exp(a_log.astype(f32))
+            y, state = ssd_ops.ssd(x, dt, A, B, C, skip, self.chunk)
+            stats = jax.lax.stop_gradient(jnp.stack([
+                jnp.sqrt(jnp.mean(jnp.square(state))),
+                jnp.mean(jnp.exp(dt * A))]))
+        with jax.named_scope("ssm/gate_norm"):
+            y = y.reshape(b, s, d_inner) * nn.silu(z)
+            y = nn.RMSNorm(epsilon=self.eps, name="norm")(y)
+        with jax.named_scope("ssm/out_proj"):
+            out = nn.Dense(d_model, use_bias=False, name="out_proj")(y)
+        return out, stats
+
+
 class _Block(nn.Module):
     n_heads: int
     head_dim: int
@@ -617,33 +743,54 @@ class _Block(nn.Module):
     expert_offset: int = 0
     qk_norm: bool = False
     bd_block: int = 0
+    # the layer's mixer, "attention" or "mamba" (TransformerLM's
+    # ``layer_types``), and what a Mamba-2 mixer is sized by:
+    # (heads, head_dim, d_state, d_conv, chunk)
+    mixer: str = "attention"
+    ssm: Tuple[int, ...] = ()
+    eps: float = 1e-6
+    rope: bool = True
+    attention_scale: float = 0.0
+    residual_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, x, train: bool, decode_pos=None, cache_len: int = 0,
                  pad_offset=None, kv_len=None, block_tables=None,
                  page_len: int = 0, kv_pages: int = 0,
                  kv_quant: bool = False, verify_limit=None):
-        """Returns ``(x, aux, counts)``; ``counts`` is the expert
-        layer's (held,) copies, of length 0 under a dense MLP."""
-        h = nn.RMSNorm(name="attn_norm")(x)
-        h = _Attention(self.n_heads, self.head_dim, self.attention,
-                       self.causal, self.mesh,
-                       n_kv_heads=self.n_kv_heads,
-                       fused_qkv=self.fused_proj,
-                       lora_rank=self.lora_rank,
-                       lora_alpha=self.lora_alpha,
-                       window=self.window,
-                       rope_base=self.rope_base, qk_norm=self.qk_norm,
-                       bd_block=self.bd_block, name="attn")(
-            h, train, decode_pos=decode_pos, cache_len=cache_len,
-            pad_offset=pad_offset, kv_len=kv_len,
-            block_tables=block_tables, page_len=page_len,
-            kv_pages=kv_pages, kv_quant=kv_quant,
-            verify_limit=verify_limit)
+        """Returns ``(x, aux, counts, stats)``; ``counts`` is the expert
+        layer's (held,) copies, of length 0 under a dense MLP; ``stats``
+        a Mamba-2 mixer's (``_Mamba2``), None under attention."""
+        stats = None
+        if self.mixer == "mamba":
+            if decode_pos is not None or cache_len:
+                raise NotImplementedError(
+                    "a Mamba-2 layer has no decode path yet: its "
+                    "recurrent state is not carried between calls")
+            h = nn.RMSNorm(epsilon=self.eps, name="ssm_norm")(x)
+            h, stats = _Mamba2(*self.ssm, eps=self.eps, name="ssm")(h)
+        else:
+            h = nn.RMSNorm(epsilon=self.eps, name="attn_norm")(x)
+            h = _Attention(self.n_heads, self.head_dim, self.attention,
+                           self.causal, self.mesh,
+                           n_kv_heads=self.n_kv_heads,
+                           fused_qkv=self.fused_proj,
+                           lora_rank=self.lora_rank,
+                           lora_alpha=self.lora_alpha,
+                           window=self.window,
+                           rope_base=self.rope_base, qk_norm=self.qk_norm,
+                           bd_block=self.bd_block, rope=self.rope,
+                           scale=self.attention_scale, eps=self.eps,
+                           name="attn")(
+                h, train, decode_pos=decode_pos, cache_len=cache_len,
+                pad_offset=pad_offset, kv_len=kv_len,
+                block_tables=block_tables, page_len=page_len,
+                kv_pages=kv_pages, kv_quant=kv_quant,
+                verify_limit=verify_limit)
         if self.dropout and train:
             h = nn.Dropout(self.dropout, deterministic=False)(h)
-        x = x + h
-        h = nn.RMSNorm(name="mlp_norm")(x)
+        x = _residual(x, h, self.residual_multiplier)
+        h = nn.RMSNorm(epsilon=self.eps, name="mlp_norm")(x)
         aux = jnp.zeros((), jnp.float32)
         counts = jnp.zeros((0,), jnp.int32)
         if self.n_experts > 0:
@@ -655,7 +802,8 @@ class _Block(nn.Module):
                      name="mlp")(h)
         if self.dropout and train:
             h = nn.Dropout(self.dropout, deterministic=False)(h)
-        return x + h, aux, counts
+        return (_residual(x, h, self.residual_multiplier), aux, counts,
+                stats)
 
 
 class FusedHeadOut(NamedTuple):
@@ -679,6 +827,9 @@ class FusedHeadOut(NamedTuple):
     # block diffusion: the step's noise, {"masked": (b, L) bool,
     # "t": (b,)}, put here by LanguageModel._apply_fn for the loss
     noise: Any = None
+    # {"l<i>": (2,) float32} of the Mamba-2 layers: the RMS of the
+    # states held at the rows' end and the mean decay (``_Mamba2``)
+    ssm_stats: Any = None
 
 
 class _LMHead(nn.Module):
@@ -754,6 +905,24 @@ class TransformerLM(nn.Module):
     # block diffusion: > 0 and ``tokens`` is [noisy ; clean], 2L
     # positions (docs/DIFFUSION.md); the output is FusedHeadOut
     bd_block: int = 0
+    # the per-layer spec: one of LAYER_TYPES a layer (None: attention
+    # everywhere), and a Mamba-2 mixer's sizes (docs/STATE_SPACE.md)
+    layer_types: Optional[Tuple[str, ...]] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    rms_norm_eps: float = 1e-6
+    rope: bool = True              # False: no position term at all
+    attention_scale: float = 0.0   # 0: 1/sqrt(head_dim)
+    # x = embed * embedding_multiplier; x += residual_multiplier * f(x);
+    # logits = h @ W / logits_scaling (the granite family's settings)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # the head is the embedding transposed: one table, no lm_head
+    tie_embeddings: bool = False
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, decode_pos=None,
@@ -771,9 +940,14 @@ class TransformerLM(nn.Module):
             raise ValueError("the block-diffusion loss runs through the "
                              "chunked head: fused_head_chunk must be > 0")
 
+        _check_layer_types(self.layer_types, self.n_layers)
+        types = self.layer_types or ("attention",) * self.n_layers
+        ssm = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+               self.ssm_conv, self.ssm_chunk)
+        eps = self.rms_norm_eps
+        embed = nn.Embed(self.vocab_size, self.d_model, name="embed")
         with jax.named_scope("embed"):
-            x = nn.Embed(self.vocab_size, self.d_model,
-                         name="embed")(tokens)
+            x = _scaled(embed(tokens), self.embedding_multiplier)
         if decode_pos is None:
             x = sharding_lib.constrain(
                 x, mesh, mesh_lib.data_axes(mesh) or None,
@@ -803,8 +977,9 @@ class TransformerLM(nn.Module):
                                  static_argnums=(2, 3, 4, 7, 8, 9, 10))
         aux_total = jnp.zeros((), jnp.float32)
         counts = []
+        ssm_stats = {}
         for i in range(self.n_layers):
-            x, aux, layer_counts = block_cls(
+            x, aux, layer_counts, stats = block_cls(
                 self.n_heads, head_dim, d_ff,
                 self.attention, self.causal,
                 self.n_experts, self.moe_k,
@@ -814,14 +989,28 @@ class TransformerLM(nn.Module):
                 self.sliding_window, self.rope_base,
                 self.experts_held, self.expert_offset,
                 self.qk_norm, self.bd_block,
+                mixer=types[i], ssm=ssm if types[i] == "mamba" else (),
+                eps=eps, rope=self.rope,
+                attention_scale=self.attention_scale,
+                residual_multiplier=self.residual_multiplier,
                 name=f"layer_{i}")(
                 x, train, decode_pos, cache_len, pad_offset, kv_len,
                 block_tables, page_len, kv_pages, kv_quant,
                 verify_limit)
             aux_total = aux_total + aux
             counts.append(layer_counts)
-        x = nn.RMSNorm(name="final_norm")(x)
-        head = _LMHead(self.vocab_size, name="lm_head")
+            if stats is not None:
+                ssm_stats[f"l{i}"] = stats
+        x = nn.RMSNorm(epsilon=eps, name="final_norm")(x)
+        # h @ W / c as (h / c) @ W: the chunked head takes it so
+        x = _scaled(x, 1.0 / self.logits_scaling)
+        if self.tie_embeddings:
+            def head(h, return_kernel: bool = False):
+                kernel = embed.embedding.T
+                return kernel if return_kernel \
+                    else h @ kernel.astype(h.dtype)
+        else:
+            head = _LMHead(self.vocab_size, name="lm_head")
         if self.fused_head_chunk and decode_pos is None and \
                 (train or self.bd_block):
             tiles = None
@@ -833,7 +1022,8 @@ class TransformerLM(nn.Module):
                                 kernel=head(x, return_kernel=True),
                                 aux=aux_total,
                                 moe_counts=jnp.stack(counts),
-                                moe_tiles=tiles)
+                                moe_tiles=tiles,
+                                ssm_stats=ssm_stats or None)
         return head(x), aux_total
 
 
@@ -963,6 +1153,19 @@ def _moe_counters(out: FusedHeadOut) -> Dict[str, Any]:
     return counters
 
 
+def _ssm_counters(out: FusedHeadOut) -> Dict[str, Any]:
+    """The Mamba-2 layers' readings as epoch-record counters, a mean
+    over the epoch's steps: ``ssmStateRms_l<i>`` (the RMS of the states
+    the layer holds at the rows' end) and ``ssmDecayMean_l<i>`` (the
+    mean of ``a_t`` over positions and heads)."""
+    one = jnp.ones((), jnp.float32)
+    counters = {}
+    for layer, stats in (out.ssm_stats or {}).items():
+        counters[f"ssmStateRms_{layer}"] = (stats[0], one)
+        counters[f"ssmDecayMean_{layer}"] = (stats[1], one)
+    return counters
+
+
 def _head_targets(out: FusedHeadOut, batch, weights):
     """(hidden (b, n, d), targets (b, n), weights (b, n), denominator,
     counters) of the chunked head, by objective. Next token: position
@@ -1028,6 +1231,7 @@ def _fused_head_loss(out: FusedHeadOut, batch, weights, chunk: int,
     # masked positions, each by its row's 1/t)
     counters["accuracy"] = (ok_sum, total)
     counters.update(_moe_counters(out))
+    counters.update(_ssm_counters(out))
     return loss, counters
 
 
@@ -1246,7 +1450,7 @@ class TransformerEncoder(nn.Module):
             else None,
             None)
         for i in range(self.n_layers):
-            x, _, _ = _Block(self.n_heads, head_dim, d_ff,
+            x, _, _, _ = _Block(self.n_heads, head_dim, d_ff,
                              self.attention, False, 0, 2,
                              self.dropout, self.mesh, self.n_kv_heads,
                              name=f"layer_{i}")(x, train)
@@ -1604,8 +1808,14 @@ class LanguageModel:
                     "fused_proj", "lora_rank", "lora_alpha",
                     "sliding_window", "rope_base", "head_dim", "qk_norm",
                     "experts_held", "expert_offset", "objective",
-                    "block_length", "mask_token_id")
+                    "block_length", "mask_token_id",
+                    "layer_types", "ssm_heads", "ssm_head_dim",
+                    "ssm_state", "ssm_conv", "ssm_chunk", "rms_norm_eps",
+                    "position_embedding", "attention_scale",
+                    "embedding_multiplier", "residual_multiplier",
+                    "logits_scaling", "tie_embeddings")
     OBJECTIVES = ("next_token", "block_diffusion")
+    POSITION_EMBEDDINGS = ("rope", "nope")
 
     def __init__(self, vocab_size: int, d_model: int = 256,
                  n_layers: int = 4, n_heads: int = 4,
@@ -1620,8 +1830,52 @@ class LanguageModel:
                  experts_held: int = 0, expert_offset: int = 0,
                  objective: str = "next_token", block_length: int = 4,
                  mask_token_id: Optional[int] = None,
+                 layer_types: Optional[Any] = None, ssm_heads: int = 0,
+                 ssm_head_dim: int = 64, ssm_state: int = 128,
+                 ssm_conv: int = 4, ssm_chunk: int = 256,
+                 rms_norm_eps: float = 1e-6,
+                 position_embedding: str = "rope",
+                 attention_scale: float = 0.0,
+                 embedding_multiplier: float = 1.0,
+                 residual_multiplier: float = 1.0,
+                 logits_scaling: float = 1.0,
+                 tie_embeddings: bool = False,
                  name: str = "language_model"):
         self.name = name
+        # the per-layer spec (docs/STATE_SPACE.md): one of LAYER_TYPES a
+        # layer, None for attention everywhere; a tuple, so that it
+        # hashes (the engine's cache key) and saves as a list
+        self.layer_types = None if layer_types is None \
+            else tuple(str(t) for t in layer_types)
+        _check_layer_types(self.layer_types, n_layers)
+        self.ssm_heads, self.ssm_head_dim = int(ssm_heads), int(ssm_head_dim)
+        self.ssm_state, self.ssm_conv = int(ssm_state), int(ssm_conv)
+        self.ssm_chunk = int(ssm_chunk)
+        if self.has_mamba and (
+                min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                    self.ssm_conv, self.ssm_chunk) < 1
+                or n_experts or objective != "next_token"
+                or attention in ("ring", "ulysses") or lora_rank):
+            raise ValueError(
+                "a model with Mamba-2 layers needs ssm_heads, ssm_head_dim, "
+                "ssm_state, ssm_conv and ssm_chunk >= 1, a dense MLP, the "
+                "next-token objective, no sequence parallelism and no LoRA")
+        self.rms_norm_eps = float(rms_norm_eps)
+        if position_embedding not in self.POSITION_EMBEDDINGS:
+            raise ValueError(f"position_embedding must be one of "
+                             f"{self.POSITION_EMBEDDINGS}, got "
+                             f"{position_embedding!r}")
+        self.position_embedding = position_embedding
+        self.attention_scale = float(attention_scale)
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.residual_multiplier = float(residual_multiplier)
+        self.logits_scaling = float(logits_scaling)
+        self.tie_embeddings = bool(tie_embeddings)
+        if self.attention_scale < 0 or self.logits_scaling <= 0 \
+                or self.rms_norm_eps <= 0:
+            raise ValueError("attention_scale must be >= 0 (0: 1/sqrt("
+                             "head_dim)), logits_scaling and rms_norm_eps "
+                             "> 0")
         self.head_dim = int(head_dim)
         self.qk_norm = bool(qk_norm)
         if self.head_dim < 0 or self.head_dim % 2:
@@ -1705,6 +1959,10 @@ class LanguageModel:
         self._mesh_override = None
         self._accum = engine_lib.default_grad_accum()
         self._drop_decode_caches()
+
+    @property
+    def has_mamba(self) -> bool:
+        return "mamba" in (self.layer_types or ())
 
     def set_mesh(self, mesh) -> None:
         """Pin this model to a mesh (e.g. a sweep trial's sub-slice of
@@ -1831,7 +2089,17 @@ class LanguageModel:
             rope_base=self.rope_base, head_dim=self.head_dim,
             qk_norm=self.qk_norm, experts_held=self.experts_held,
             expert_offset=self.expert_offset,
-            bd_block=self.block_length if bd else 0)
+            bd_block=self.block_length if bd else 0,
+            layer_types=self.layer_types, ssm_heads=self.ssm_heads,
+            ssm_head_dim=self.ssm_head_dim, ssm_state=self.ssm_state,
+            ssm_conv=self.ssm_conv, ssm_chunk=self.ssm_chunk,
+            rms_norm_eps=self.rms_norm_eps,
+            rope=self.position_embedding == "rope",
+            attention_scale=self.attention_scale,
+            embedding_multiplier=self.embedding_multiplier,
+            residual_multiplier=self.residual_multiplier,
+            logits_scaling=self.logits_scaling,
+            tie_embeddings=self.tie_embeddings)
 
     @property
     def module(self) -> TransformerLM:
@@ -1926,11 +2194,14 @@ class LanguageModel:
                 # is excluded — its lookup is a gather, not a matmul
                 # (lm_head is a separate, counted matrix).
                 b, s = batch["x"].shape[:2]
-                matmul_params = (self.num_params()
-                                 - self.vocab_size * self.d_model)
+                matmul_params = self.num_params()
+                if not self.tie_embeddings:  # a tied table IS the head
+                    matmul_params -= self.vocab_size * self.d_model
                 proj = self.n_heads * (self.head_dim
                                        or self.d_model // self.n_heads)
-                attn = 6.0 * self.n_layers * b * s * s * proj
+                attn_layers = self.n_layers if self.layer_types is None \
+                    else self.layer_types.count("attention")
+                attn = 6.0 * attn_layers * b * s * s * proj
                 if self.n_experts:
                     # a token meets moe_k of n_experts: of the held
                     # experts' matrices, that share
@@ -1965,7 +2236,8 @@ class LanguageModel:
                 predict_transform=lambda outputs: outputs[0],
                 flops_floor_fn=flops_floor,
                 grad_accum=self._accum,
-                counter_prefixes=("moe", "masked"),
+                counter_prefixes=("moe", "masked", "ssm"),
+                float32_leaves=FLOAT32_LEAVES if self.has_mamba else (),
                 cache_key=self._engine_cache_key())
         return self._engine
 
@@ -2048,7 +2320,7 @@ class LanguageModel:
     def predict(self, x=None, batch_size: Optional[int] = None,
                 **_: Any) -> np.ndarray:
         """Next-token logits (n, seq, vocab)."""
-        self._require_autoregressive("predict")
+        self._require_next_token("predict")
         self._require_built()
         eng = self._get_engine()
         state = self._state or eng.init_state(self.params)
@@ -2816,7 +3088,7 @@ class LanguageModel:
         fns[sig] = propose
         return fns[sig]
 
-    def _require_autoregressive(self, what: str) -> None:
+    def _require_next_token(self, what: str) -> None:
         """Generation by blocks (several denoising passes a block, a
         step that yields a block and not a token) is not built yet
         (ROADMAP): a block-diffusion model trains and evaluates."""
@@ -2824,6 +3096,26 @@ class LanguageModel:
             raise NotImplementedError(
                 f"{what} of an objective={self.objective!r} model: "
                 f"block-wise denoising generation is not implemented")
+
+    def _require_autoregressive(self, what: str) -> None:
+        """The guard of every path that decodes through a cache:
+        ``_require_next_token``, and neither is decoding through a
+        Mamba-2 layer built (its recurrent state would live beside the
+        KV cache, ROADMAP R5) nor the decode paths' share of the
+        settings that came with it: such a model trains, evaluates and
+        predicts (whole rows, no cache)."""
+        self._require_next_token(what)
+        undecodable = [k for k, default in (
+            ("position_embedding", "rope"), ("attention_scale", 0.0),
+            ("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
+            ("logits_scaling", 1.0)) if getattr(self, k) != default]
+        if self.has_mamba or undecodable:
+            raise NotImplementedError(
+                f"{what} of a model with "
+                f"{'Mamba-2 layers' if self.has_mamba else undecodable}: "
+                f"the decode paths carry neither a recurrent state nor "
+                f"these settings yet (docs/STATE_SPACE.md); fit, evaluate "
+                f"and predict work")
 
     def _require_built(self) -> None:
         if self.params is None:

@@ -153,8 +153,13 @@ class Engine:
                  flops_floor_fn: Optional[Callable] = None,
                  grad_accum: int = 1,
                  cache_key: Any = None,
-                 counter_prefixes: Tuple[str, ...] = ()):
+                 counter_prefixes: Tuple[str, ...] = (),
+                 float32_leaves: Tuple[str, ...] = ()):
         self._apply_fn = apply_fn
+        # parameters of these names are not cast to the compute dtype
+        # (a state-space layer's decay exponents); the model's settings
+        # decide them, so the cache key already tells such engines apart
+        self._float32_leaves = tuple(float32_leaves)
         # loss-emitted sums whose names start so are COUNTERS of the
         # model (router load, masked positions): like every emitted
         # metric they reach the epoch record as a mean over the steps,
@@ -272,6 +277,10 @@ class Engine:
                 return x.astype(dtype)
             return x
 
+        if self._float32_leaves:
+            return jax.tree_util.tree_map_with_path(
+                lambda path, x: x if getattr(path[-1], "key", None)
+                in self._float32_leaves else cast_leaf(x), tree)
         return jax.tree_util.tree_map(cast_leaf, tree)
 
     # ------------------------------------------------------------------

@@ -267,7 +267,7 @@ def test_count_only_takes_the_models_count_and_lowers_nothing():
 
 
 # ------------------------------------ fit history + timeline block
-def _fit_small(monkeypatch, tmp_path, epochs=3):
+def _fit_small(monkeypatch, tmp_path, epochs=3, units=32):
     monkeypatch.setenv("LO_PEAK_TFLOPS_PER_CHIP", "0.05")
     monkeypatch.setenv("LO_PEAK_HBM_GBPS", "1")
     config_mod.set_config(config_mod.Config(
@@ -278,7 +278,7 @@ def _fit_small(monkeypatch, tmp_path, epochs=3):
     x = rng.normal(size=(1024, 32)).astype(np.float32)
     y = (x[:, 0] > 0).astype(np.int32)
     model = NeuralModel([
-        {"kind": "dense", "units": 32, "activation": "relu"},
+        {"kind": "dense", "units": units, "activation": "relu"},
         {"kind": "dense", "units": 2, "activation": "softmax"}])
     with obs_trace.span("job", trace="perf_fit", phase="run"):
         model.fit(x, y, epochs=epochs, batch_size=128, shuffle=False)
@@ -298,7 +298,11 @@ def test_fit_history_carries_roofline_block(monkeypatch, tmp_path):
 
 
 def test_timeline_summary_emits_perf_percentiles(monkeypatch, tmp_path):
-    _fit_small(monkeypatch, tmp_path)
+    # the rates are rounded to four decimals of a TFLOP/s: at 32 units
+    # an epoch is 7 MFLOP, and beside five other xdist workers on the
+    # same cores its rate read 0.0000 (the driver's run of PR 31). 512
+    # units are 107 MFLOP an epoch, clear of the rounding a hundredfold.
+    _fit_small(monkeypatch, tmp_path, units=512)
     tl = obs_timeline.summary("perf_fit")
     perf = tl.get("perf")
     assert perf, tl

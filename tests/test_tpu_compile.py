@@ -222,3 +222,54 @@ def test_expert_layer_gradient_compiles_for_v5e_with_the_chunked_passes(
     # the loops update the row buffers in place (``gated``'s gradients
     # start as its inputs): no copy of a whole one
     assert not re.search(r"= bf16\[135168,\d+\]\{[^}]*\} copy\(", text)
+
+
+def test_ssd_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
+    """The state-space scan at granite-4.0-h-micro's widths: one row of
+    8,192 positions, 64 heads of 64, a state of 128, chunks of 256;
+    forward and backward, each kernel under its own name, the forward
+    twice (with and without the chunks' starting states)."""
+    from learningorchestra_tpu.ops import ssd
+
+    def loss(x, dt, a, b, c, d):
+        with jax.named_scope("ssm/scan"):  # as the model's module does
+            y, state = ssd.ssd(x, dt, a, b, c, d, 256, impl="pallas",
+                               interpret=False)
+        return y.astype(jnp.float32).sum() + state.sum()
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((1, 8192, 64, 64)), sds((1, 8192, 64), jnp.float32),
+            sds((64,), jnp.float32), sds((1, 8192, 128)),
+            sds((1, 8192, 128)), sds((64,), jnp.float32))
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile().as_text()
+    calls = _custom_calls(text)
+    assert {"ssd_fwd", "ssd_bwd"} <= calls, calls
+    assert not any(c.startswith("ssm") for c in calls), calls
+    # nothing chunk x chunk a head leaves the kernels: no array of
+    # 32 chunks x 64 heads x 256 x 256 in the program
+    assert not re.search(r"\[(1,)?(32,64|64,32),256,256\]", text)
+    fwd_only = jax.jit(loss).lower(*args).compile().as_text()
+    assert "ssd_fwd" in fwd_only and "ssd_bwd" not in fwd_only
+
+
+def test_flash_kernels_compile_for_v5e_at_head_64_with_a_given_scale(
+        one_chip):
+    """The hybrid's attention layer: one row of 8,192 positions, 32
+    query heads on 8 KV heads of 64 (half a lane tile), the softmax's
+    scale given as a number (1/64, not 1/sqrt(64)); forward and both
+    backward kernels, each under its own name."""
+    fn = functools.partial(attn.flash_attention, interpret=False,
+                           causal=True, scale=0.015625)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            return fn(q, k, v).astype(jnp.float32).sum()
+
+    q, kv = (1, 8192, 32, 64), (1, 8192, 8, 64)
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                          one_chip, q, kv, kv)
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} \
+        <= _custom_calls(text)
