@@ -66,14 +66,19 @@ def _scan_inputs(b, s, heads, p, n, seed=0):
 
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-@pytest.mark.parametrize("seq", [32, 27, 5],
-                         ids=["whole_chunks", "ragged", "under_a_chunk"])
-def test_chunked_scan_matches_the_stepped_recurrence(impl, seq):
+@pytest.mark.parametrize(
+    "seq,heads,p", [(32, 4, 8), (27, 4, 8), (5, 4, 8), (32, 16, 16),
+                    (27, 16, 64)],
+    ids=["whole_chunks", "ragged", "under_a_chunk",
+         "two_head_blocks_of_16", "two_head_blocks_of_head_pairs"])
+def test_chunked_scan_matches_the_stepped_recurrence(impl, seq, heads, p):
     """Values, the state held at the row's end and every gradient, at
     lengths that are and are not a multiple of the chunk; the kernels
-    run in interpret mode."""
-    args = _scan_inputs(2, seq, 4, 8, 16)
-    w = jax.random.normal(jax.random.PRNGKey(9), (2, seq, 4, 8))
+    run in interpret mode. 16 heads make two blocks of 8 (x's lanes
+    taken a block at a time): 8 heads of 16 share a lane tile, heads of
+    64 go in pairs (the cell's width)."""
+    args = _scan_inputs(2, seq, heads, p, 16)
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, seq, heads, p))
 
     def chunked(*a):
         return ssd_ops.ssd(*a, chunk=8, impl=impl, interpret=True)

@@ -255,6 +255,66 @@ def test_ssd_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     assert "ssd_fwd" in fwd_only and "ssd_bwd" not in fwd_only
 
 
+def _instructions(text):
+    """(name, result type, opcode, op_name) of each instruction line of
+    a compiled module, fused computations' lines included."""
+    line = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\("
+                      r"(?:.*op_name=\"([^\"]*)\")?", re.M)
+    return [m.groups("") for m in line.finditer(text)]
+
+
+def _elements(shape):
+    dims = re.match(r"\w+\[([\d,]*)\]", shape)
+    n = 1
+    for d in (dims.group(1).split(",") if dims and dims.group(1) else []):
+        n *= int(d)
+    return n
+
+
+def test_mamba_mixer_step_leaves_no_copy_of_the_scans_arrays_for_v5e(
+        one_chip, monkeypatch):
+    """One ``_Mamba2`` mixer of the granite cell, forward and backward:
+    one row of 8,192 positions, 64 heads of 64, a state of 128, chunks
+    of 256, the kernels forced (``impl="auto"`` is ``jnp`` on the CPU
+    backend even when compiling for the chip). The kernels read x and
+    dy and write y and dx in the mixer's ``(b, s, heads x 64)`` layout,
+    so XLA computes no array of x's 8192 x 4096 elements under
+    ``ssm/scan``: no transpose to heads before positions, no layout
+    copy, no float32 cast of x, no ``dt x``, no skip."""
+    from learningorchestra_tpu.models import transformer as tlm
+    from learningorchestra_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "resolve_impl", lambda impl="auto": "pallas")
+    monkeypatch.setattr(ssd, "_auto_interpret", lambda: False)
+    mixer = tlm._Mamba2(64, 64, 128, 4, 256)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    u = sds((1, 8192, 2048))
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: sds(a.shape, jnp.float32 if any(
+            n in jax.tree_util.keystr(path) for n in tlm.FLOAT32_LEAVES)
+            else jnp.bfloat16),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0), u))
+
+    def loss(p, u_):
+        out, stats = mixer.apply(p, u_)
+        return out.astype(jnp.float32).sum() + stats.sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    assert {"ssd_fwd", "ssd_bwd"} <= _custom_calls(text)
+    scan = [i for i in _instructions(text) if "ssm/scan" in i[3]]
+    assert scan
+    made = [(name, shape, op) for name, shape, op, _ in scan
+            if _elements(shape) == 8192 * 4096
+            and op not in ("custom-call", "get-tuple-element", "bitcast")]
+    assert not made, made
+    assert not [i for i in scan if "[1,64,8192,64]" in i[1]
+                or i[1].startswith("f32[1,8192,4096]")]
+
+
 def test_flash_kernels_compile_for_v5e_at_head_64_with_a_given_scale(
         one_chip):
     """The hybrid's attention layer: one row of 8,192 positions, 32
