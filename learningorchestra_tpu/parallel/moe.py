@@ -11,22 +11,28 @@ layer, and the shares of all ranks add up to the whole layer
 The held experts' products run as ONE grouped product
 (``ops/grouped_matmul.py``): the routed copies are sorted by expert
 (:func:`grouped_layout`), each expert's run padded to whole row tiles,
-gathered, multiplied (gate, up, down) and scattered back weighted. The
+gathered, multiplied (gate, up, down) and gathered back weighted. The
 row buffer is static and holds the worst case (every choice of every
 token held here), so every copy is computed whatever the router does.
 The layout puts every copy in the buffer's first ``n_active`` tiles,
 and nothing that touches the buffer goes past them. The products skip
-the tiles that hold no copy. The passes around them are loops over
-chunks of whole tiles whose trip count is the used prefix, forward and
-backward (written by hand: a ``while_loop`` has no reverse mode): the
-gather into the buffer (:func:`dispatch`; backward a scatter-add onto
-the tokens), ``silu(gate) * up`` between the products (:func:`gated`)
-and the weighted scatter-add out of it (:func:`combine`; backward a
-gather of the output's gradient). The rows past the last used chunk
-are never written and never read, so the passes cost what the copies
+the tiles that hold no copy. The passes on the buffer's side are loops
+over chunks of whole tiles whose trip count is the used prefix, forward
+and backward (written by hand: a ``while_loop`` has no reverse mode):
+the gather into the buffer (:func:`dispatch`), ``silu(gate) * up``
+between the products (:func:`gated`) and the backward of the weighted
+sum out of it (:func:`combine`). The rows past the last used chunk are
+never written and never read, so these passes cost what the copies
 cost, by a chunk at a time; when every choice of every token is held
-they run over the whole buffer. One path: no second buffer, no
-``cond``.
+they run over the whole buffer. What lands on the tokens is read, not
+added (a scatter-add costs a fixed time for every row that lands, 0.3
+us on a v5e: PERF.md section 5): the layout gives each copy its row
+(``pos``); the combine and the dispatch's backward sum each token's
+rows in ``ops/row_gather.py``'s kernel, which reads the rows that hold
+copies and no others; the gradient of the router's weights reads a
+number a row by ``pos``; the router's top-k puts its gradient back by
+a compare (:func:`top_k`). No scatter, forward or backward. One path:
+no second buffer, no ``cond``.
 
 On a mesh whose ``ep`` axis is larger than 1 the older schedule stays
 until a four-chip cell measures its replacement (ROADMAP S17): experts
@@ -52,6 +58,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from learningorchestra_tpu.ops import grouped_matmul as gmm_ops
+from learningorchestra_tpu.ops import row_gather
 from learningorchestra_tpu.parallel import sharding as sharding_lib
 from learningorchestra_tpu.runtime import mesh as mesh_lib
 
@@ -77,6 +84,34 @@ def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
     }
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def top_k(probs: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """``lax.top_k``, whose backward puts each value's gradient on its
+    expert by comparing ``idx`` with every expert (T x E x k compares):
+    ``lax.top_k``'s own is a scatter-add of every choice."""
+    vals, idx = jax.lax.top_k(probs, k)
+    return vals, idx
+
+
+def _top_k_fwd(probs, k):
+    vals, idx = jax.lax.top_k(probs, k)
+    return (vals, idx), (probs, idx)
+
+
+def _top_k_bwd(k, res, cot):
+    probs, idx = res
+    dvals = cot[0]
+    experts = jnp.arange(probs.shape[-1], dtype=idx.dtype)
+    dprobs = jnp.zeros_like(probs)
+    for j in range(k):
+        dprobs = dprobs + jnp.where(idx[..., j, None] == experts,
+                                    dvals[..., j, None], 0)
+    return (dprobs,)
+
+
+top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def route(logits: jax.Array, k: int, scale: float = 1.0,
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The router's head: float32 softmax over all experts, the k
@@ -87,7 +122,7 @@ def route(logits: jax.Array, k: int, scale: float = 1.0,
     aux)."""
     e = logits.shape[-1]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    vals, idx = jax.lax.top_k(probs, k)
+    vals, idx = top_k(probs, k)
     vals = vals / jnp.maximum(jnp.sum(vals, axis=-1, keepdims=True), 1e-9)
     if scale != 1.0:
         vals = vals * jnp.float32(scale)
@@ -105,6 +140,13 @@ def held_counts(idx: jax.Array, held: int, offset: int) -> jax.Array:
     return jnp.sum(local == jnp.arange(held), axis=0, dtype=jnp.int32)
 
 
+def held_group(idx: jax.Array, held: int, offset: int) -> jax.Array:
+    """The held expert of each routed copy, ``held`` for one not held
+    here (``idx``'s shape)."""
+    local = idx - offset
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
 def tiles_needed(counts: jax.Array, tile_m: int) -> jax.Array:
     """Row tiles of each group: its copies in whole tiles, one at
     least (the grouped product writes every group's gradient block)."""
@@ -116,16 +158,17 @@ def grouped_layout(idx: jax.Array, held: int, offset: int, rows: int,
     """Where each routed copy goes in a buffer of ``rows`` rows sorted
     by held expert, every expert's run starting on a tile edge.
 
-    Returns ``(src, valid, tile_group, n_active)``: for each row the
-    flat index ``t * k + choice`` of the copy it holds and whether it
-    holds one; the held expert of each row tile (non-decreasing; past
-    the used tiles the last expert again); the number of used tiles,
-    (1,) int32. Copies keep token order within an expert. The caller
-    sees to it that they fit (``tiles_needed`` summed, against
-    ``rows // tile_m``)."""
+    Returns ``(src, valid, tile_group, n_active, pos)``: for each row
+    the flat index ``t * k + choice`` of the copy it holds and whether
+    it holds one; the held expert of each row tile (non-decreasing;
+    past the used tiles the last expert again); the number of used
+    tiles, (1,) int32; for each copy (``pos`` (T, k) int32) the row
+    that holds it, ``rows`` (past the buffer) for a copy not held here,
+    so ``src[pos[t, j]] == t * k + j`` for every held copy. Copies keep
+    token order within an expert. The caller sees to it that they fit
+    (``tiles_needed`` summed, against ``rows // tile_m``)."""
     n_copies = idx.size
-    local = idx.reshape(-1) - offset
-    key = jnp.where((local >= 0) & (local < held), local, held)
+    key = held_group(idx, held, offset).reshape(-1)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     if counts is None:
         counts = held_counts(idx, held, offset)
@@ -133,6 +176,14 @@ def grouped_layout(idx: jax.Array, held: int, offset: int, rows: int,
     tiles = tiles_needed(counts, tile_m)
     tile_ends = jnp.cumsum(tiles)
     tile_starts = tile_ends - tiles
+    # a copy's row is its place in the sort (the inverse of ``order``,
+    # a second sort: its scatter would be one) moved by its expert's
+    # shift, found by a compare as ``held_counts`` counts
+    shift = tile_starts * tile_m - starts
+    mine = key[:, None] == jnp.arange(held)
+    pos = jnp.where(key < held,
+                    jnp.argsort(order).astype(jnp.int32)
+                    + jnp.sum(jnp.where(mine, shift, 0), axis=1), rows)
     n_tiles = rows // tile_m
     tile = jnp.arange(n_tiles, dtype=jnp.int32)
     tile_group = jnp.minimum(
@@ -147,7 +198,8 @@ def grouped_layout(idx: jax.Array, held: int, offset: int, rows: int,
     src = order[jnp.clip(starts[tile_group][:, None] + within, 0,
                          n_copies - 1)]
     return (src.reshape(rows), valid.reshape(rows), tile_group,
-            tile_ends[-1:].astype(jnp.int32))
+            tile_ends[-1:].astype(jnp.int32),
+            pos.astype(jnp.int32).reshape(idx.shape))
 
 
 def _auto_tile(copies_per_expert: float) -> int:
@@ -213,9 +265,9 @@ def _take(table: jax.Array, index: jax.Array) -> jax.Array:
     return jnp.take(table, index, axis=0, mode="fill", fill_value=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 4, 5))
-def dispatch(tokens: jax.Array, copy: jax.Array, k: int,
-             n_active: jax.Array, tile_m: int, chunk: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5, 6))
+def dispatch(tokens: jax.Array, copy: jax.Array, runs: row_gather.Runs,
+             k: int, n_active: jax.Array, tile_m: int, chunk: int,
              ) -> Tuple[jax.Array, jax.Array]:
     """The row buffer ``xs (rows, d)``: ``xs[r] = tokens[copy[r] // k]``
     (``copy[r]``: the routed copy ``token * k + choice`` the row holds,
@@ -223,7 +275,9 @@ def dispatch(tokens: jax.Array, copy: jax.Array, k: int,
     chunks; the rows past them are not written (zero). ``chunk``
     divides ``rows``. Given TWICE, once for each product that reads
     it: their two gradients then come back apart and are summed a chunk
-    at a time, where one buffer's would be summed over all of it."""
+    at a time, where one buffer's would be summed over all of it.
+    Backward, each token sums its copies' rows (``runs``: where they
+    lie, ``row_gather.runs`` of :func:`grouped_layout`'s ``pos``)."""
     def body(rows_at, xs):
         return rows_at(xs, _take(tokens, rows_at(copy) // k))
 
@@ -233,23 +287,23 @@ def dispatch(tokens: jax.Array, copy: jax.Array, k: int,
     return xs, xs
 
 
-def _dispatch_fwd(tokens, copy, k, n_active, tile_m, chunk):
-    return dispatch(tokens, copy, k, n_active, tile_m, chunk), \
-        (tokens, copy, n_active)
+def _dispatch_fwd(tokens, copy, runs, k, n_active, tile_m, chunk):
+    return dispatch(tokens, copy, runs, k, n_active, tile_m, chunk), \
+        (runs, n_active)
 
 
 def _dispatch_bwd(k, tile_m, chunk, res, dxs):
-    tokens, copy, n_active = res
+    runs, n_active = res
     with jax.named_scope("moe/experts"):
-        # summed in the tokens' type, as the transpose of ``take`` is
-        def body(rows_at, dtokens):
-            return dtokens.at[rows_at(copy) // k].add(
-                (rows_at(dxs[0]) + rows_at(dxs[1])).astype(dtokens.dtype),
-                mode="drop")
+        # the two gradients summed into the first, a used chunk at a
+        # time; each token's copies then summed in float32 and rounded
+        # once to the tokens' type
+        def body(rows_at, d):
+            return rows_at(d, rows_at(d) + rows_at(dxs[1]))
 
-        dtokens = _over_used_chunks(body, jnp.zeros_like(tokens),
-                                    n_active, tile_m, chunk)
-    return dtokens, None, None
+        d = _over_used_chunks(body, dxs[0], n_active, tile_m, chunk)
+        dtokens = row_gather.rows_summed(d, runs).astype(dxs[0].dtype)
+    return dtokens, None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -295,55 +349,49 @@ def _gated_bwd(tile_m, chunk, res, dh):
 gated.defvjp(_gated_fwd, _gated_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def combine(ys: jax.Array, weights: jax.Array, copy: jax.Array,
-            n_active: jax.Array, tile_m: int, chunk: int) -> jax.Array:
-    """``out (t, d)`` float32: ``out[copy[r] // k] += ys[r] *
-    weights.flat[copy[r]]`` over the rows of the used chunks
-    (``weights (t, k)``; a ``copy[r]`` past the copies is dropped)."""
-    t, k = weights.shape
-    flat = weights.reshape(-1)
-
-    def body(rows_at, out):
-        c = rows_at(copy)
-        return out.at[c // k].add(
-            rows_at(ys).astype(jnp.float32) * _take(flat, c)[:, None],
-            mode="drop")
-
-    return _over_used_chunks(
-        body, jnp.zeros((t, ys.shape[1]), jnp.float32), n_active, tile_m,
-        chunk)
+            pos: jax.Array, runs: row_gather.Runs, n_active: jax.Array,
+            tile_m: int, chunk: int) -> jax.Array:
+    """``out (t, d)`` float32: ``out[t] = sum over j of weights[t, j] *
+    ys[pos[t, j]]`` (``weights (t, k)``; a ``pos`` past the buffer reads
+    zero), by ``runs`` (``row_gather.runs`` of ``pos``). Backward,
+    ``copy`` (as :func:`dispatch`'s) gives each row of the used chunks
+    its token's gradient."""
+    return row_gather.rows_summed(ys, runs, weights)
 
 
-def _combine_fwd(ys, weights, copy, n_active, tile_m, chunk):
-    return combine(ys, weights, copy, n_active, tile_m, chunk), \
-        (ys, weights, copy, n_active)
+def _combine_fwd(ys, weights, copy, pos, runs, n_active, tile_m, chunk):
+    return combine(ys, weights, copy, pos, runs, n_active, tile_m, chunk), \
+        (ys, weights, copy, pos, n_active)
 
 
 def _combine_bwd(tile_m, chunk, res, dout):
-    ys, weights, copy, n_active = res
+    ys, weights, copy, pos, n_active = res
     k = weights.shape[1]
     flat = weights.reshape(-1)
     with jax.named_scope("moe/combine"):
+        # the weights' gradient is a number a row, ``<dout[t], ys[r]>``,
+        # read back by each copy's row
         def body(rows_at, carry):
-            dys, dflat = carry
+            dys, dot = carry
             c = rows_at(copy)
             d = _take(dout, c // k)
-            dflat = dflat.at[c].add(
-                jnp.sum(d * rows_at(ys).astype(jnp.float32), axis=-1),
-                mode="drop")
-            return rows_at(dys, d * _take(flat, c)[:, None]), dflat
+            return (rows_at(dys, d * _take(flat, c)[:, None]),
+                    rows_at(dot, jnp.sum(
+                        d * rows_at(ys).astype(jnp.float32), axis=-1)))
 
         # ``dys`` does not start as ``ys`` the way ``gated``'s gradients
-        # start as its inputs: the chunk's read (for ``dflat``) and its
+        # start as its inputs: the chunk's read (for ``dot``) and its
         # write are two fusions here, and in a whole step XLA then
         # copied the buffer twice a trip to keep them apart (PERF.md
         # section 6, PR 27)
-        dys, dflat = _over_used_chunks(
-            body, (jnp.zeros_like(ys), jnp.zeros(flat.shape, jnp.float32)),
+        dys, dot = _over_used_chunks(
+            body, (jnp.zeros_like(ys),
+                   jnp.zeros(ys.shape[:1], jnp.float32)),
             n_active, tile_m, chunk)
-    return dys, dflat.reshape(weights.shape).astype(weights.dtype), \
-        None, None
+        dweights = _take(dot, pos)
+    return dys, dweights.astype(weights.dtype), None, None, None, None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -358,21 +406,22 @@ def _held_experts(experts: Dict[str, Any], tokens: jax.Array,
     t, k = idx.shape
     held = experts["w_gate"].shape[0]
     with jax.named_scope("moe/route"):
-        src, valid, tile_group, n_active = grouped_layout(
+        src, valid, tile_group, n_active, pos = grouped_layout(
             idx, held, offset, rows, tile_m, counts)
-        # a row that holds no copy reads past the copies (zeros) and is
-        # dropped by the scatters
+        # a row that holds no copy reads past the copies: zeros
         copy = jnp.where(valid, src, t * k)
+        runs = row_gather.runs(pos, held_group(idx, held, offset), held,
+                               rows)
     passes = (n_active, tile_m, chunk)
     product = lambda a, w: gmm_ops.grouped_matmul(  # noqa: E731
         a, w, tile_group, n_active, tile_m=tile_m)
     with jax.named_scope("moe/experts"):
-        xs_gate, xs_up = dispatch(tokens, copy, k, *passes)
+        xs_gate, xs_up = dispatch(tokens, copy, runs, k, *passes)
         h = gated(product(xs_gate, experts["w_gate"]),
                   product(xs_up, experts["w_up"]), *passes)
         ys = product(h, experts["w_down"])
     with jax.named_scope("moe/combine"):
-        return combine(ys, weights, copy, *passes)
+        return combine(ys, weights, copy, pos, runs, *passes)
 
 
 def sparse_route(gate_idx: jax.Array, gate_vals: jax.Array, e: int,

@@ -17,6 +17,7 @@ from benchmark.reference import sdar_moe
 from learningorchestra_tpu.models import LanguageModel
 from learningorchestra_tpu.ops import attention as attn_ops
 from learningorchestra_tpu.ops import grouped_matmul as gmm_ops
+from learningorchestra_tpu.ops import row_gather
 from learningorchestra_tpu.parallel import moe
 
 SEED = 2600000007
@@ -124,7 +125,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 def test_grouped_layout_pads_every_expert_to_whole_tiles():
     idx = jnp.asarray([[0, 5], [1, 5], [5, 6], [5, 2], [7, 5]], jnp.int32)
-    src, valid, tile_group, n_active = moe.grouped_layout(
+    src, valid, tile_group, n_active, pos = moe.grouped_layout(
         idx, held=3, offset=4, rows=48, tile_m=4)
     # experts 4, 5, 6 receive 0, 5 and 1 copies: 1, 2 and 1 tiles
     assert int(n_active[0]) == 4
@@ -137,6 +138,9 @@ def test_grouped_layout_pads_every_expert_to_whole_tiles():
     # token order within an expert
     np.testing.assert_array_equal(np.asarray(src)[rows] // 2,
                                   [0, 1, 2, 3, 4, 2])
+    # each copy's row; 48, past the buffer, where its expert is not held
+    np.testing.assert_array_equal(
+        pos, [[48, 4], [48, 5], [6, 12], [7, 48], [48, 8]])
 
 
 def test_grouped_matmul_matches_a_product_a_group():
@@ -202,14 +206,19 @@ def test_grouped_matmul_never_reads_a_tile_past_the_active_ones(n_active):
 
 def test_the_row_buffer_is_the_worst_case_and_nothing_else():
     """One path: the layer's forward holds its three grouped products
-    once (no second buffer behind a ``cond``), and its buffer has a row
-    for every choice of every token plus a tile a held expert."""
+    and the combine's sum of rows once (no second buffer behind a
+    ``cond``), and its buffer has a row for every choice of every token
+    plus a tile a held expert."""
     params, _ = _layer(LM)
     x = _tokens(96, LM["d_model"])
     jaxpr = jax.make_jaxpr(lambda p, x: moe.moe_layer(
         p, x, k=LM["moe_k"], expert_offset=LM["expert_offset"])[0])(params, x)
     text = str(jaxpr)
-    assert text.count("pallas_call[") == 3
+    assert text.count("pallas_call[") == 4
+    kernels = [label for name, label in _primitives(jaxpr.jaxpr, [])
+               if name == "pallas_call"]
+    assert kernels.count("moe_gmm_fwd") == 3
+    assert kernels.count("moe_rows_sum") == 1
     tile = moe._auto_tile(96 * LM["moe_k"] / LM["n_experts"])
     held = params["experts"]["w_gate"].shape[0]
     rows = -(-96 * min(LM["moe_k"], held) // tile) * tile + held * tile
@@ -228,32 +237,104 @@ def _routing(case):
         first = jnp.select([jnp.arange(t) < 9, jnp.arange(t) < 12,
                             jnp.arange(t) < 22], [2, 3, 4], 6)
         return jnp.stack([first, away], axis=1), 3, 2
+    if case == "skewed":                     # the router's drift: 3 and 4
+        first = jnp.where(jnp.arange(t) % 5 == 0, 3, 4)   # take most
+        second = jnp.where(jnp.arange(t) % 3 == 0, 7, 3 + first % 2)
+        return jnp.stack([first, second], axis=1), 4, 1
     # every expert held, every copy kept
     idx = jax.lax.top_k(jax.random.normal(jax.random.PRNGKey(4), (t, 4)),
                         2)[1].astype(jnp.int32)
     return idx, 4, 0
 
 
+def _buffer_rows(idx, held, tile, chunk):
+    t, k = idx.shape
+    rows = -(-t * min(k, held) // tile) * tile + held * tile
+    return -(-rows // chunk) * chunk
+
+
+@pytest.mark.parametrize("case", ["one_tile", "ends_inside_a_chunk",
+                                  "every_copy_held", "skewed"])
+def test_each_copys_row_holds_it(case):
+    """``pos``: every held copy's row holds that copy (``src`` there is
+    ``t * k + j``, ``valid`` set), the rows of the held copies are
+    distinct and within the used tiles, and every copy not held here
+    reads the sentinel, the row past the buffer."""
+    tile, chunk = 8, 16
+    idx, held, offset = _routing(case)
+    t, k = idx.shape
+    rows = _buffer_rows(idx, held, tile, chunk)
+    src, valid, _, n_active, pos = moe.grouped_layout(idx, held, offset,
+                                                      rows, tile)
+    assert pos.shape == (t, k) and pos.dtype == jnp.int32
+    local = np.asarray(idx) - offset
+    mine = (local >= 0) & (local < held)
+    pos, src, valid = np.asarray(pos), np.asarray(src), np.asarray(valid)
+    np.testing.assert_array_equal(pos[~mine], rows)
+    flat = np.arange(t * k).reshape(t, k)
+    np.testing.assert_array_equal(src[pos[mine]], flat[mine])
+    assert valid[pos[mine]].all()
+    assert len(np.unique(pos[mine])) == int(mine.sum()) == int(valid.sum())
+    assert pos[mine].max(initial=-1) < int(n_active[0]) * tile
+
+
+@pytest.mark.parametrize("t,k,experts,held,d,dtype,weighted", [
+    (40, 2, 8, 4, 16, jnp.float32, True),        # one token tile
+    (300, 4, 16, 8, 128, jnp.float32, True),     # three, the last padded
+    (300, 4, 16, 8, 128, jnp.bfloat16, True),
+    (300, 4, 16, 8, 128, jnp.bfloat16, False),
+    (256, 8, 64, 8, 256, jnp.bfloat16, True)],
+    ids=["one_tile", "padded_f32", "padded_bf16", "unweighted_bf16",
+         "k8_bf16"])
+def test_the_sum_of_rows_reads_the_held_rows_alone(t, k, experts, held, d,
+                                                   dtype, weighted):
+    """``row_gather.rows_summed`` gives each token the weighted sum of
+    its copies' rows that ``take`` gives over the whole buffer, with
+    float32 sums (the bf16 weights in two parts: 16 leading bits), and
+    reads no row past the used tiles (no number there)."""
+    rng = np.random.default_rng(t + k)
+    idx = jnp.asarray(np.argsort(rng.random((t, experts)), axis=1)[:, :k],
+                      jnp.int32)
+    tile, rows, _ = moe.buffer_shape(t, k, experts, held)
+    _, _, _, n_active, pos = moe.grouped_layout(idx, held, 0, rows, tile)
+    buf = jnp.asarray(rng.normal(size=(rows, d)), dtype)
+    buf = jnp.where((jnp.arange(rows) < int(n_active[0]) * tile)[:, None],
+                    buf, jnp.nan)
+    w = jnp.asarray(rng.random((t, k)), jnp.float32) if weighted else None
+    got = row_gather.rows_summed(
+        buf, row_gather.runs(pos, moe.held_group(idx, held, 0), held, rows),
+        w)
+    want = sum(jnp.take(buf, pos[:, j], axis=0, mode="fill",
+                        fill_value=0).astype(jnp.float32)
+               * (1.0 if w is None else w[:, j:j + 1]) for j in range(k))
+    assert got.shape == (t, d) and got.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5,
+                               atol=2e-5 * float(jnp.max(jnp.abs(want))))
+
+
 @pytest.mark.parametrize("case", ["one_tile", "ends_inside_a_chunk",
                                   "every_copy_held"])
 def test_the_passes_over_the_used_chunks_are_the_whole_buffer_passes(case):
-    """The loops over the used chunks give what ``take``, ``silu(g) *
-    u`` and ``.at[].add`` over the whole buffer give, in value and in
-    the gradients towards the tokens, the products' rows and the
-    router's weights; what lies past the used tiles is no number on
-    their side, to show that it is never read."""
+    """The loops over the used chunks and the gathers by each copy's
+    row give what ``take``, ``silu(g) * u`` and ``.at[].add`` over the
+    whole buffer give, in value and in the gradients towards the
+    tokens, the products' rows and the router's weights; what lies past
+    the used tiles is no number on their side, to show that it is never
+    read."""
     tile, chunk, d = 8, 16, 16
     idx, held, offset = _routing(case)
     t, k = idx.shape
-    rows = -(-t * min(k, held) // tile) * tile + held * tile
-    rows = -(-rows // chunk) * chunk
-    src, valid, _, n_active = moe.grouped_layout(idx, held, offset, rows,
-                                                 tile)
+    rows = _buffer_rows(idx, held, tile, chunk)
+    src, valid, _, n_active, pos = moe.grouped_layout(idx, held, offset,
+                                                      rows, tile)
     used = int(n_active[0]) * tile
     assert {"one_tile": used == tile,
             "ends_inside_a_chunk": used % chunk and chunk < used < rows,
             "every_copy_held": int(jnp.sum(valid)) == t * k}[case]
     copy = jnp.where(valid, src, t * k)
+    runs = row_gather.runs(pos, moe.held_group(idx, held, offset), held,
+                           rows)
     live = (jnp.arange(rows) < used)[:, None]
     passes = (n_active, tile, chunk)
     inputs = {"tokens": _tokens(t, d, seed=11),
@@ -265,9 +346,10 @@ def test_the_passes_over_the_used_chunks_are_the_whole_buffer_passes(case):
         + [_tokens(t, d, seed=19)]
 
     def chunked(a):
-        xs_gate, xs_up = moe.dispatch(a["tokens"], copy, k, *passes)
+        xs_gate, xs_up = moe.dispatch(a["tokens"], copy, runs, k, *passes)
         return (xs_gate, xs_up, moe.gated(a["g"], a["u"], *passes),
-                moe.combine(a["ys"], a["weights"], copy, *passes))
+                moe.combine(a["ys"], a["weights"], copy, pos, runs,
+                            *passes))
 
     def whole(a):
         xs = jnp.take(a["tokens"], copy // k, axis=0, mode="fill",
@@ -316,9 +398,10 @@ def _primitives(jaxpr, into):
 
 
 def test_the_layer_is_one_path_of_loops_around_three_products():
-    """Three ``while`` (dispatch, ``silu x up`` and combine: the
-    passes' trip count is observed) and no ``cond`` (no second buffer
-    to fall back on); a gradient of the
+    """Two ``while`` forward (dispatch and ``silu x up``: the passes'
+    trip count is observed; the combine sums each token's rows), no
+    ``cond`` (no second buffer to fall back on) and no scatter, forward
+    or backward; a gradient of the
     layer calls each product's ``moe_gmm_dw`` once, three in all: the
     kernels are not in the loops."""
     params, _ = _layer(LM)
@@ -330,14 +413,22 @@ def test_the_layer_is_one_path_of_loops_around_three_products():
 
     forward = _primitives(jax.make_jaxpr(layer)(params, x).jaxpr, [])
     names = [name for name, _ in forward]
-    assert names.count("while") == 3 and "cond" not in names
+    assert names.count("while") == 2 and "cond" not in names
     backward = _primitives(
         jax.make_jaxpr(jax.grad(layer, (0, 1)))(params, x).jaxpr, [])
-    assert "cond" not in [name for name, _ in backward]
+    back_names = [name for name, _ in backward]
+    assert "cond" not in back_names
+    # forward, dispatch and ``silu x up``; backward, those two and the
+    # combine's
+    assert back_names.count("while") == 5
+    for name in names + back_names:
+        assert not name.startswith("scatter"), name
     kernels = [label for name, label in backward if name == "pallas_call"]
     assert kernels.count("moe_gmm_dw") == 3
     assert kernels.count("moe_gmm_dx") == 3
     assert kernels.count("moe_gmm_fwd") == 3
+    # the combine, and the dispatch's backward
+    assert kernels.count("moe_rows_sum") == 2
 
 
 # ----------------------------------------------------------------------

@@ -178,23 +178,32 @@ def test_grouped_product_compiles_for_v5e_at_the_cells_shape(one_chip,
         <= _custom_calls(text)
 
 
+@pytest.mark.parametrize("experts,held,width,rows", [
+    (128, 16, 768, 135168), (256, 32, 512, 139264)],
+    ids=["sdar", "laguna"])
 def test_expert_layer_gradient_compiles_for_v5e_with_the_chunked_passes(
-        one_chip, monkeypatch):
-    """One expert layer of the sdar cell under per-block recomputation,
-    forward and backward: 16,384 positions, 8 of 128 experts a token,
-    16 held. The dispatch, ``silu x up`` and the combine are ``while``
-    loops over the used chunks of the row buffer, around the products'
-    kernels, each of which stays one call over the whole buffer
-    (``moe_gmm_dw`` three times a layer: the benchmark counts its steps
-    by that)."""
+        one_chip, monkeypatch, experts, held, width, rows):
+    """One expert layer of the sdar cell (8 of 128 experts a token, 16
+    held, of width 768) or of the Laguna cell (8 of 256, 32 held, of
+    width 512) under per-block recomputation, forward and backward:
+    16,384 positions of 2,048. The dispatch and ``silu x up``, and the
+    combine's backward, are ``while`` loops over the used chunks of the
+    row buffer of ``rows`` rows, around the products' kernels, each of
+    which stays one call over the whole buffer (``moe_gmm_dw`` three
+    times a layer: the benchmark counts its steps by that). What lands
+    on the tokens is gathered by each copy's row, ``k`` rows a token:
+    no scatter, and no array of a row for every copy."""
     from learningorchestra_tpu.ops import grouped_matmul as gmm
+    from learningorchestra_tpu.ops import row_gather
     from learningorchestra_tpu.parallel import moe
 
     # the backend here is the CPU: the kernels are asked of Mosaic
     monkeypatch.setattr(gmm, "_auto_interpret", lambda: False)
+    monkeypatch.setattr(row_gather, "_auto_interpret", lambda: False)
+    t, k, d = 16384, 8, 2048
 
     def layer(p, x):
-        return moe.moe_layer(p, x, k=8)[0]
+        return moe.moe_layer(p, x, k=k)[0]
 
     def loss(p, x):
         y = jax.checkpoint(
@@ -204,24 +213,30 @@ def test_expert_layer_gradient_compiles_for_v5e_with_the_chunked_passes(
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = {"gate": sds((2048, 128), jnp.float32),
-              "experts": {"w_gate": sds((16, 2048, 768)),
-                          "w_up": sds((16, 2048, 768)),
-                          "w_down": sds((16, 768, 2048))}}
+    params = {"gate": sds((d, experts), jnp.float32),
+              "experts": {"w_gate": sds((held, d, width)),
+                          "w_up": sds((held, d, width)),
+                          "w_down": sds((held, width, d))}}
+    assert moe.buffer_shape(t, k, experts, held)[1] == rows
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-        params, sds((16384, 2048))).compile().as_text()
+        params, sds((t, d))).compile().as_text()
     assert {"moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw"} \
         <= _custom_calls(text)
     assert len(re.findall(r"%moe_gmm_dw[.\d]* = [^\n]*custom-call\(",
                           text)) == 3
-    # the three passes forward, dispatch and ``silu x up`` recomputed
-    # (the combine's recomputation has no reader and is gone), the
-    # three backward
-    assert len(re.findall(r" while\(", text)) >= 8
+    # forward, dispatch and ``silu x up``, both recomputed (the
+    # combine's recomputation has no reader and is gone); backward,
+    # those two and the combine's
+    assert len(re.findall(r" while\(", text)) >= 7
+    # the combine's sum of rows, and the dispatch's backward
+    assert len(re.findall(r"%moe_rows_sum[.\d]* = [^\n]*custom-call\(",
+                          text)) == 2
     assert " conditional(" not in text
+    assert "scatter(" not in text
+    assert not [i for i in _instructions(text) if _elements(i[1]) == t * k * d]
     # the loops update the row buffers in place (``gated``'s gradients
     # start as its inputs): no copy of a whole one
-    assert not re.search(r"= bf16\[135168,\d+\]\{[^}]*\} copy\(", text)
+    assert not re.search(rf"= bf16\[{rows},\d+\]\{{[^}}]*\}} copy\(", text)
 
 
 def test_ssd_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
