@@ -18,11 +18,13 @@ the explicit ``trace=`` / ``parent=`` arguments, or :func:`add` to
 record an already-measured interval retroactively.
 
 One clock with the profiler: while a :func:`span` is open it also
-holds a ``jax.profiler.TraceAnnotation`` of the same name, so in a
-profiler session (``benchmark/run.py --trace 1``, ``POST /profile``,
-an incident capture) every live span lies in the host plane of the
-same ``.xplane.pb`` as the device's ops. :func:`add` records an
-interval that is already over and so never reaches the profiler.
+holds a ``jax.profiler.TraceAnnotation`` of the same name, whose
+argument ``span`` is the span's id, so in a profiler session
+(``benchmark/run.py --trace 1``, ``POST /profile``, an incident
+capture) every live span lies in the host plane of the same
+``.xplane.pb`` as the device's ops, told apart from jax's own events
+by that argument. :func:`add` records an interval that is already
+over and so never reaches the profiler.
 
 Compile activity: the first live span registers ``jax.monitoring``
 listeners (once a process) that add what jax traced, lowered, built
@@ -161,7 +163,7 @@ class _Trace:
         return sp
 
     def _evict(self) -> None:
-        """Make room. A long fit leaves some six spans an epoch, so
+        """Make room. A long fit leaves some eight spans an epoch, so
         its oldest finished ``epoch`` goes first, children and all:
         what is outside an epoch (``submit``, ``dataLoad``, every
         ``compile``) outlives any number of epochs. Else the oldest
@@ -308,10 +310,11 @@ _annotation_cls: Any = None  # jax's TraceAnnotation; False without jax
 
 
 def _open_annotation(sp: Span) -> None:
-    """Hold a profiler annotation of the span's name while it is open
-    (a ``TraceMe`` flag check when no profiler session runs), and on
-    the first live span register the compile listeners. ``jax`` is
-    imported here so that this module imports without it."""
+    """Hold a profiler annotation of the span's name, marked with its
+    id, while it is open (a ``TraceMe`` flag check when no profiler
+    session runs), and on the first live span register the compile
+    listeners. ``jax`` is imported here so that this module imports
+    without it."""
     global _annotation_cls
     if _annotation_cls is None:
         try:
@@ -324,7 +327,7 @@ def _open_annotation(sp: Span) -> None:
         except ImportError:
             _annotation_cls = False
     if _annotation_cls:
-        sp.annotation = _annotation_cls(sp.name)
+        sp.annotation = _annotation_cls(sp.name, span=sp.span_id)
         sp.annotation.__enter__()
 
 

@@ -101,11 +101,12 @@ def _top_k_fwd(probs, k):
 def _top_k_bwd(k, res, cot):
     probs, idx = res
     dvals = cot[0]
-    experts = jnp.arange(probs.shape[-1], dtype=idx.dtype)
-    dprobs = jnp.zeros_like(probs)
-    for j in range(k):
-        dprobs = dprobs + jnp.where(idx[..., j, None] == experts,
-                                    dvals[..., j, None], 0)
+    with jax.named_scope("moe/route"):
+        experts = jnp.arange(probs.shape[-1], dtype=idx.dtype)
+        dprobs = jnp.zeros_like(probs)
+        for j in range(k):
+            dprobs = dprobs + jnp.where(idx[..., j, None] == experts,
+                                        dvals[..., j, None], 0)
     return (dprobs,)
 
 
@@ -237,8 +238,9 @@ def tiles_used(counts: jax.Array, t: int, k: int, n_experts: int,
     """() int32: the tiles of the row buffer that hold the copies of
     ``counts`` (held,), ``grouped_layout``'s ``n_active``: where the
     passes over the buffer end, by a chunk."""
-    return jnp.sum(tiles_needed(
-        counts, _auto_tile(t * k / n_experts))).astype(jnp.int32)
+    with jax.named_scope("moe/route"):
+        return jnp.sum(tiles_needed(
+            counts, _auto_tile(t * k / n_experts))).astype(jnp.int32)
 
 
 def _over_used_chunks(body: Callable, init, n_active: jax.Array,

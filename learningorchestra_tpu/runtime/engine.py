@@ -70,6 +70,13 @@ def _tree_nbytes(tree) -> int:
                for x in jax.tree_util.tree_leaves(tree))
 
 
+def _host_reads(sums: Dict[str, Any]) -> int:
+    """The device arrays an epoch's totals take to the host, one at a
+    time: a sum and a count a metric, the health counter's sum alone
+    (``sums``: the totals, or on the per-step path their sums)."""
+    return 2 * len(sums) - ("_health_bad" in sums)
+
+
 def default_grad_accum() -> int:
     """Process-wide microbatch-count default (LO_GRAD_ACCUM env)."""
     return max(1, int(os.environ.get("LO_GRAD_ACCUM", "1")))
@@ -1274,9 +1281,12 @@ class Engine:
                         jax.block_until_ready(state.params)
                     dt = time.perf_counter() - t0
                     with obs_trace.span("epochEnd", epoch=epoch):
-                        bad_steps = self._pop_bad_steps(totals)
-                        record = {k: float(s) / max(float(c), 1e-9)
-                                  for k, (s, c) in totals.items()}
+                        with obs_trace.span(
+                                "totalsRead", epoch=epoch,
+                                arrays=_host_reads(totals)):
+                            bad_steps = self._pop_bad_steps(totals)
+                            record = {k: float(s) / max(float(c), 1e-9)
+                                      for k, (s, c) in totals.items()}
                         if policy is not None:
                             proceed, state, event = \
                                 self._health_epoch_end(
@@ -1309,7 +1319,8 @@ class Engine:
                             self._save_checkpoint(checkpointer, state,
                                                   epoch)
                         if log_fn is not None:
-                            log_fn(record)
+                            with obs_trace.span("recordWrite", epoch=epoch):
+                                log_fn(record)
                 # fair scheduling: offer the mesh lease to waiting
                 # jobs of other pools (no-op outside the service
                 # layer); the epoch is checkpointed, so the hand-off
@@ -1518,10 +1529,12 @@ class Engine:
                 now = time.perf_counter()
                 dt = now - t0
                 with obs_trace.span("epochEnd", epoch=epoch):
-                    bad_steps = self._pop_bad_steps(sums, counts)
-                    record = {
-                        k: float(sums[k]) / max(float(counts[k]), 1e-9)
-                        for k in sums}
+                    with obs_trace.span("totalsRead", epoch=epoch,
+                                        arrays=_host_reads(sums)):
+                        bad_steps = self._pop_bad_steps(sums, counts)
+                        record = {
+                            k: float(sums[k]) / max(float(counts[k]), 1e-9)
+                            for k in sums}
                     if policy is not None:
                         proceed, state, event = self._health_epoch_end(
                             policy, sent, epoch, bad_steps,
@@ -1552,7 +1565,8 @@ class Engine:
                     if checkpointer is not None:
                         self._save_checkpoint(checkpointer, state, epoch)
                     if log_fn is not None:
-                        log_fn(record)
+                        with obs_trace.span("recordWrite", epoch=epoch):
+                            log_fn(record)
             epoch += 1
             if epoch < epochs:  # fair scheduling (see _fit_scanned)
                 state, migrated = self._maybe_migrate(
@@ -1907,11 +1921,13 @@ class FusedEngine(Engine):
                     jax.block_until_ready(state.params)
                 dt = time.perf_counter() - t0
                 with obs_trace.span("epochEnd", epoch=epoch):
-                    record: Dict[str, Any] = {
-                        k: (np.asarray(s, np.float64)
-                            / np.maximum(np.asarray(c, np.float64), 1e-9)
-                            ).round(6).tolist()
-                        for k, (s, c) in totals.items()}
+                    with obs_trace.span("totalsRead", epoch=epoch,
+                                        arrays=2 * len(totals)):
+                        record: Dict[str, Any] = {
+                            k: (np.asarray(s, np.float64)
+                                / np.maximum(np.asarray(c, np.float64),
+                                             1e-9)).round(6).tolist()
+                            for k, (s, c) in totals.items()}
                     record.update(epoch=epoch, epochSeconds=round(dt, 4))
                     self._observe_window(
                         epoch_span, dt, {"epoch": epoch}, 0,
@@ -1919,7 +1935,8 @@ class FusedEngine(Engine):
                         builds=dispatch.attrs.get("builds", 0))
                     history.append(record)
                     if log_fn is not None:
-                        log_fn(record)
+                        with obs_trace.span("recordWrite", epoch=epoch):
+                            log_fn(record)
             if es_armed and epoch + 1 < epochs:
                 vals = self.evaluate_fused(state, eval_batcher)
                 for i in range(n):

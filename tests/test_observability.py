@@ -440,6 +440,59 @@ def test_live_spans_reach_the_profiler_capture(tmp_path):
         assert names[gone] == 0, (gone, names[gone])
 
 
+@pytest.mark.parametrize("per_step", [False, True])
+def test_epoch_end_holds_the_totals_read_then_the_record_written(
+        tmp_path, per_step):
+    """``epochEnd`` holds ``totalsRead {epoch, arrays}`` (the totals to
+    the host: a sum and a count of each metric) and then
+    ``recordWrite {epoch}`` (``log_fn``), on the scanned and the
+    per-step path; in a profiler capture each live span is a host event
+    marked with its id."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    config_mod.set_config(config_mod.Config(
+        home=str(tmp_path / "lo_home"), compute_dtype="float32",
+        **({"scan_fit_max_bytes": 0} if per_step else {})))
+    records = []
+    try:
+        lm, x = _tiny_lm()
+        jax.profiler.start_trace(str(tmp_path / "prof"))
+        try:
+            with obs_trace.span("job", trace="ends"):
+                lm.fit(x, batch_size=4, epochs=2, shuffle=False,
+                       log_fn=records.append)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        config_mod.reset_config()
+    spans = obs_trace.spans_of("ends")
+    by_id = {s.span_id: s for s in spans}
+    for e in (0, 1):
+        ends = [s for s in spans if s.name == "epochEnd"
+                and s.attrs["epoch"] == e]
+        assert len(ends) == 1
+        read, write = [s for s in spans if s.parent_id == ends[0].span_id]
+        assert (read.name, write.name) == ("totalsRead", "recordWrite")
+        assert read.attrs == {"epoch": e, "arrays": 2 * 2}  # loss, accuracy
+        assert write.attrs == {"epoch": e}
+        assert read.end <= write.start
+    assert [r["epoch"] for r in records] == [0, 1]
+    (path,) = glob.glob(str(tmp_path / "prof") + "/**/*.xplane.pb",
+                        recursive=True)
+    marked = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines if plane.name.startswith("/host:") else ():
+            for event in line.events:
+                stats = dict(event.stats)
+                if "span" in stats:
+                    marked[stats["span"]] = event.name
+    assert marked == {sid: s.name for sid, s in by_id.items()
+                      if s.name != "compile"}
+
+
 def test_compile_spans_follow_the_builds_jax_reports(tmp_config):
     """``compile`` spans of a cold fit: one per dispatch during which
     ``jax.monitoring`` reported a build, each with what the build cost,
